@@ -9,28 +9,19 @@ current-device notion, and host/device transfer helpers.
 from __future__ import annotations
 
 import functools
-import os
 from typing import List, Optional
 
 import jax
 
 
 def local_devices(platform: Optional[str] = None):
-    """Devices of the requested platform, honoring ``PADDLE_TPU_PLATFORM``.
-
-    Some PJRT plugins register themselves as the default platform regardless of
-    ``JAX_PLATFORMS``; tests that need the virtual 8-device CPU mesh set
-    ``PADDLE_TPU_PLATFORM=cpu`` to force device discovery onto it.
+    """Devices of ``platform``, or of jax's default platform (which
+    ``JAX_PLATFORMS`` chooses: the test suite and the CPU simulation set it
+    to ``cpu``).  A platform that was asked for and is not there is an
+    error (``RuntimeError`` from jax) — never a quiet move to whatever the
+    default platform happens to be.
     """
-    platform = platform or os.environ.get("PADDLE_TPU_PLATFORM")
-    if platform:
-        try:
-            return jax.devices(platform)
-        except RuntimeError as e:
-            import warnings
-            warnings.warn(f"requested platform {platform!r} unavailable "
-                          f"({e}); falling back to default platform")
-    return jax.devices()
+    return jax.devices(platform) if platform else jax.devices()
 
 
 class Place:

@@ -10,9 +10,11 @@ TPU-native: one process per *host* (not per chip — XLA owns all local chips
 in a single process), ``jax.distributed`` coordination service in place of
 the TCP comm-id rendezvous, and the watch loop keeps the reference's
 exit-code protocol (ELASTIC_EXIT_CODE=101 → relaunch with current peers).
-On a single host with N chips the launcher simply runs ONE process: device
-parallelism comes from the mesh, so nproc_per_node exists only for
-CPU-simulation (`--devices cpu --nproc N` sets
+On a single host with N chips the launcher runs ONE process: a chip belongs
+to one process at a time, so a second process on the host would fail or hang
+waiting for it, and device parallelism comes from the mesh.  nproc_per_node
+above 1 is therefore refused except for the CPU simulation
+(`--devices cpu --nproc_per_node N` sets
 xla_force_host_platform_device_count).
 """
 
@@ -53,8 +55,9 @@ def _parse_args(argv=None):
                    default=os.environ.get("PADDLE_MASTER", ""),
                    help="coordinator host:port (first node's address)")
     p.add_argument("--nproc_per_node", type=int, default=1,
-                   help="processes per node (TPU: leave 1 — XLA owns all "
-                        "local chips; >1 only for CPU simulation)")
+                   help="processes per node; above 1 only with --devices "
+                        "cpu (on a TPU host one process drives all local "
+                        "chips)")
     p.add_argument("--devices", type=str, default="",
                    help="'cpu' forces CPU simulation with "
                         "xla_force_host_platform_device_count=nproc_per_node")
@@ -72,7 +75,12 @@ def _parse_args(argv=None):
                         "on relaunch")
     p.add_argument("training_script", type=str)
     p.add_argument("training_script_args", nargs=argparse.REMAINDER)
-    return p.parse_args(argv)
+    args = p.parse_args(argv)
+    if args.nproc_per_node > 1 and args.devices != "cpu":
+        p.error("--nproc_per_node above 1 needs --devices cpu: on a TPU "
+                "host one process drives all local chips, and a second "
+                "process would fail or hang waiting for them")
+    return args
 
 
 def _child_env(args, local_rank: int, world: int, nproc: int) -> dict:
@@ -89,7 +97,6 @@ def _child_env(args, local_rank: int, world: int, nproc: int) -> dict:
         env["PADDLE_ELASTIC_STORE"] = str(args.elastic_store)
     if args.devices == "cpu":
         env["JAX_PLATFORMS"] = "cpu"
-        env["PADDLE_TPU_PLATFORM"] = "cpu"
         prev = env.get("XLA_FLAGS", "")
         if "host_platform_device_count" not in prev:
             env["XLA_FLAGS"] = (prev + " --xla_force_host_platform_device_count="
@@ -172,8 +179,8 @@ def _maybe_host_store(args):
 def launch(argv=None) -> int:
     args = _parse_args(argv)
     nnodes = int(str(args.nnodes).split(":")[0])
-    world = nnodes * args.nproc_per_node if args.devices == "cpu" else nnodes
-    nproc = args.nproc_per_node if args.devices == "cpu" else 1
+    nproc = args.nproc_per_node     # 1 off the CPU simulation (_parse_args)
+    world = nnodes * nproc
     os.makedirs(args.log_dir, exist_ok=True)
     _store_server = _maybe_host_store(args)  # noqa: F841 (lifetime anchor)
 

@@ -63,7 +63,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 __all__ = [
     "CATALOG_VERSION", "ShardingRules", "activation_batch_spec",
-    "batch_spec", "build_param_specs", "build_state_shardings",
+    "attention_specs", "batch_spec", "build_param_specs", "build_state_shardings",
     "make_spec", "match_partition_rules", "override_leading_axis",
     "register_rules", "replica_stacked_spec", "replicated_spec",
     "replication_fallback", "resolve_flat_shard_spec",
@@ -92,6 +92,9 @@ _RULE_CATALOG: Tuple[Tuple[str, str], ...] = (
     ("dp_update", "plain-DP weight-update sharding: flat optimizer shards "
                   "carry a leading replica dim over the dp axis "
                   "(update_sharding.py)"),
+    ("attention", "Pallas attention kernels run per shard: batch over "
+                  "'data' and 'sharding', heads over 'model', each where "
+                  "it divides (attention_specs)"),
     ("flat_residual", "flat comm residuals ride an axis only when the "
                       "length divides; otherwise replicate WITH byte "
                       "accounting (resolve_flat_shard_spec)"),
@@ -144,6 +147,31 @@ def activation_batch_spec(mesh: Mesh) -> Optional[PartitionSpec]:
     if "data" in mesh.shape and mesh.shape["data"] > 1:
         return PartitionSpec("data", None, None)
     return None
+
+
+def attention_specs(mesh: Mesh, batch: int, heads: int):
+    """shard_map layouts for a Pallas attention kernel under ``mesh``
+    (ops/attention.py): the batch dim over the data-parallel axes ("data",
+    "sharding") and the head dim over "model", each only where the axis
+    has size > 1 and divides the dim — an axis that does not divide is
+    left out, so its devices each compute the whole dim (correct, just
+    not split).  Returns ``(specs, sharded_axes)`` with ``specs`` keyed
+    "qkv" for (B, L, H, D), "kmask" for (B, L), "stat" for (B, H, L) and
+    "rep" for replicated operands."""
+    b_axes, split = [], 1
+    for ax in ("data", "sharding"):
+        n = mesh.shape.get(ax, 1)
+        if n > 1 and batch % (split * n) == 0:
+            b_axes.append(ax)
+            split *= n
+    b = tuple(b_axes) if b_axes else None
+    mp = mesh.shape.get("model", 1)
+    h = "model" if mp > 1 and heads % mp == 0 else None
+    specs = {"qkv": PartitionSpec(b, None, h, None),
+             "kmask": PartitionSpec(b, None),
+             "stat": PartitionSpec(b, h, None),
+             "rep": PartitionSpec()}
+    return specs, tuple(b_axes) + ((h,) if h else ())
 
 
 def sep_activation_spec(ndim: int = 4, axis: str = "sep",

@@ -37,7 +37,7 @@ The consumer of all this is the gateway's resilience layer
 dispatch errors injected here, retries/backoff absorb the transient
 window, hedging races the slow straggler, and the stall/crash faults
 drive the quarantine-replay path — docs/RESILIENCE.md walks the whole
-taxonomy.
+classification.
 
 No reference counterpart: the reference snapshot serves static batches
 with no failure model at all (SURVEY §2.3).
@@ -56,7 +56,7 @@ __all__ = ["Fault", "FaultPlan", "FaultyEngine", "FAULT_KINDS",
            "InjectedAllocationError", "FaultInjectionError",
            "torn_write", "corrupt_file"]
 
-#: the typed fault vocabulary (docs/RESILIENCE.md taxonomy table).
+#: the typed fault vocabulary (docs/RESILIENCE.md classification table).
 #: ``torn_write``/``corrupt_file`` are FILESYSTEM faults: FaultyEngine
 #: never fires them; the checkpoint layer
 #: (``train_resilience.CheckpointManager``) consults the plan at save

@@ -228,6 +228,11 @@ class ExecutableCache:
         digest = self._digest(key)
         blob = pickle.dumps((payload, in_tree, out_tree), protocol=4)
         jaxv, jaxlibv = _versions()
+        # the executable's OWN devices: deserialize_and_load otherwise loads
+        # onto every device of the backend, and a one-device program then
+        # refuses its one-shard arguments
+        device_ids = [d.id for d in
+                      compiled._executable._unloaded_executable.device_list]
         with self._lock, self._manifest_write_lock():
             fname = digest + ".bin"
             self._write_atomic(os.path.join(self.dir, fname), blob)
@@ -237,6 +242,7 @@ class ExecutableCache:
                 "key": str(key), "file": fname, "jax": jaxv,
                 "jaxlib": jaxlibv, "backend": self.backend,
                 "mesh": mesh_signature(mesh), "rules": _rules_digest(),
+                "devices": device_ids,
                 "bytes": len(blob), "created_at": time.time()}
             self._write_atomic(self._manifest_path,
                                json.dumps(manifest, indent=2,
@@ -286,7 +292,10 @@ class ExecutableCache:
         try:
             from jax.experimental import serialize_executable as se
             payload, in_tree, out_tree = pickle.loads(blob)
-            compiled = se.deserialize_and_load(payload, in_tree, out_tree)
+            by_id = {d.id: d for d in jax.devices(self.backend)}
+            compiled = se.deserialize_and_load(
+                payload, in_tree, out_tree, backend=self.backend,
+                execution_devices=[by_id[i] for i in entry["devices"]])
         except Exception as e:  # noqa: BLE001 — a corrupt/incompatible
             # payload must degrade to a recompile, never kill serving
             self.invalidated += 1
@@ -321,13 +330,31 @@ class ExecutableCache:
 # XLA compilation-cache fallback wiring
 # ---------------------------------------------------------------------------
 
-def enable_persistent_compilation_cache(cache_dir) -> str:
-    """Point jax's XLA persistent compilation cache at ``<cache_dir>/xla``
-    (created if needed) and drop the min-compile-time / min-entry-size
-    gates so EVERY program persists — serving programs are many and small,
-    and the whole point is that none of them compiles twice.  Idempotent;
-    returns the XLA cache directory."""
-    xla_dir = os.path.join(str(cache_dir), "xla")
+#: where the XLA cache lives when nobody says otherwise: a fixed,
+#: git-ignored directory of the checkout.  The path is part of what a
+#: cached program is found by, so it is never a temporary name, a pid or a
+#: time.
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_persistent_compilation_cache(cache_dir=None) -> str:
+    """Turn on jax's XLA persistent compilation cache and drop the
+    min-compile-time / min-entry-size gates so EVERY program persists —
+    serving programs are many and small, and the whole point is that none
+    of them compiles twice.  Where it lives, one rule:
+
+    1. ``JAX_COMPILATION_CACHE_DIR`` set: there — the cache was placed
+       from outside, jax already reads it, and code sets no other;
+    2. else the caller's ``<cache_dir>/xla``;
+    3. else ``DEFAULT_CACHE_DIR`` (``<checkout>/.jax_cache``).
+
+    Idempotent; returns the XLA cache directory."""
+    xla_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not xla_dir:
+        xla_dir = (DEFAULT_CACHE_DIR if cache_dir is None
+                   else os.path.join(str(cache_dir), "xla"))
     os.makedirs(xla_dir, exist_ok=True)
     changed = False
     if jax.config.jax_compilation_cache_dir != xla_dir:
@@ -344,14 +371,9 @@ def enable_persistent_compilation_cache(cache_dir) -> str:
         # (is_cache_used memoizes per task); wiring the dir after any
         # compile has happened — the normal case for an engine warming
         # post-construction — needs the latch reset or nothing persists
-        try:
-            from jax._src.compilation_cache import reset_cache
-        except ImportError:
-            _log.warning("jax %s has no compilation_cache.reset_cache; "
-                         "programs compiled before this call may not "
-                         "persist", jax.__version__)
-        else:
-            reset_cache()
+        from jax.experimental.compilation_cache.compilation_cache import \
+            reset_cache
+        reset_cache()
     return xla_dir
 
 

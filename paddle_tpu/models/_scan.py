@@ -5,6 +5,7 @@ from __future__ import annotations
 
 def resolve_scan_unroll(config) -> int:
     """Layers per scan step.  1 = rolled loop (O(1) compile in depth);
-    num_layers = fully unrolled (no dynamic_slice/update HBM traffic — see
-    BENCH_NOTES.md, ~11ms/step at gpt2s bench shapes)."""
+    num_layers = fully unrolled (no dynamic_slice/update HBM traffic; a
+    builder's round-2 profile on one v5e put that traffic at ~11 ms/step at
+    the gpt2s bench shapes — not measured since)."""
     return max(1, int(getattr(config, "scan_unroll", 1) or 1))

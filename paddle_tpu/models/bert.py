@@ -145,7 +145,7 @@ class BertModel(Layer):
             h = h + jnp.take(params["type_emb"], token_type_ids, axis=0)
         return self._ln(h, params["emb_ln_w"], params["emb_ln_b"]).astype(dt)
 
-    def block_fn(self, sl: Dict[str, Any], h, attn_mask=None):
+    def block_fn(self, sl: Dict[str, Any], h, attn_mask=None, mesh=None):
         c = self.config
         dt = h.dtype
         B, Lq, H = h.shape
@@ -157,7 +157,8 @@ class BertModel(Layer):
         if c.use_flash_attention:
             # the (B,1,1,L) padding mask rides inside the Pallas kernel as a
             # key mask — no dense fallback (ops/attention.py)
-            att = flash_attention(q, k, v, causal=False, key_mask=attn_mask)
+            att = flash_attention(q, k, v, causal=False, key_mask=attn_mask,
+                                  mesh=mesh)
         else:
             att = dense_attention(q, k, v, mask=attn_mask, causal=False)
         att = att.reshape(B, Lq, H)
@@ -184,19 +185,23 @@ class BertModel(Layer):
                         sl["blocks_ln2_w"], sl["blocks_ln2_b"],
                         sl["blocks_fc2_b"])
 
-    def scan_blocks(self, params, h, attn_mask=None, remat=True):
+    def scan_blocks(self, params, h, attn_mask=None, remat=True, mesh=None):
         stacked = {k: params[k] for k in self.stacked_param_names()}
-        fn = (jax.checkpoint(lambda sl, hh: self.block_fn(sl, hh, attn_mask))
-              if remat else (lambda sl, hh: self.block_fn(sl, hh, attn_mask)))
+
+        def fn(sl, hh):
+            return self.block_fn(sl, hh, attn_mask, mesh=mesh)
+
+        if remat:
+            fn = jax.checkpoint(fn)
         from ._scan import resolve_scan_unroll
         out, _ = jax.lax.scan(lambda carry, sl: (fn(sl, carry), None), h, stacked,
                               unroll=resolve_scan_unroll(self.config))
         return out
 
     def encode(self, params, input_ids, token_type_ids=None, attn_mask=None,
-               remat=False):
+               remat=False, mesh=None):
         h = self.embed_fn(params, input_ids, token_type_ids)
-        return self.scan_blocks(params, h, attn_mask, remat=remat)
+        return self.scan_blocks(params, h, attn_mask, remat=remat, mesh=mesh)
 
     def pool_fn(self, params, h):
         dt = h.dtype
@@ -227,11 +232,13 @@ class BertModel(Layer):
         return (1.0 - attention_mask.astype(jnp.float32))[:, None, None, :] * -1e30
 
     def pretrain_loss_fn(self, params, input_ids, mlm_labels, nsp_labels=None,
-                         token_type_ids=None, attention_mask=None, remat=False):
-        """MLM (ignore label -100) + optional NSP loss."""
+                         token_type_ids=None, attention_mask=None, remat=False,
+                         mesh=None):
+        """MLM (ignore label -100) + optional NSP loss.  ``mesh``: the mesh
+        the step is partitioned over, for the flash kernel."""
         h = self.encode(params, input_ids, token_type_ids,
                         attn_mask=self._additive_mask(attention_mask),
-                        remat=remat)
+                        remat=remat, mesh=mesh)
         logits = self._mlm_logits(params, h)
         valid = mlm_labels >= 0
         safe = jnp.where(valid, mlm_labels, 0)
@@ -269,7 +276,7 @@ def make_bert_train_step(model: BertModel, optimizer, hcg, remat: bool = True,
 
     def loss_of(params, input_ids, mlm_labels, nsp_labels):
         return model.pretrain_loss_fn(params, input_ids, mlm_labels,
-                                      nsp_labels, remat=remat)
+                                      nsp_labels, remat=remat, mesh=hcg.mesh)
 
     return make_gspmd_step_from_loss(loss_of, params0, optimizer, hcg.mesh,
                                      layer=model, donate=donate)
@@ -297,7 +304,7 @@ def make_sharded_bert_train_step(cfg: BertConfig, optimizer, hcg,
 
     def loss_of(params, input_ids, mlm_labels, nsp_labels):
         return meta.pretrain_loss_fn(params, input_ids, mlm_labels,
-                                     nsp_labels, remat=remat)
+                                     nsp_labels, remat=remat, mesh=hcg.mesh)
 
     return make_gspmd_sharded_init_step(loss_of, build, optimizer, hcg.mesh,
                                         meta, zero_stage=zero_stage,

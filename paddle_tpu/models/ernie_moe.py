@@ -165,7 +165,7 @@ class ErnieMoeModel(CausalDecoderMixin, Layer):
     def block_fn(self, sl: Dict[str, Any], h, mesh=None):
         """One block; returns (h, aux_loss)."""
         q, k, v = self._block_qkv(sl, h)
-        att = flash_attention(q, k, v, causal=True)
+        att = flash_attention(q, k, v, causal=True, mesh=mesh)
         h = self._attn_residual(sl, h, att)
         return self._moe_residual(sl, h, mesh=mesh)
 
@@ -257,13 +257,15 @@ class ErnieMoeModel(CausalDecoderMixin, Layer):
         h = self._attn_residual(sl, h, att)
         return self._moe_residual_gather(sl, h), ck, cv
 
-    def prefill(self, params, input_ids, max_len: int, pad_lens=None):
+    def prefill(self, params, input_ids, max_len: int, pad_lens=None,
+                mesh=None):
         """Prompt pass with no-drop routing; returns (h, (ck, cv)) with
         caches filled at [0, P).  Uses the buffered no-drop indices dispatch
         (cf = E/k): at prefill T = B·P is large, so gathering (T, k, H, I)
         weight slices would cost more than the padded buffer does.  With
         ``pad_lens`` (left-padded prompts), pad keys get a finite -1e30 mask
-        and positions shift per row (see GPT.prefill)."""
+        and positions shift per row; ``mesh`` is the serving mesh, for the
+        flash kernel (see GPT.prefill)."""
         c = self.config
         B, P = input_ids.shape
         if pad_lens is None:
@@ -275,7 +277,8 @@ class ErnieMoeModel(CausalDecoderMixin, Layer):
 
         def body(carry, sl):
             q, k, v = self._block_qkv(sl, carry)
-            att = flash_attention(q, k, v, causal=True, key_mask=key_mask)
+            att = flash_attention(q, k, v, causal=True, key_mask=key_mask,
+                                  mesh=mesh)
             hh = self._attn_residual(sl, carry, att)
             hh, _ = self._moe_residual(sl, hh,
                                        capacity_factor=self._nodrop_cf())

@@ -192,17 +192,18 @@ class GPTModel(CausalDecoderMixin, Layer):
                          approximate=self.config.hidden_act == "gelu_approx")
         return h + ff @ sl["blocks_fc2_w"].astype(dt) + sl["blocks_fc2_b"].astype(dt)
 
-    def block_fn(self, sl: Dict[str, Any], h, key=None, sp_mesh=None):
+    def block_fn(self, sl: Dict[str, Any], h, key=None, mesh=None):
         """One transformer block given this layer's parameter slice.
 
-        ``sp_mesh``: when set (by make_gpt_train_step on a mesh with sep>1)
-        attention runs as explicit ring/Ulysses context parallelism over the
-        "sep" axis instead of letting GSPMD gather the sequence."""
+        ``mesh``: the mesh the step is partitioned over (set by the GSPMD
+        and ZeRO builders).  The flash kernel then runs on each device's
+        own rows, and with ``sequence_parallel`` on a mesh with sep>1
+        attention runs as explicit ring/Ulysses context parallelism over
+        the "sep" axis instead of letting GSPMD gather the sequence."""
         c = self.config
         B, Lq, H = h.shape
         q, k, v = self._block_qkv(sl, h)
         sp_mode = getattr(c, "sequence_parallel", None)
-        mesh = sp_mesh
         if sp_mode and mesh is not None and mesh.shape.get("sep", 1) > 1:
             if Lq % mesh.shape["sep"] != 0:
                 # never fall back silently — gathered attention is exactly the
@@ -224,7 +225,7 @@ class GPTModel(CausalDecoderMixin, Layer):
                 out_specs=sep_activation_spec(), axis_names={"sep"},
             )(q, k, v)
         else:
-            att = flash_attention(q, k, v, causal=True)
+            att = flash_attention(q, k, v, causal=True, mesh=mesh)
         return self._block_post_attn(sl, h, att)
 
     def _head_logits(self, params: Dict[str, Any], h):
@@ -250,7 +251,7 @@ class GPTModel(CausalDecoderMixin, Layer):
         from ..ops.loss import softmax_cross_entropy_mean
         return softmax_cross_entropy_mean(self._head_logits(params, h), labels)
 
-    def scan_blocks(self, params, h, key=None, remat=True, sp_mesh=None):
+    def scan_blocks(self, params, h, key=None, remat=True, mesh=None):
         """``remat``: False = save all activations; True = full per-block
         recompute (≙ RecomputeOptimizer, fluid/optimizer.py:5930); "dots" =
         selective policy that saves MXU (matmul) outputs and recomputes only
@@ -262,14 +263,14 @@ class GPTModel(CausalDecoderMixin, Layer):
             if remat == "dots":
                 policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
             fn = jax.checkpoint(
-                lambda sl, hh: self.block_fn(sl, hh, key, sp_mesh=sp_mesh),
+                lambda sl, hh: self.block_fn(sl, hh, key, mesh=mesh),
                 policy=policy)
 
             def body(carry, sl):
                 return fn(sl, carry), None
         else:
             def body(carry, sl):
-                return self.block_fn(sl, carry, key, sp_mesh=sp_mesh), None
+                return self.block_fn(sl, carry, key, mesh=mesh), None
         from ._scan import resolve_scan_unroll
         out, _ = jax.lax.scan(body, h, stacked,
                               unroll=resolve_scan_unroll(self.config))
@@ -312,11 +313,14 @@ class GPTModel(CausalDecoderMixin, Layer):
                                pad_lens=pad_lens)
         return self._block_post_attn(sl, h, att), ck, cv
 
-    def prefill(self, params, input_ids, max_len: int, pad_lens=None):
+    def prefill(self, params, input_ids, max_len: int, pad_lens=None,
+                mesh=None):
         """Run the prompt through all blocks, returning the final hidden
         states (B, P, H) and caches filled at positions [0, P).  With
         ``pad_lens`` (left-padded prompts), embedding positions shift and
-        pad keys are masked (mixin helpers — one canonical convention)."""
+        pad keys are masked (mixin helpers — one canonical convention).
+        ``mesh``: the tensor-parallel serving mesh, for the flash kernel
+        (see block_fn)."""
         c = self.config
         B, P = input_ids.shape
         if pad_lens is None:
@@ -328,7 +332,8 @@ class GPTModel(CausalDecoderMixin, Layer):
 
         def body(carry, sl):
             q, k, v = self._block_qkv(sl, carry)
-            att = flash_attention(q, k, v, causal=True, key_mask=key_mask)
+            att = flash_attention(q, k, v, causal=True, key_mask=key_mask,
+                                  mesh=mesh)
             return self._block_post_attn(sl, carry, att), (k, v)
 
         h, (ks, vs) = jax.lax.scan(body, h, stacked)
@@ -490,7 +495,7 @@ def make_gpt_train_step(model: GPTModel, optimizer, hcg, n_microbatches: int = 1
         h = model.embed_fn(params, x, key)
         if seq_spec is not None:
             h = jax.lax.with_sharding_constraint(h, NamedSharding(mesh, seq_spec))
-        h = model.scan_blocks(params, h, key, remat=remat, sp_mesh=sp_mesh)
+        h = model.scan_blocks(params, h, key, remat=remat, mesh=mesh)
         return model.head_loss_fn(params, h, labels)
 
     raw_step = None
@@ -608,7 +613,7 @@ def make_sharded_gpt_train_step(cfg: GPTConfig, optimizer, hcg,
 
     def loss_of(params, key, x, labels):
         h = meta_model.embed_fn(params, x, key)
-        h = meta_model.scan_blocks(params, h, key, remat=remat)
+        h = meta_model.scan_blocks(params, h, key, remat=remat, mesh=mesh)
         return meta_model.head_loss_fn(params, h, labels)
 
     from ..telemetry import instrument_train_step

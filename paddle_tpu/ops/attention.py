@@ -386,48 +386,102 @@ def _from_bh(x, B, H):
     return x.reshape(B, H, L, D).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
-def _flash_attention(q, k, v, kmask, seed, causal, scale, dropout_p, block):
-    out, _ = _flash_fwd(q, k, v, kmask, seed, causal, scale, dropout_p, block)
+def _per_shard(local, mesh, q_shape, in_kinds, out_kinds):
+    """``local(sharded_axes, *arrays)`` calls Pallas on per-shard arrays;
+    this returns it as a function of the whole arrays.  GSPMD cannot
+    partition a Pallas call, so under a mesh the kernels sit in a
+    ``shard_map`` with the batch split over the data-parallel axes and the
+    heads over "model" (sharding_rules.attention_specs);
+    ``in_kinds``/``out_kinds`` name each operand's layout there.  With no
+    mesh (or one device) ``local`` runs on the arrays as they are."""
+    if mesh is None or mesh.size == 1:
+        return functools.partial(local, ())
+    from ..distributed.sharding_rules import attention_specs
+    from ..distributed.spmd import shard_map
+    specs, axes = attention_specs(mesh, q_shape[0], q_shape[2])
+    return shard_map(functools.partial(local, axes), mesh=mesh,
+                     in_specs=tuple(specs[kd] for kd in in_kinds),
+                     out_specs=tuple(specs[kd] for kd in out_kinds),
+                     check_vma=False)
+
+
+def _shard_seed(seed, axes):
+    """Fold this shard's mesh coordinates into the dropout seed: the
+    in-kernel keep-mask hashes LOCAL (batch·head, row, col) positions, so
+    without this every shard would replay shard 0's masks.  Forward and
+    backward fold identically (same mesh, same axes)."""
+    for ax in axes:
+        seed = seed * jnp.uint32(0x9E3779B1) \
+            + lax.axis_index(ax).astype(jnp.uint32) + jnp.uint32(1)
+    return seed
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
+def _flash_attention(q, k, v, kmask, seed, causal, scale, dropout_p, block,
+                     mesh=None):
+    out, _ = _flash_fwd(q, k, v, kmask, seed, causal, scale, dropout_p, block,
+                        mesh)
     return out
 
 
-def _flash_fwd(q, k, v, kmask, seed, causal, scale, dropout_p, block):
-    B, L, H, D = q.shape
+def _flash_fwd(q, k, v, kmask, seed, causal, scale, dropout_p, block, mesh):
+    """Returns (out (B,L,H,D), lse (B,H,L))."""
     interpret = jax.default_backend() != "tpu"
-    out, lse = _flash_fwd_pallas(
-        _to_bh(q), _to_bh(k), _to_bh(v), kmask, seed, causal, scale,
-        dropout_p, block, block, H, interpret)
-    return _from_bh(out, B, H), lse
+
+    def local(axes, q, k, v, kmask, seed):
+        B, L, H, D = q.shape
+        out, lse = _flash_fwd_pallas(
+            _to_bh(q), _to_bh(k), _to_bh(v), kmask, _shard_seed(seed, axes),
+            causal, scale, dropout_p, block, block, H, interpret)
+        return _from_bh(out, B, H), lse.reshape(B, H, L)
+
+    return _per_shard(local, mesh, q.shape,
+                      ("qkv", "qkv", "qkv", "kmask", "rep"),
+                      ("qkv", "stat"))(q, k, v, kmask, seed)
 
 
-def _flash_fwd_rule(q, k, v, kmask, seed, causal, scale, dropout_p, block):
-    out, lse = _flash_fwd(q, k, v, kmask, seed, causal, scale, dropout_p, block)
+def _flash_fwd_rule(q, k, v, kmask, seed, causal, scale, dropout_p, block,
+                    mesh):
+    out, lse = _flash_fwd(q, k, v, kmask, seed, causal, scale, dropout_p,
+                          block, mesh)
     return out, (q, k, v, kmask, seed, out, lse)
 
 
-def _flash_bwd_rule(causal, scale, dropout_p, block, res, g):
+def _flash_bwd_rule(causal, scale, dropout_p, block, mesh, res, g):
     q, k, v, kmask, seed, out, lse = res
-    B, L, H, D = q.shape
     interpret = jax.default_backend() != "tpu"
-    do = _to_bh(g)
-    o = _to_bh(out)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    dq, dk, dv = _flash_bwd_pallas(
-        _to_bh(q), _to_bh(k), _to_bh(v), kmask, seed, do, lse, delta,
-        causal, scale, dropout_p, block, block, H, interpret)
-    return (_from_bh(dq, B, H).astype(q.dtype),
-            _from_bh(dk, B, H).astype(k.dtype),
-            _from_bh(dv, B, H).astype(v.dtype),
-            jnp.zeros_like(kmask), None)
+
+    def local(axes, q, k, v, kmask, seed, out, lse, g):
+        B, L, H, D = q.shape
+        do = _to_bh(g)
+        o = _to_bh(out)
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1)
+        dq, dk, dv = _flash_bwd_pallas(
+            _to_bh(q), _to_bh(k), _to_bh(v), kmask, _shard_seed(seed, axes),
+            do, lse.reshape(B * H, L), delta, causal, scale, dropout_p,
+            block, block, H, interpret)
+        return (_from_bh(dq, B, H).astype(q.dtype),
+                _from_bh(dk, B, H).astype(k.dtype),
+                _from_bh(dv, B, H).astype(v.dtype))
+
+    dq, dk, dv = _per_shard(
+        local, mesh, q.shape,
+        ("qkv", "qkv", "qkv", "kmask", "rep", "qkv", "stat", "qkv"),
+        ("qkv", "qkv", "qkv"))(q, k, v, kmask, seed, out, lse, g)
+    return dq, dk, dv, jnp.zeros_like(kmask), None
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def flash_attention(q, k, v, causal=False, scale=None, key_mask=None,
-                    dropout_p=0.0, dropout_seed=None):
+                    dropout_p=0.0, dropout_seed=None, mesh=None):
     """Public flash attention on raw arrays, (B,L,H,D).
+
+    mesh: the ``jax.sharding.Mesh`` the caller's program is partitioned
+    over, when it is — the Pallas kernels then run on each device's own
+    batch rows and heads (see _per_shard); the dense path needs no mesh.
 
     key_mask: optional additive mask over keys, shape (B, Lk) (or any shape
     reshapeable to it, e.g. the BERT (B,1,1,Lk) padding mask).  dropout_p
@@ -459,7 +513,7 @@ def flash_attention(q, k, v, causal=False, scale=None, key_mask=None,
         seed = (jnp.zeros((1,), jnp.uint32) if dropout_seed is None
                 else jnp.asarray(dropout_seed, jnp.uint32).reshape(1))
         return _flash_attention(q, k, v, kmask, seed, causal, scale,
-                                float(dropout_p), block)
+                                float(dropout_p), block, mesh)
     mask4 = None if key_mask is None else \
         key_mask.reshape(B, 1, 1, k.shape[1]).astype(jnp.float32)
     dkey = None

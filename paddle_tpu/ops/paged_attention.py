@@ -45,21 +45,20 @@ def _paged_decode_kernel(table_ref, t_ref, pad_ref, q_ref, k_ref, v_ref,
         q = q_ref[0].astype(jnp.float32) * scale       # (nh, hd)
         k = k_ref[0].astype(jnp.float32)               # (bs, nh, hd)
         v = v_ref[0].astype(jnp.float32)
-        # scores (nh, bs): contract hd, batch over heads
-        sc = lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                             preferred_element_type=jnp.float32)
-        pos = j * bs + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        # Scores and the weighted sum are broadcast-multiply-and-reduce,
+        # all rank 3 with heads on sublanes: Mosaic refuses the head-batched
+        # dot_general whose rank-2 left operand has no free dimension, and
+        # one query row could not fill the MXU anyway.
+        sc = jnp.sum(q[None] * k, axis=-1, keepdims=True)   # (bs, nh, 1)
+        pos = j * bs + lax.broadcasted_iota(jnp.int32, sc.shape, 0)
         valid = (pos <= t_ref[s]) & (pos >= pad_ref[s])
         sc = jnp.where(valid, sc, _NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        p = jnp.exp(sc - m_new)                        # (nh, bs)
+        m_prev = m_ref[:]                              # (nh, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))
+        p = jnp.exp(sc - m_new[None])                  # (bs, nh, 1)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
-        # (nh, hd): contract positions, batch over heads
-        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=0)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.sum(p * v, axis=0)
         m_ref[:] = m_new
 
     # columns past the clock: the clamped index map (see in_specs) makes

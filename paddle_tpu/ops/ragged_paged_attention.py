@@ -100,21 +100,18 @@ def _ragged_kernel(table_ref, seq_ref, pos_ref, pad_ref, q_ref, *rest,
         if quantized:                                  # fused dequant
             k = k * ks_ref[0].astype(jnp.float32)[..., None]
             v = v * vs_ref[0].astype(jnp.float32)[..., None]
-        # scores (nh, bs): contract hd, batch over heads
-        sc = lax.dot_general(q, k, (((1,), (2,)), ((0,), (1,))),
-                             preferred_element_type=jnp.float32)
-        pos = j * bs + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        # broadcast-multiply-and-reduce, rank 3 with heads on sublanes —
+        # see ops/paged_attention.py for why not a head-batched dot_general
+        sc = jnp.sum(q[None] * k, axis=-1, keepdims=True)   # (bs, nh, 1)
+        pos = j * bs + lax.broadcasted_iota(jnp.int32, sc.shape, 0)
         valid = (pos <= pos_ref[i]) & (pos >= pad_ref[seq_ref[i]])
         sc = jnp.where(valid, sc, _NEG_INF)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        p = jnp.exp(sc - m_new)                        # (nh, bs)
+        m_prev = m_ref[:]                              # (nh, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=0))
+        p = jnp.exp(sc - m_new[None])                  # (bs, nh, 1)
         alpha = jnp.exp(m_prev - m_new)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
-        # (nh, hd): contract positions, batch over heads
-        acc_ref[:] = acc_ref[:] * alpha + lax.dot_general(
-            p, v, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=0)
+        acc_ref[:] = acc_ref[:] * alpha + jnp.sum(p * v, axis=0)
         m_ref[:] = m_new
 
     # columns past the row's kv position: the clamped index map re-fetches

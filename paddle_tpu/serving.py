@@ -536,7 +536,8 @@ class ContinuousBatchingEngine:
         def run(params, big_ck, big_cv, ids, pad_len, slot, key, presence,
                 planes):
             h, (ck, cv) = model.prefill(params, ids, P,
-                                        pad_lens=pad_len[None])
+                                        pad_lens=pad_len[None],
+                                        mesh=self.mesh)
 
             put = _slot_write(slot)
             big_ck = jax.tree.map(put, big_ck, ck)

@@ -1113,8 +1113,7 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
 
     The parent engine compiles a prefill program per (bucket, prefix
     depth) plus a separate decode family — prefill and decode tokens can
-    never share a step, and every new bucket pays a fresh compile (the
-    compile dial that has repeatedly eaten bench rounds; HEALTH.log).
+    never share a step, and every new bucket pays a fresh compile.
     This engine instead packs every step into ONE flattened ragged token
     batch of at most ``token_budget`` rows:
 

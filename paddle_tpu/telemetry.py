@@ -63,8 +63,7 @@ Exports:
 Recompile visibility: every program-cache miss after the engine's first
 completed tick is counted as a *post-warmup* recompile; once
 ``recompile_warn_threshold`` of them accumulate the tracer logs ONE warning
-(the recompile-storm dial that has repeatedly eaten bench rounds —
-HEALTH.log).
+(a recompile storm can eat a whole benchmark run).
 
 Training side (``TrainMonitor``, built on the same ring-buffer Tracer —
 the Paddle-profiler/fleet-metrics role for the TRAIN loop):

@@ -74,7 +74,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 __all__ = ["RunLedger", "FlightRecorder", "BUCKETS", "set_active_ledger",
            "current_ledger", "ledger_span", "chrome_counters_from_dump"]
 
-#: The exhaustive bucket taxonomy, in display order.  ``unattributed`` is
+#: The exhaustive bucket classification, in display order.  ``unattributed`` is
 #: derived (elapsed − attributed), never recorded directly.
 BUCKETS: Tuple[str, ...] = (
     "compute", "data_wait", "host_dispatch", "compile", "checkpoint_save",

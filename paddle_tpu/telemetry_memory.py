@@ -75,7 +75,7 @@ __all__ = ["MemoryLedger", "POOLS", "SPACES", "KV_TIERS",
            "account_bytes", "live_array_census", "device_allocator_stats",
            "chrome_counters_from_memory_dump"]
 
-#: The exhaustive pool taxonomy, in display order.  ``other`` is the
+#: The exhaustive pool classification, in display order.  ``other`` is the
 #: census residual — live arrays nothing registered — never written to
 #: directly.
 POOLS: Tuple[str, ...] = (

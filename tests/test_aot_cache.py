@@ -62,9 +62,12 @@ def _serve(eng, prompt=(1, 2, 3, 4), n=3):
 
 
 @pytest.fixture
-def restore_compilation_cache():
+def restore_compilation_cache(monkeypatch):
     """enable_persistent_compilation_cache mutates process-global jax
-    config; put it back so later tests see the default state."""
+    config; put it back so later tests see the default state.  The tests
+    that use it place the cache themselves, so a cache placed from outside
+    (JAX_COMPILATION_CACHE_DIR, which would win) is taken away."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     prev = jax.config.jax_compilation_cache_dir
     yield
     jax.config.update("jax_compilation_cache_dir", prev)
@@ -116,6 +119,52 @@ class TestKeyHelper:
                 assert pow2_bucket(need, cap) in pow2_grid(cap), (need, cap)
 
 
+# --------------------------------------------------- where the cache goes --
+
+class TestCacheDirRule:
+    """One rule (jit/aot.py): JAX_COMPILATION_CACHE_DIR, else the caller's
+    directory, else a fixed directory of the checkout."""
+
+    def test_env_wins_and_code_sets_no_other(self, tmp_path, monkeypatch,
+                                             restore_compilation_cache):
+        from paddle_tpu.jit.aot import enable_persistent_compilation_cache
+        outside = str(tmp_path / "outside")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+        jax.config.update("jax_compilation_cache_dir", outside)  # as at import
+        got = enable_persistent_compilation_cache(tmp_path / "caller")
+        assert got == outside == jax.config.jax_compilation_cache_dir
+        assert os.path.isdir(outside)
+        assert not (tmp_path / "caller").exists()
+
+    def test_callers_directory_without_env(self, tmp_path,
+                                           restore_compilation_cache):
+        from paddle_tpu.jit.aot import enable_persistent_compilation_cache
+        got = enable_persistent_compilation_cache(tmp_path)
+        assert got == os.path.join(str(tmp_path), "xla")
+        assert jax.config.jax_compilation_cache_dir == got
+
+    def test_checkout_directory_without_either(self, monkeypatch, tmp_path,
+                                               restore_compilation_cache):
+        from paddle_tpu.jit import aot
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert aot.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        with open(os.path.join(repo, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+        # the rule itself, without writing into the checkout
+        monkeypatch.setattr(aot, "DEFAULT_CACHE_DIR",
+                            str(tmp_path / ".jax_cache"))
+        got = aot.enable_persistent_compilation_cache()
+        assert got == str(tmp_path / ".jax_cache") and os.path.isdir(got)
+        assert jax.config.jax_compilation_cache_dir == got
+
+    def test_every_program_persists(self, tmp_path,
+                                    restore_compilation_cache):
+        from paddle_tpu.jit.aot import enable_persistent_compilation_cache
+        enable_persistent_compilation_cache(tmp_path)
+        assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+        assert jax.config.jax_persistent_cache_min_entry_size_bytes == -1
+
+
 # ------------------------------------------------------ persistent cache --
 
 class TestExecutableCache:
@@ -136,6 +185,33 @@ class TestExecutableCache:
         np.testing.assert_array_equal(np.asarray(got(x)), want)
         # second-level in-process cache: same object, no re-deserialize
         assert fresh.get("prog") is got and fresh.hits_memory == 1
+
+    def test_entry_records_the_executables_own_devices(self, tmp_path):
+        """jax reloads a serialized executable onto every device of the
+        backend unless told which: the entry keeps the program's own."""
+        dev = jax.devices()[3]
+        f = jax.jit(lambda x: x + 1)
+        x = jax.device_put(jnp.arange(4.0), dev)    # committed: f follows it
+        cache = ExecutableCache(tmp_path)
+        assert cache.put("on3", f.lower(x).compile())
+        assert cache.entries()[0]["devices"] == [dev.id]
+        got = ExecutableCache(tmp_path).get("on3")
+        out = got(x)
+        assert out.devices() == {dev}
+        np.testing.assert_array_equal(np.asarray(out), [1.0, 2.0, 3.0, 4.0])
+
+    def test_entry_without_devices_degrades_to_recompile(self, tmp_path):
+        compiled, _ = self._compiled()
+        ExecutableCache(tmp_path).put("prog", compiled)
+        path = os.path.join(str(tmp_path), "manifest.json")
+        with open(path) as fh:
+            manifest = json.load(fh)
+        for entry in manifest["entries"].values():
+            del entry["devices"]                 # an entry from before
+        with open(path, "w") as fh:
+            json.dump(manifest, fh)
+        fresh = ExecutableCache(tmp_path)
+        assert fresh.get("prog") is None and fresh.invalidated == 1
 
     def test_miss_is_none(self, tmp_path):
         cache = ExecutableCache(tmp_path)

@@ -295,6 +295,16 @@ class TestStatic:
 
 
 class TestDevice:
+    def test_local_devices_raises_for_a_platform_that_is_not_there(self):
+        """Never a quiet move to the default platform: code that asked for
+        a TPU and got the CPU would measure the wrong machine."""
+        from paddle_tpu.core.device import local_devices
+        with pytest.raises(RuntimeError, match="tpu"):
+            local_devices("tpu")
+        assert [d.platform for d in local_devices("cpu")] == ["cpu"] * 8
+        import jax
+        assert local_devices() == jax.devices()
+
     def test_device_api(self):
         dev = paddle.device.get_device()
         assert ":" in dev
