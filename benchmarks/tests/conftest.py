@@ -1,0 +1,15 @@
+"""Tests of the benchmark's own yardstick.  They run on the CPU:
+
+    JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q -p no:cacheprovider
+
+Nothing here touches a TPU library at import.
+"""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
