@@ -480,7 +480,7 @@ def bench_gpt_serving(on_tpu):
     includes admission, scheduling, paging, and preemption overheads.
     MFU/roofline attribution comes from the compile-seam cost analysis
     (telemetry attribute_cost): per-dispatch model FLOPs over tick wall
-    — ``mfu`` needs a configured peak (PADDLE_TPU_PEAK_FLOPS), the raw
+    — ``mfu`` needs ``Tracer(peak_flops=)`` and is null here, the raw
     model-FLOPs/s and arithmetic intensity report regardless.
     vs_baseline is null — the reference publishes no serving figure.
     PADDLE_TPU_DECODE_KV=int8 A/Bs the quantized pool."""
@@ -594,8 +594,8 @@ def bench_gpt_serving(on_tpu):
 
     out = {"metric": "gpt_serving_tokens_per_sec",
             "value": round(total / dt, 1), "unit": "tokens/s/chip",
-            # null unless PADDLE_TPU_PEAK_FLOPS declares the roofline;
-            # the raw model-FLOPs attribution reports either way
+            # null: this cell gives its tracer no peak; the raw
+            # model-FLOPs attribution reports either way
             "mfu": mfu["mfu"],
             "vs_baseline": None, "vs_a100_flops": None,
             "loss": 0.0, "backend": "tpu" if on_tpu else "cpu",

@@ -244,8 +244,9 @@ def make_spmd_train_step(layer, loss_fn, optimizer, hcg, zero_stage: int = 0,
             (loss, (new_b, _)), grads = jax.value_and_grad(loss_of, has_aux=True)(
                 state["params"], state["buffers"], key, inputs, labels)
         grads, comm_state = apply_policy_local(policy, grads, state)
-        new_params, new_opt = optimizer.update(grads, state["opt"], state["params"],
-                                               lr=lr)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt = optimizer.update(
+                grads, state["opt"], state["params"], lr=lr)
         # keep shardings stable across steps
         new_params = jax.lax.with_sharding_constraint(
             new_params, {k: NamedSharding(mesh, p_specs[k]) for k in new_params})
@@ -275,8 +276,9 @@ def _make_gspmd_step(loss_of, optimizer, mesh, p_specs, donate,
     def step(state, lr, *batch):
         loss, grads = jax.value_and_grad(loss_of)(state["params"], *batch)
         grads, comm_state = apply_policy_local(policy, grads, state)
-        new_params, new_opt = optimizer.update(grads, state["opt"],
-                                               state["params"], lr=lr)
+        with jax.named_scope("optimizer"):  # a region, like the model's
+            new_params, new_opt = optimizer.update(grads, state["opt"],
+                                                   state["params"], lr=lr)
         new_params = jax.lax.with_sharding_constraint(
             new_params, {k: NamedSharding(mesh, p_specs[k]) for k in new_params})
         return {"params": new_params, "opt": new_opt, "buffers": {},

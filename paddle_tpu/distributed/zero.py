@@ -222,7 +222,9 @@ def make_zero_train_step(loss_of: Callable, params0: Dict[str, Any], optimizer,
 
         upd_params = {k: state["master"].get(k, p)
                       for k, p in state["params"].items()}
-        new_upd, new_opt = optimizer.update(grads, state["opt"], upd_params, lr=lr)
+        with jax.named_scope("optimizer"):  # a region, like the model's
+            new_upd, new_opt = optimizer.update(grads, state["opt"],
+                                                upd_params, lr=lr)
 
         def sel(new, old):
             return jax.tree_util.tree_map(
@@ -323,7 +325,8 @@ def make_zero_offload_train_step(loss_of: Callable, params0: Dict[str, Any],
 
     @jax.jit
     def host_phase(grads, opt, master, lr, found_inf):
-        new_upd, new_opt = optimizer.update(grads, opt, master, lr=lr)
+        with jax.named_scope("optimizer"):
+            new_upd, new_opt = optimizer.update(grads, opt, master, lr=lr)
 
         def sel(new, old):
             return jax.tree_util.tree_map(

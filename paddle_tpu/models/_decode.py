@@ -173,15 +173,17 @@ def ragged_write(pool, chunk, table, row_seq, row_pos):
     and write both planes (quantize_kv layout)."""
     if isinstance(pool, tuple):
         vals, scales = pool
-        q, s = quantize_kv(chunk)
+        with jax.named_scope("kv_write"):
+            q, s = quantize_kv(chunk)
         return (ragged_write(vals, q, table, row_seq, row_pos),
                 ragged_write(scales, s, table, row_seq, row_pos))
-    bs = pool.shape[1]
-    seq = jnp.clip(row_seq, 0, table.shape[0] - 1)
-    col = jnp.clip(row_pos // bs, 0, table.shape[1] - 1)
-    pb = jnp.where(row_pos >= 0, table[seq, col], 0)
-    off = jnp.where(row_pos >= 0, row_pos % bs, 0)
-    return pool.at[pb, off].set(chunk.astype(pool.dtype))
+    with jax.named_scope("kv_write"):
+        bs = pool.shape[1]
+        seq = jnp.clip(row_seq, 0, table.shape[0] - 1)
+        col = jnp.clip(row_pos // bs, 0, table.shape[1] - 1)
+        pb = jnp.where(row_pos >= 0, table[seq, col], 0)
+        off = jnp.where(row_pos >= 0, row_pos % bs, 0)
+        return pool.at[pb, off].set(chunk.astype(pool.dtype))
 
 
 def write_cache(cache, chunk, t):
@@ -192,23 +194,24 @@ def write_cache(cache, chunk, t):
     ``cache`` may be a quantized pair ``(values_int8, scales)`` (see
     ``quantize_kv``) — the chunk is quantized and both planes written —
     or a ``PagedKV`` (block-pool writes through the slot table)."""
-    if isinstance(cache, PagedKV):
-        return cache.write(chunk, t)
-    if isinstance(cache, tuple):
-        vals, scales = cache
-        q, s = quantize_kv(chunk)
-        return (write_cache(vals, q, t), write_cache(scales, s, t))
-    t_arr = jnp.asarray(t)
-    if t_arr.ndim == 0:
-        # rank-generic: the int8 scale plane is (B, T, nh), one rank short
-        # of the (B, T, nh, hd) value plane
-        return jax.lax.dynamic_update_slice(
-            cache, chunk.astype(cache.dtype),
-            (0, t_arr) + (0,) * (cache.ndim - 2))
-    B, kq = chunk.shape[:2]
-    rows = jnp.arange(B)[:, None]
-    slots = t_arr[:, None] + jnp.arange(kq)[None, :]
-    return cache.at[rows, slots].set(chunk.astype(cache.dtype))
+    with jax.named_scope("kv_write"):
+        if isinstance(cache, PagedKV):
+            return cache.write(chunk, t)
+        if isinstance(cache, tuple):
+            vals, scales = cache
+            q, s = quantize_kv(chunk)
+            return (write_cache(vals, q, t), write_cache(scales, s, t))
+        t_arr = jnp.asarray(t)
+        if t_arr.ndim == 0:
+            # rank-generic: the int8 scale plane is (B, T, nh), one rank
+            # short of the (B, T, nh, hd) value plane
+            return jax.lax.dynamic_update_slice(
+                cache, chunk.astype(cache.dtype),
+                (0, t_arr) + (0,) * (cache.ndim - 2))
+        B, kq = chunk.shape[:2]
+        rows = jnp.arange(B)[:, None]
+        slots = t_arr[:, None] + jnp.arange(kq)[None, :]
+        return cache.at[rows, slots].set(chunk.astype(cache.dtype))
 
 
 def quantize_kv(x):
@@ -460,10 +463,11 @@ class CausalDecoderMixin:
         length so real tokens get logical positions 0..n-1."""
         dt = jnp.dtype(self.config.compute_dtype)
         P = input_ids.shape[1]
-        pos = jnp.maximum(jnp.arange(P)[None, :] - pad_lens[:, None], 0)
-        h = jnp.take(params["wte"], input_ids, axis=0) \
-            + jnp.take(params["wpe"], pos, axis=0)
-        return h.astype(dt)
+        with jax.named_scope("embed"):
+            pos = jnp.maximum(jnp.arange(P)[None, :] - pad_lens[:, None], 0)
+            h = jnp.take(params["wte"], input_ids, axis=0) \
+                + jnp.take(params["wpe"], pos, axis=0)
+            return h.astype(dt)
 
     @staticmethod
     def _prefill_key_mask(P, pad_lens):
@@ -499,15 +503,16 @@ class CausalDecoderMixin:
         (B,)): (B,) -> (B, 1, H).  With left-padded prompts the LOGICAL
         position is t - pad_lens[b]."""
         dt = jnp.dtype(self.config.compute_dtype)
-        wte = jnp.take(params["wte"], tok[:, None], axis=0)
-        t_arr = jnp.asarray(t)
-        if pad_lens is not None:
-            wpe = params["wpe"][t_arr - pad_lens][:, None, :]
-        elif t_arr.ndim == 0:
-            wpe = params["wpe"][t_arr][None, None, :]
-        else:
-            wpe = params["wpe"][t_arr][:, None, :]
-        return (wte + wpe).astype(dt)
+        with jax.named_scope("embed"):
+            wte = jnp.take(params["wte"], tok[:, None], axis=0)
+            t_arr = jnp.asarray(t)
+            if pad_lens is not None:
+                wpe = params["wpe"][t_arr - pad_lens][:, None, :]
+            elif t_arr.ndim == 0:
+                wpe = params["wpe"][t_arr][None, None, :]
+            else:
+                wpe = params["wpe"][t_arr][:, None, :]
+            return (wte + wpe).astype(dt)
 
     def init_cache(self, batch_size: int, max_len: int):
         c = self.config
@@ -676,11 +681,12 @@ class CausalDecoderMixin:
         Logical positions shift by the owning sequence's pad (the
         _embed_one/_embed_chunk convention); returns (1, T, H)."""
         dt = jnp.dtype(self.config.compute_dtype)
-        seq = jnp.clip(row_seq, 0, pad_lens.shape[0] - 1)
-        pos = jnp.clip(row_pos - pad_lens[seq], 0,
-                       params["wpe"].shape[0] - 1)
-        h = jnp.take(params["wte"], toks, axis=0) + params["wpe"][pos]
-        return h[None].astype(dt)
+        with jax.named_scope("embed"):
+            seq = jnp.clip(row_seq, 0, pad_lens.shape[0] - 1)
+            pos = jnp.clip(row_pos - pad_lens[seq], 0,
+                           params["wpe"].shape[0] - 1)
+            h = jnp.take(params["wte"], toks, axis=0) + params["wpe"][pos]
+            return h[None].astype(dt)
 
     def generate_speculative(self, params, input_ids, max_new_tokens: int,
                              draft_model, draft_params, draft_k: int = 4,

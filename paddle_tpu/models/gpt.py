@@ -153,12 +153,20 @@ class GPTModel(CausalDecoderMixin, Layer):
                                         "proj_w", "proj_b", "ln2_w", "ln2_b",
                                         "fc1_w", "fc1_b", "fc2_w", "fc2_b")]
 
+    # Named regions (``jax.named_scope``): embed / layers {attn {kv_write,
+    # <kernel>}, mlp} / head are the one vocabulary the training step and
+    # the serving programs share.  They are metadata on the operations (the
+    # ``op_name`` of the HLO, the ``tf_op`` of a profiler trace): a device
+    # operation is attributed to the innermost region on its path.
+
     def embed_fn(self, params: Dict[str, Any], input_ids, key=None):
         c = self.config
         dt = jnp.dtype(c.compute_dtype)
-        pos = jnp.arange(input_ids.shape[-1])
-        h = jnp.take(params["wte"], input_ids, axis=0) + params["wpe"][pos]
-        return h.astype(dt)
+        with jax.named_scope("embed"):
+            pos = jnp.arange(input_ids.shape[-1])
+            h = jnp.take(params["wte"], input_ids, axis=0) \
+                + params["wpe"][pos]
+            return h.astype(dt)
 
     def _block_ln(self, x, w, b, dt):
         x32 = x.astype(jnp.float32)
@@ -181,16 +189,23 @@ class GPTModel(CausalDecoderMixin, Layer):
                 v.reshape(B, Lq, nh, hd))
 
     def _block_post_attn(self, sl, h, att):
-        """attention output projection + residual + MLP half of the block."""
+        """attention output projection + residual (the end of the block's
+        ``attn`` region), then the MLP half of the block (``mlp``)."""
         dt = h.dtype
         B, Lq, H = h.shape
-        att = att.reshape(B, Lq, H)
-        h = h + att @ sl["blocks_proj_w"].astype(dt) + sl["blocks_proj_b"].astype(dt)
-        m_in = self._block_ln(h, sl["blocks_ln2_w"], sl["blocks_ln2_b"], dt)
-        ff = jax.nn.gelu(m_in @ sl["blocks_fc1_w"].astype(dt)
-                         + sl["blocks_fc1_b"].astype(dt),
-                         approximate=self.config.hidden_act == "gelu_approx")
-        return h + ff @ sl["blocks_fc2_w"].astype(dt) + sl["blocks_fc2_b"].astype(dt)
+        with jax.named_scope("attn"):
+            att = att.reshape(B, Lq, H)
+            h = h + att @ sl["blocks_proj_w"].astype(dt) \
+                + sl["blocks_proj_b"].astype(dt)
+        with jax.named_scope("mlp"):
+            m_in = self._block_ln(h, sl["blocks_ln2_w"], sl["blocks_ln2_b"],
+                                  dt)
+            ff = jax.nn.gelu(
+                m_in @ sl["blocks_fc1_w"].astype(dt)
+                + sl["blocks_fc1_b"].astype(dt),
+                approximate=self.config.hidden_act == "gelu_approx")
+            return h + ff @ sl["blocks_fc2_w"].astype(dt) \
+                + sl["blocks_fc2_b"].astype(dt)
 
     def block_fn(self, sl: Dict[str, Any], h, key=None, mesh=None):
         """One transformer block given this layer's parameter slice.
@@ -200,6 +215,12 @@ class GPTModel(CausalDecoderMixin, Layer):
         own rows, and with ``sequence_parallel`` on a mesh with sep>1
         attention runs as explicit ring/Ulysses context parallelism over
         the "sep" axis instead of letting GSPMD gather the sequence."""
+        with jax.named_scope("attn"):
+            att = self._block_attention(sl, h, mesh)
+        return self._block_post_attn(sl, h, att)
+
+    def _block_attention(self, sl, h, mesh):
+        """pre-LN + QKV + attention of ``block_fn``: (B, L, nh, hd)."""
         c = self.config
         B, Lq, H = h.shape
         q, k, v = self._block_qkv(sl, h)
@@ -226,20 +247,21 @@ class GPTModel(CausalDecoderMixin, Layer):
             )(q, k, v)
         else:
             att = flash_attention(q, k, v, causal=True, mesh=mesh)
-        return self._block_post_attn(sl, h, att)
+        return att
 
     def _head_logits(self, params: Dict[str, Any], h):
         c = self.config
-        x32 = h.astype(jnp.float32)
-        m = x32.mean(-1, keepdims=True)
-        v = x32.var(-1, keepdims=True)
-        h = (x32 - m) * jax.lax.rsqrt(v + c.layer_norm_epsilon) * params["lnf_w"] \
-            + params["lnf_b"]
-        w = params.get("lm_head")
-        if w is None:
-            w = params["wte"].T
-        dt = jnp.dtype(c.compute_dtype)
-        return h.astype(dt) @ w.astype(dt)
+        with jax.named_scope("head"):
+            x32 = h.astype(jnp.float32)
+            m = x32.mean(-1, keepdims=True)
+            v = x32.var(-1, keepdims=True)
+            h = (x32 - m) * jax.lax.rsqrt(v + c.layer_norm_epsilon) \
+                * params["lnf_w"] + params["lnf_b"]
+            w = params.get("lm_head")
+            if w is None:
+                w = params["wte"].T
+            dt = jnp.dtype(c.compute_dtype)
+            return h.astype(dt) @ w.astype(dt)
 
     def head_fn(self, params: Dict[str, Any], h):
         return self._head_logits(params, h).astype(jnp.float32)
@@ -249,7 +271,9 @@ class GPTModel(CausalDecoderMixin, Layer):
         # (B, L, V) log-prob tensor (ops/loss.py — ≙ the reference's fused
         # softmax_with_cross_entropy, operators/math/cross_entropy.cu)
         from ..ops.loss import softmax_cross_entropy_mean
-        return softmax_cross_entropy_mean(self._head_logits(params, h), labels)
+        with jax.named_scope("head"):
+            return softmax_cross_entropy_mean(self._head_logits(params, h),
+                                              labels)
 
     def scan_blocks(self, params, h, key=None, remat=True, mesh=None):
         """``remat``: False = save all activations; True = full per-block
@@ -272,8 +296,9 @@ class GPTModel(CausalDecoderMixin, Layer):
             def body(carry, sl):
                 return self.block_fn(sl, carry, key, mesh=mesh), None
         from ._scan import resolve_scan_unroll
-        out, _ = jax.lax.scan(body, h, stacked,
-                              unroll=resolve_scan_unroll(self.config))
+        with jax.named_scope("layers"):
+            out, _ = jax.lax.scan(body, h, stacked,
+                                  unroll=resolve_scan_unroll(self.config))
         return out
 
     # ------------------------------------------------------------- nn.Layer
@@ -302,15 +327,17 @@ class GPTModel(CausalDecoderMixin, Layer):
         Returns (h_out, ck, cv) with the new k/v written at index t and
         attention taken over cache positions ≤ t (later slots hold zeros or
         stale values — and left-pad slots, when pad_lens is set — masked)."""
-        q, k, v = self._block_qkv(sl, h)
-        ck = write_cache(ck, k, t)
-        cv = write_cache(cv, v, t)
-        # int8 caches dequantize here; XLA fuses the convert*scale into the
-        # attention einsum's operand read (no fp cache copy materializes)
-        dt = q.dtype
-        att = cached_attention(q, dequantize_cache(ck, dt),
-                               dequantize_cache(cv, dt), t,
-                               pad_lens=pad_lens)
+        with jax.named_scope("attn"):
+            q, k, v = self._block_qkv(sl, h)
+            ck = write_cache(ck, k, t)
+            cv = write_cache(cv, v, t)
+            # int8 caches dequantize here; XLA fuses the convert*scale into
+            # the attention einsum's operand read (no fp cache copy
+            # materializes)
+            dt = q.dtype
+            att = cached_attention(q, dequantize_cache(ck, dt),
+                                   dequantize_cache(cv, dt), t,
+                                   pad_lens=pad_lens)
         return self._block_post_attn(sl, h, att), ck, cv
 
     def prefill(self, params, input_ids, max_len: int, pad_lens=None,
@@ -331,12 +358,14 @@ class GPTModel(CausalDecoderMixin, Layer):
         stacked = {k: params[k] for k in self.stacked_param_names()}
 
         def body(carry, sl):
-            q, k, v = self._block_qkv(sl, carry)
-            att = flash_attention(q, k, v, causal=True, key_mask=key_mask,
-                                  mesh=mesh)
+            with jax.named_scope("attn"):
+                q, k, v = self._block_qkv(sl, carry)
+                att = flash_attention(q, k, v, causal=True,
+                                      key_mask=key_mask, mesh=mesh)
             return self._block_post_attn(sl, carry, att), (k, v)
 
-        h, (ks, vs) = jax.lax.scan(body, h, stacked)
+        with jax.named_scope("layers"):
+            h, (ks, vs) = jax.lax.scan(body, h, stacked)
         if getattr(c, "kv_cache_dtype", None) == "int8":
             def padq(x):
                 q, s = quantize_kv(x)
@@ -355,11 +384,12 @@ class GPTModel(CausalDecoderMixin, Layer):
         intra-pack causal attention (a prefill chunk's rows attending each
         other) reads the freshly written keys — the _block_decode
         write-then-attend order over the ragged layout."""
-        q, k, v = self._block_qkv(sl, h)               # (1, T, nh, hd)
-        pck = ragged_write(pck, k[0], table, row_seq, row_pos)
-        pcv = ragged_write(pcv, v[0], table, row_seq, row_pos)
-        att = ragged_attention(q[0], pck, pcv, table, row_seq, row_pos,
-                               pad_lens)
+        with jax.named_scope("attn"):
+            q, k, v = self._block_qkv(sl, h)           # (1, T, nh, hd)
+            pck = ragged_write(pck, k[0], table, row_seq, row_pos)
+            pcv = ragged_write(pcv, v[0], table, row_seq, row_pos)
+            att = ragged_attention(q[0], pck, pcv, table, row_seq, row_pos,
+                                   pad_lens)
         return self._block_post_attn(sl, h, att[None]), pck, pcv
 
     def decode_ragged(self, params, h, pools, table, row_seq, row_pos,
@@ -384,7 +414,9 @@ class GPTModel(CausalDecoderMixin, Layer):
                 sl, carry, pck, pcv, table, row_seq, row_pos, pad_lens)
             return out, (pck, pcv)
 
-        h, (cks, cvs) = jax.lax.scan(body, h, (stacked, pools[0], pools[1]))
+        with jax.named_scope("layers"):
+            h, (cks, cvs) = jax.lax.scan(body, h,
+                                         (stacked, pools[0], pools[1]))
         return h, (cks, cvs)
 
     def decode_step(self, params, h, caches, t, pad_lens=None):
@@ -398,7 +430,9 @@ class GPTModel(CausalDecoderMixin, Layer):
                                              pad_lens=pad_lens)
             return out, (ck, cv)
 
-        h, (cks, cvs) = jax.lax.scan(body, h, (stacked, caches[0], caches[1]))
+        with jax.named_scope("layers"):
+            h, (cks, cvs) = jax.lax.scan(body, h,
+                                         (stacked, caches[0], caches[1]))
         return h, (cks, cvs)
 
 

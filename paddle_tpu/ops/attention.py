@@ -176,6 +176,7 @@ def _flash_fwd_pallas(q, k, v, kmask, seed, causal, scale, dropout_p,
     # block (1, 1, block) is fine.
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),  # seed (1,)
@@ -333,6 +334,7 @@ def _flash_bwd_pallas(q, k, v, kmask, seed, do, lse, delta, causal, scale,
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_k=L // block_k, **common),
+        name="flash_attention_dq",
         grid=(BH, L // block_q, L // block_k),
         in_specs=data_specs + [
             qspec(lambda b, i, j: (b, i, 0)),
@@ -351,6 +353,7 @@ def _flash_bwd_pallas(q, k, v, kmask, seed, do, lse, delta, causal, scale,
 
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, n_q=L // block_q, **common),
+        name="flash_attention_dkv",
         grid=(BH, L // block_k, L // block_q),
         in_specs=data_specs + [
             qspec(lambda b, j, i: (b, i, 0)),
@@ -430,9 +433,13 @@ def _flash_fwd(q, k, v, kmask, seed, causal, scale, dropout_p, block, mesh):
 
     def local(axes, q, k, v, kmask, seed):
         B, L, H, D = q.shape
-        out, lse = _flash_fwd_pallas(
-            _to_bh(q), _to_bh(k), _to_bh(v), kmask, _shard_seed(seed, axes),
-            causal, scale, dropout_p, block, block, H, interpret)
+        qkv = _to_bh(q), _to_bh(k), _to_bh(v)
+        # the region and the kernels' names say what they are, wherever
+        # this file moves: a trace finds the kernels by them
+        with jax.named_scope("flash_attention"):
+            out, lse = _flash_fwd_pallas(
+                *qkv, kmask, _shard_seed(seed, axes),
+                causal, scale, dropout_p, block, block, H, interpret)
         return _from_bh(out, B, H), lse.reshape(B, H, L)
 
     return _per_shard(local, mesh, q.shape,
@@ -457,10 +464,12 @@ def _flash_bwd_rule(causal, scale, dropout_p, block, mesh, res, g):
         o = _to_bh(out)
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1)
-        dq, dk, dv = _flash_bwd_pallas(
-            _to_bh(q), _to_bh(k), _to_bh(v), kmask, _shard_seed(seed, axes),
-            do, lse.reshape(B * H, L), delta, causal, scale, dropout_p,
-            block, block, H, interpret)
+        qkv = _to_bh(q), _to_bh(k), _to_bh(v)
+        with jax.named_scope("flash_attention"):
+            dq, dk, dv = _flash_bwd_pallas(
+                *qkv, kmask, _shard_seed(seed, axes),
+                do, lse.reshape(B * H, L), delta, causal, scale, dropout_p,
+                block, block, H, interpret)
         return (_from_bh(dq, B, H).astype(q.dtype),
                 _from_bh(dk, B, H).astype(k.dtype),
                 _from_bh(dv, B, H).astype(v.dtype))

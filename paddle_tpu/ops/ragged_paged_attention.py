@@ -190,14 +190,18 @@ def ragged_attention_rows(q, pool_k, pool_v, table, row_seq, row_pos,
             pltpu.VMEM((nh, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, nh, hd), q.dtype),
-        interpret=interpret,
-    )(table.astype(jnp.int32), jnp.asarray(row_seq, jnp.int32),
-      jnp.asarray(row_pos, jnp.int32), jnp.asarray(pad_lens, jnp.int32),
-      *operands)
+    # the region and the kernel's name say what it is, wherever this file
+    # moves: a trace finds the kernel by them
+    with jax.named_scope("ragged_paged_attention"):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((T, nh, hd), q.dtype),
+            interpret=interpret,
+            name="ragged_paged_attention",
+        )(table.astype(jnp.int32), jnp.asarray(row_seq, jnp.int32),
+          jnp.asarray(row_pos, jnp.int32), jnp.asarray(pad_lens, jnp.int32),
+          *operands)
 
 
 def ragged_attention_ref(q, pool_k, pool_v, table, row_seq, row_pos,

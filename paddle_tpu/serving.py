@@ -36,6 +36,7 @@ Greedy by default; temperature/top-k/top-p sampling share the engine key.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import logging
 import time
@@ -47,7 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .jit.bucketing import select_bucket
-from .telemetry import program_label
+from .telemetry import (PHASE_ADMIT, PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC,
+                        PHASE_UNPACK, program_label)
 from .utils.stats import StatRegistry, stat_add
 from .utils.stats import prometheus_text as _prometheus_text
 from .models._decode import (apply_repetition_penalty, make_row_sampler,
@@ -57,6 +59,15 @@ from .models._decode import (apply_repetition_penalty, make_row_sampler,
 
 __all__ = ["ContinuousBatchingEngine", "SpeculativeBatchingEngine",
            "Request"]
+
+
+# what _step_impl enters for each phase of a round when no tracer is
+# attached: one shared object that does nothing
+_NO_PHASE = contextlib.nullcontext()
+
+
+def _no_phase(name):
+    return _NO_PHASE
 
 
 def _timed_first_dispatch(run, cb):
@@ -118,13 +129,17 @@ def _slot_write(slot):
 class Request:
     """One in-flight generation request (host-side bookkeeping)."""
 
-    def __init__(self, rid: int, prompt: List[int], max_new_tokens: int):
+    def __init__(self, rid: int, prompt: List[int], max_new_tokens: int,
+                 due_at: Optional[float] = None):
         self.id = rid
         self.prompt = list(prompt)
         self.max_new_tokens = int(max_new_tokens)
         self.generated: List[int] = []
         self.done = False
         self.enqueued_at = time.monotonic()
+        # where TTFT and latency count from: the time the caller says the
+        # request was due, else the time it was queued
+        self.due_at = self.enqueued_at if due_at is None else float(due_at)
         self.first_token_at: Optional[float] = None
         self.finished_at: Optional[float] = None
         self.on_token = None          # optional streaming callback
@@ -421,8 +436,6 @@ class ContinuousBatchingEngine:
                         label, _program_cost(run, a, kw)
                         or {"flops": 0.0, "bytes": 0.0}))
             return run
-        self._tick_note["compiles"] = \
-            self._tick_note.get("compiles", 0) + 1
 
         def report(dt, a, kw):
             cost = (_program_cost(run, a, kw)
@@ -480,6 +493,12 @@ class ContinuousBatchingEngine:
         if self.tracer is None:
             return
         self._tick_note[key] = self._tick_note.get(key, 0) + value
+
+    def _phases(self):
+        """``tracer.phase`` with a tracer attached, else the shared no-op:
+        what ``_step_impl`` brackets each phase of the round with."""
+        tr = self.tracer
+        return _no_phase if tr is None else tr.phase
 
     def _first_token_tail(self):
         """The first-token sampling sequence (penalty → EOS window → draw →
@@ -785,9 +804,16 @@ class ContinuousBatchingEngine:
     # --------------------------------------------------------- scheduling --
 
     def add_request(self, prompt, max_new_tokens: int,
-                    on_token=None, trace_ctx=None, **sampling) -> int:
+                    on_token=None, trace_ctx=None, due_at=None,
+                    **sampling) -> int:
         """Queue a prompt; returns the request id.  Admission happens inside
         ``step()`` whenever a slot is free.
+
+        ``due_at``: optional time, on ``time.monotonic``, at which the
+        request was DUE.  A load generator that injects between ticks runs
+        late by up to a tick; with ``due_at`` the ``queued`` event carries
+        it and ``RequestTimeline.ttft_s`` / ``metrics()["mean_ttft_s"]``
+        (and ``mean_latency_s``) count from it instead of from the call.
 
         ``trace_ctx``: optional ``telemetry.TraceContext`` propagated by a
         caller that minted the request's end-to-end trace (the gateway's
@@ -826,15 +852,17 @@ class ContinuousBatchingEngine:
                 f"bucketed prompt ({len(prompt)} -> bucket {P}) needs "
                 f"{need} cache positions for max_new_tokens="
                 f"{max_new_tokens}; exceeds max_len ({self.max_len})")
-        req = Request(next(self._ids), prompt, max_new_tokens)
+        req = Request(next(self._ids), prompt, max_new_tokens, due_at)
         req.sampling = self._resolve_sampling(sampling)
         req.on_token = on_token
         self._queue.append(req)
-        if self.tracer is not None:
+        tr = self.tracer
+        if tr is not None:
             if trace_ctx is not None:
-                self.tracer.bind_trace(req.id, trace_ctx)
-            self.tracer.request_event(req.id, "queued",
-                                      prompt_len=len(prompt))
+                tr.bind_trace(req.id, trace_ctx)
+            # due_at on the tracer's clock, like every timeline stamp
+            due = {} if due_at is None else {"due_at": req.due_at - tr.t0}
+            tr.request_event(req.id, "queued", prompt_len=len(prompt), **due)
         return req.id
 
     _SAMPLING_KEYS = ("temperature", "top_k", "top_p", "greedy",
@@ -1078,8 +1106,8 @@ class ContinuousBatchingEngine:
         s = self._stats
         s.add("requests_finished")
         s.add("tokens_emitted", n)
-        s.add("ttft_seconds_sum", req.first_token_at - req.enqueued_at)
-        s.add("latency_seconds_sum", req.finished_at - req.enqueued_at)
+        s.add("ttft_seconds_sum", req.first_token_at - req.due_at)
+        s.add("latency_seconds_sum", req.finished_at - req.due_at)
         if self.tracer is not None:
             self.tracer.request_event(req.id, "retired", tokens=n)
 
@@ -1160,10 +1188,12 @@ class ContinuousBatchingEngine:
     def step(self):
         """One scheduler round (each engine's ``_step_impl`` documents its
         semantics).  With a tracer attached the round is bracketed by tick
-        telemetry — host wall time, queue depth, counter deltas, packed
-        rows, program labels; with ``tracer=None`` (default) this wrapper
-        is ONE attribute check and a tail call: no event allocation, no
-        tracer lock, no extra operands anywhere near a compiled program.
+        telemetry — its number, host wall time and the seconds of each
+        phase, queue depth, counter deltas, packed rows, program labels —
+        and by the ``engine.tick`` span; with ``tracer=None`` (default)
+        this wrapper is ONE attribute check and a tail call: no event
+        allocation, no tracer lock, no span, no extra operands anywhere
+        near a compiled program.
 
         An exception escaping ``_step_impl`` is SURFACED before it
         propagates — the ``step_errors`` counter ticks and (with a
@@ -1179,7 +1209,7 @@ class ContinuousBatchingEngine:
                 self._stats.add("step_errors")
                 raise
         t0 = time.perf_counter()
-        self._tick_note = {}
+        self._tick_note = tr.open_tick()
         s = self._stats
         base = {k: s.value(k) for k in self._TICK_COUNTERS}
         try:
@@ -1208,29 +1238,32 @@ class ContinuousBatchingEngine:
         """One scheduler round: admit waiting requests into free slots, then
         run ``ticks_per_sync`` batched decode ticks and retire finished
         requests from the returned token block."""
-        self._admit()
-        if self._filling:
-            self._fill_segments()
+        phase = self._phases()
+        with phase(PHASE_ADMIT):    # the bucketed engines prefill in here
+            self._admit()
+            if self._filling:
+                self._fill_segments()
         if not self._active.any():
             return
-        res = self._run_decode()
+        res = self._run_decode(phase)
         if res is None:
             return
         active_before, blk = res                   # blk (k, S)
-        for slot in np.flatnonzero(active_before):
-            for j in range(self.ticks_per_sync):
-                if not self._active[slot]:
-                    break  # retired mid-chunk: discard the chunk's tail
-                self._t[slot] += 1
-                self._tok[slot] = blk[j, slot]
-                self._record(int(slot), int(blk[j, slot]))
-            # room is a CHUNK-boundary concern: a surviving slot must fit a
-            # whole next chunk.  Admission-validated budgets always do; this
-            # is the safety net against inconsistent slot state, truncating
-            # rather than writing past the cache.
-            if self._active[slot] and \
-                    int(self._t[slot]) + self.ticks_per_sync > self.max_len:
-                self._retire(int(slot))
+        with phase(PHASE_UNPACK):
+            for slot in np.flatnonzero(active_before):
+                for j in range(self.ticks_per_sync):
+                    if not self._active[slot]:
+                        break  # retired mid-chunk: discard the chunk's tail
+                    self._t[slot] += 1
+                    self._tok[slot] = blk[j, slot]
+                    self._record(int(slot), int(blk[j, slot]))
+                # room is a CHUNK-boundary concern: a surviving slot must
+                # fit a whole next chunk.  Admission-validated budgets
+                # always do; this is the safety net against inconsistent
+                # slot state, truncating rather than writing past the cache.
+                if self._active[slot] and int(self._t[slot]) \
+                        + self.ticks_per_sync > self.max_len:
+                    self._retire(int(slot))
 
     def _prepare_decode(self) -> bool:
         """Pre-sync hook: the paged subclass grows block tables here
@@ -1243,27 +1276,31 @@ class ContinuousBatchingEngine:
         (the paged subclass passes its block table)."""
         return ()
 
-    def _run_decode(self):
+    def _run_decode(self, phase=_no_phase):
         """One ``ticks_per_sync`` decode sync over the engine's cache
         storage; returns (active_before, (k, S) token block) or None if no
         slot could decode."""
-        if not self._prepare_decode():
-            return None
-        run = self._decode_prog_all()
-        active_before = self._active.copy()
-        self._note("decode_rows", int(active_before.sum()))
-        emitted0 = np.asarray(
-            [len(r.generated) if r is not None else 0
-             for r in self._slot_req], np.int32)
-        ck, cv, blk, self._presence = run(
-            self.params, self.caches[0], self.caches[1],
-            *self._decode_extra_operands(),
-            jnp.asarray(self._tok), jnp.asarray(self._t),
-            jnp.asarray(self._pad), jnp.asarray(active_before),
-            self._next_key(), self._presence, jnp.asarray(emitted0),
-            self._plane_operands())
-        self.caches = (ck, cv)
-        return active_before, np.asarray(blk)
+        with phase(PHASE_PACK):
+            if not self._prepare_decode():
+                return None
+            active_before = self._active.copy()
+            self._note("decode_rows", int(active_before.sum()))
+            emitted0 = np.asarray(
+                [len(r.generated) if r is not None else 0
+                 for r in self._slot_req], np.int32)
+        with phase(PHASE_DISPATCH):
+            run = self._decode_prog_all()
+            ck, cv, blk, self._presence = run(
+                self.params, self.caches[0], self.caches[1],
+                *self._decode_extra_operands(),
+                jnp.asarray(self._tok), jnp.asarray(self._t),
+                jnp.asarray(self._pad), jnp.asarray(active_before),
+                self._next_key(), self._presence, jnp.asarray(emitted0),
+                self._plane_operands())
+            self.caches = (ck, cv)
+        with phase(PHASE_SYNC):
+            blk = np.asarray(blk)
+        return active_before, blk
 
     # metrics() contract: {key: (kind, pytype)}; kind "counter" = monotonic
     # over the engine's lifetime, "gauge" = instantaneous/derived.  Keys

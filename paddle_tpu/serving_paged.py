@@ -57,6 +57,8 @@ import numpy as np
 from .serving import ContinuousBatchingEngine, _default_buckets
 from .jit.bucketing import pow2_bucket, pow2_grid, select_bucket
 from .kv_store import KVPage, chain_hex
+from .telemetry import (PHASE_ADMIT, PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC,
+                        PHASE_UNPACK)
 from .models._decode import (PagedKV, apply_repetition_penalty,
                              greedy_verify, seed_presence, suppress_eos,
                              suppress_eos_rows)
@@ -826,7 +828,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     # --------------------------------------------------------- scheduling --
 
     def add_request(self, prompt, max_new_tokens: int, on_token=None,
-                    trace_ctx=None, **sampling) -> int:
+                    trace_ctx=None, due_at=None, **sampling) -> int:
         """Queue a prompt (the base-engine contract, plus the paged
         engine's preemption semantics).  ``trace_ctx`` threads through to
         the base engine's tracer binding (end-to-end request tracing);
@@ -860,7 +862,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                     f"max_new_tokens")
         return super().add_request(prompt_l, max_new_tokens,
                                    on_token=on_token, trace_ctx=trace_ctx,
-                                   **sampling)
+                                   due_at=due_at, **sampling)
 
     def _admit(self):
         free = self._free_slots()
@@ -1299,7 +1301,7 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
 
     def add_request(self, prompt, max_new_tokens: int, on_token=None,
                     trace_ctx=None, spec: Optional[bool] = None,
-                    **sampling) -> int:
+                    due_at=None, **sampling) -> int:
         """The base contract plus the per-request speculative budget:
         ``spec=None`` (default) speculates iff the engine has a draft
         model; ``spec=False`` opts this request out (plain greedy decode
@@ -1315,7 +1317,8 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         try:
             rid = super().add_request(prompt, max_new_tokens,
                                       on_token=on_token,
-                                      trace_ctx=trace_ctx, **sampling)
+                                      trace_ctx=trace_ctx, due_at=due_at,
+                                      **sampling)
         finally:
             self._pending_spec = None
         self._queue[-1].spec = eff     # base add_request just appended it
@@ -1471,48 +1474,78 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         completed prompts activate with their first token).  With a
         draft model the same round runs the fused draft+verify program
         instead — still one compiled program per (token_budget,
-        table-width) bucket."""
-        self._admit()
-        pack = self._build_pack()
-        if pack is None:
-            return
-        (toks, row_seq, row_pos, C, sample_rows, sample_active, dec_slots,
-         fill_adv, spec_row0, spec_active) = pack
+        table-width) bucket.  The round is five phases, each bracketed
+        by ``tracer.phase`` when a tracer is attached (telemetry.PHASES)."""
+        phase = self._phases()
+        with phase(PHASE_ADMIT):
+            self._admit()
+        with phase(PHASE_PACK):
+            pack = self._build_pack()
+            if pack is None:
+                return
+            (toks, row_seq, row_pos, C, sample_rows, sample_active,
+             dec_slots, fill_adv, spec_row0, spec_active) = pack
+            if self.tracer is not None:
+                self._note_pack(dec_slots, fill_adv, spec_active)
         if self.draft_model is not None:
-            return self._run_spec_pack(toks, row_seq, row_pos, C,
+            return self._run_spec_pack(phase, toks, row_seq, row_pos, C,
                                        sample_rows, dec_slots, fill_adv,
                                        spec_row0, spec_active)
-        if self.tracer is not None:
-            pf = int(sum(fill_adv.values()))
-            note = self._tick_note
-            note["decode_rows"] = note.get("decode_rows", 0) \
-                + len(dec_slots)
-            note["prefill_tokens"] = note.get("prefill_tokens", 0) + pf
-            note["budget_used"] = note.get("budget_used", 0) \
-                + len(dec_slots) + pf
-            note["token_budget"] = self.token_budget
-            note["table_cols"] = C
-        emitted0 = np.asarray(
-            [len(self._slot_req[s].generated) if self._active[s] else 0
-             for s in range(self.S)], np.int32)
-        run = self._ragged_prog(C)
-        ck, cv, ntok, self._presence = run(
-            self.params, self.caches[0], self.caches[1],
-            jnp.asarray(toks), jnp.asarray(row_seq), jnp.asarray(row_pos),
-            jnp.asarray(self._table[:, :C]), jnp.asarray(self._pad),
-            jnp.asarray(sample_rows), jnp.asarray(sample_active),
-            jnp.asarray(emitted0), self._next_key(), self._presence,
-            self._plane_operands())
-        self.caches = (ck, cv)
-        self._stats.add("ragged_steps")
-        ntok = np.asarray(ntok)
+        with phase(PHASE_DISPATCH):
+            emitted0 = np.asarray(
+                [len(self._slot_req[s].generated) if self._active[s] else 0
+                 for s in range(self.S)], np.int32)
+            run = self._ragged_prog(C)
+            ck, cv, ntok, self._presence = run(
+                self.params, self.caches[0], self.caches[1],
+                jnp.asarray(toks), jnp.asarray(row_seq),
+                jnp.asarray(row_pos), jnp.asarray(self._table[:, :C]),
+                jnp.asarray(self._pad), jnp.asarray(sample_rows),
+                jnp.asarray(sample_active), jnp.asarray(emitted0),
+                self._next_key(), self._presence, self._plane_operands())
+            self.caches = (ck, cv)
+            self._stats.add("ragged_steps")
+        with phase(PHASE_SYNC):
+            ntok = np.asarray(ntok)
+        with phase(PHASE_UNPACK):
+            for slot in dec_slots:
+                self._t[slot] += 1
+                self._tok[slot] = int(ntok[slot])
+                self._record(slot, int(ntok[slot]))
+                # room safety net (admission-validated budgets never
+                # trigger)
+                if self._active[slot] \
+                        and int(self._t[slot]) + 1 > self.max_len:
+                    self._retire(slot)
+            self._advance_fills(fill_adv, ntok)
+
+    def _note_pack(self, dec_slots, fill_adv, spec_active):
+        """Record the pack on the tick in flight, where it is built:
+        ``rows`` is one ``[rid, rows, kv_end]`` per sequence — a decode
+        row ``[rid, 1, t + 1]``, a verify chunk its K + 1 rows, a prefill
+        chunk ``[rid, m, last real position + 1]`` (``m`` counts the
+        bucket's left-pad rows, which the program runs too) — so the rows
+        sum to ``budget_used`` whatever a preemption or a dry pool did to
+        the order."""
+        K = self.K
+        rows = []
         for slot in dec_slots:
-            self._t[slot] += 1
-            self._tok[slot] = int(ntok[slot])
-            self._record(slot, int(ntok[slot]))
-            # room safety net (admission-validated budgets never trigger)
-            if self._active[slot] and int(self._t[slot]) + 1 > self.max_len:
-                self._retire(slot)
+            n = K + 1 if spec_active[slot] else 1
+            rows.append([self._slot_req[slot].id, n,
+                         int(self._t[slot]) + n])
+        for slot, m in fill_adv.items():
+            st = self._filling[slot]
+            rows.append([st["req"].id, m,
+                         max(st["filled"] + m - st["pad"], 0)])
+        self._tick_note.update(
+            decode_rows=len(dec_slots),
+            prefill_tokens=int(sum(fill_adv.values())),
+            budget_used=sum(r[1] for r in rows),
+            token_budget=self.token_budget, rows=rows)
+
+    def _advance_fills(self, fill_adv, first_tok):
+        """After a step: move every filler on by its chunk; a prompt that
+        completed activates with its first token (``first_tok[slot]``)."""
         for slot, m in fill_adv.items():
             st = self._filling[slot]
             st["filled"] += m
@@ -1521,7 +1554,7 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                 self._register_prompt_blocks(slot, st["ids"], st["pad"],
                                              st["P"])
                 self._activate(slot, st["req"], st["P"], st["pad"],
-                               int(ntok[slot]))
+                               int(first_tok[slot]))
 
     # ---------------------------------------------------------- programs --
 
@@ -1552,100 +1585,87 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             # ONE sampler over S gathered rows: each decode slot's row and
             # each completing prompt's last row (dummy row 0 for the rest
             # — computed, ignored host-side)
-            h_s = h[0, sample_rows][:, None]            # (S, 1, H)
-            l2 = model.decode_logits(params, h_s)[:, -1]
-            key, sub = jax.random.split(key)
-            if per_request:
-                temp, topk, topp, greedy, rpv, mnv, eosv = planes
-                l2 = apply_repetition_penalty(l2, presence, rpv)
-                l2 = suppress_eos_rows(l2, eosv, emitted0 < mnv)
-                ntok = row_sample(l2[:, None, :], sub, temp, topk, topp,
-                                  greedy)
-            else:
+            with jax.named_scope("head"):       # logits and the sampler
+                h_s = h[0, sample_rows][:, None]        # (S, 1, H)
+                l2 = model.decode_logits(params, h_s)[:, -1]
+                key, sub = jax.random.split(key)
+                if per_request:
+                    temp, topk, topp, greedy, rpv, mnv, eosv = planes
+                    l2 = apply_repetition_penalty(l2, presence, rpv)
+                    l2 = suppress_eos_rows(l2, eosv, emitted0 < mnv)
+                    ntok = row_sample(l2[:, None, :], sub, temp, topk,
+                                      topp, greedy)
+                else:
+                    if track:
+                        l2 = apply_repetition_penalty(l2, presence, rp)
+                    if min_new > 0:
+                        l2 = suppress_eos(l2, eos, emitted0 < min_new)
+                    ntok = sample(l2[:, None, :], sub)
                 if track:
-                    l2 = apply_repetition_penalty(l2, presence, rp)
-                if min_new > 0:
-                    l2 = suppress_eos(l2, eos, emitted0 < min_new)
-                ntok = sample(l2[:, None, :], sub)
-            if track:
-                # prompt tokens were seeded at admission; only SAMPLED
-                # tokens update presence in-program
-                presence = presence.at[jnp.arange(S), ntok].max(
-                    sample_active)
+                    # prompt tokens were seeded at admission; only SAMPLED
+                    # tokens update presence in-program
+                    presence = presence.at[jnp.arange(S), ntok].max(
+                        sample_active)
             return pool_ck, pool_cv, ntok, presence
 
         return run
 
     # ------------------------------------------- speculative ragged step --
 
-    def _run_spec_pack(self, toks, row_seq, row_pos, C, sample_rows,
-                       dec_slots, fill_adv, spec_row0, spec_active):
+    def _run_spec_pack(self, phase, toks, row_seq, row_pos, C,
+                       sample_rows, dec_slots, fill_adv, spec_row0,
+                       spec_active):
         """Dispatch one fused draft+verify ragged step and unpack: each
         speculating slot advances by its accepted count + 1 (greedy
         contract — outputs equal plain decode by construction), plain
         decode slots and completing prompts advance by their single
-        sampled token through the SAME program."""
+        sampled token through the SAME program.  ``phase`` brackets the
+        round's last three phases (see ``_step_impl``)."""
         K = self.K
         n_spec = int(spec_active.sum())
-        if self.tracer is not None:
-            pf = int(sum(fill_adv.values()))
-            note = self._tick_note
-            note["decode_rows"] = note.get("decode_rows", 0) \
-                + len(dec_slots)
-            note["spec_rows"] = note.get("spec_rows", 0) + n_spec * K
-            note["prefill_tokens"] = note.get("prefill_tokens", 0) + pf
-            note["budget_used"] = note.get("budget_used", 0) \
-                + len(dec_slots) + n_spec * K + pf
-            note["token_budget"] = self.token_budget
-            note["table_cols"] = C
-        run = self._ragged_spec_prog(C)
-        ck, cv, dck, dcv, lead, block = run(
-            (self.params, self.draft_params), self.caches[0],
-            self.caches[1], self.draft_caches[0], self.draft_caches[1],
-            jnp.asarray(toks), jnp.asarray(row_seq), jnp.asarray(row_pos),
-            jnp.asarray(self._table[:, :C]), jnp.asarray(self._pad),
-            jnp.asarray(sample_rows), jnp.asarray(spec_row0),
-            jnp.asarray(spec_active), jnp.asarray(self._tok),
-            jnp.asarray(self._t))
-        self.caches = (ck, cv)
-        self.draft_caches = (dck, dcv)
-        self._stats.add("ragged_steps")
-        if n_spec:
-            self._stats.add("spec_rounds")
-            self._stats.add("tokens_drafted", n_spec * K)
-        lead = np.asarray(lead)
-        block = np.asarray(block)
-        for slot in dec_slots:
-            m = int(lead[slot]) + 1 if spec_active[slot] else 1
-            if spec_active[slot]:
-                self._stats.add("tokens_accepted", int(lead[slot]))
-            for j in range(m):
-                if not self._active[slot]:
-                    break              # retired/cancelled mid-round:
-                self._t[slot] += 1     # discard the round's tail
-                self._tok[slot] = int(block[slot, j])
-                self._record(slot, int(block[slot, j]))
-            if self._active[slot]:
-                if int(self._t[slot]) + 1 > self.max_len:
-                    self._retire(slot)         # room safety net
-                elif spec_active[slot]:
-                    # KV rollback: whole blocks past the accepted clock
-                    # held only REJECTED draft pages — return them to
-                    # the pool now instead of stranding them until
-                    # retirement (self-healing writes make the next
-                    # round's fresh blocks safe by construction)
-                    self._rollback_blocks(slot)
-        for slot, m in fill_adv.items():
-            st = self._filling[slot]
-            st["filled"] += m
-            if st["filled"] == st["P"]:
-                del self._filling[slot]
-                self._register_prompt_blocks(slot, st["ids"], st["pad"],
-                                             st["P"])
-                # a completing prompt's first token rides block[:, 0]
-                # (its lead is 0 through the shared acceptance gather)
-                self._activate(slot, st["req"], st["P"], st["pad"],
-                               int(block[slot, 0]))
+        with phase(PHASE_DISPATCH):
+            run = self._ragged_spec_prog(C)
+            ck, cv, dck, dcv, lead, block = run(
+                (self.params, self.draft_params), self.caches[0],
+                self.caches[1], self.draft_caches[0], self.draft_caches[1],
+                jnp.asarray(toks), jnp.asarray(row_seq),
+                jnp.asarray(row_pos), jnp.asarray(self._table[:, :C]),
+                jnp.asarray(self._pad), jnp.asarray(sample_rows),
+                jnp.asarray(spec_row0), jnp.asarray(spec_active),
+                jnp.asarray(self._tok), jnp.asarray(self._t))
+            self.caches = (ck, cv)
+            self.draft_caches = (dck, dcv)
+            self._stats.add("ragged_steps")
+            if n_spec:
+                self._stats.add("spec_rounds")
+                self._stats.add("tokens_drafted", n_spec * K)
+        with phase(PHASE_SYNC):
+            lead = np.asarray(lead)
+            block = np.asarray(block)
+        with phase(PHASE_UNPACK):
+            for slot in dec_slots:
+                m = int(lead[slot]) + 1 if spec_active[slot] else 1
+                if spec_active[slot]:
+                    self._stats.add("tokens_accepted", int(lead[slot]))
+                for j in range(m):
+                    if not self._active[slot]:
+                        break              # retired/cancelled mid-round:
+                    self._t[slot] += 1     # discard the round's tail
+                    self._tok[slot] = int(block[slot, j])
+                    self._record(slot, int(block[slot, j]))
+                if self._active[slot]:
+                    if int(self._t[slot]) + 1 > self.max_len:
+                        self._retire(slot)         # room safety net
+                    elif spec_active[slot]:
+                        # KV rollback: whole blocks past the accepted
+                        # clock held only REJECTED draft pages — return
+                        # them to the pool now instead of stranding them
+                        # until retirement (self-healing writes make the
+                        # next round's fresh blocks safe by construction)
+                        self._rollback_blocks(slot)
+            # a completing prompt's first token rides block[:, 0] (its
+            # lead is 0 through the shared acceptance gather)
+            self._advance_fills(fill_adv, block[:, 0])
 
     def _rollback_blocks(self, slot: int):
         """Free the slot's table columns past the accepted clock — the
@@ -1733,12 +1753,13 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             # (5) greedy verification: gather each slot's K+1 rows (non-
             # spec slots gather their single row K+1 times — their lead
             # is forced to 0, so block[:, 0] is plain greedy decode)
-            grows = sample_rows[:, None] + jnp.arange(K + 1)[None] \
-                * spec_active[:, None].astype(jnp.int32)
-            h_s = h[0, grows]                              # (S, K+1, H)
-            tpred = jnp.argmax(model.decode_logits(params, h_s),
-                               -1).astype(jnp.int32)       # (S, K+1)
-            lead, block = greedy_verify(d, tpred, active=spec_active)
+            with jax.named_scope("head"):
+                grows = sample_rows[:, None] + jnp.arange(K + 1)[None] \
+                    * spec_active[:, None].astype(jnp.int32)
+                h_s = h[0, grows]                          # (S, K+1, H)
+                tpred = jnp.argmax(model.decode_logits(params, h_s),
+                                   -1).astype(jnp.int32)   # (S, K+1)
+                lead, block = greedy_verify(d, tpred, active=spec_active)
             return pool_ck, pool_cv, dpool_ck, dpool_cv, lead, block
 
         return run

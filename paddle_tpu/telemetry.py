@@ -11,16 +11,21 @@ attribute check — no event allocation, no lock.
 Event kinds (each event is one flat JSON-serializable dict):
 
 ``tick``     one scheduler round.  Fields: ``engine`` (class name),
-             ``dur_s`` (host wall time), ``queue_depth``, ``active``
+             ``tick`` (this tracer's sequence number of the round — the
+             request events emitted inside it carry the same number),
+             ``dur_s`` (host wall time), ``phases`` (``{name: seconds}``
+             of the ``engine.*`` phases that partition the round, see
+             ``Tracer.phase``), ``queue_depth``, ``active``
              (decoding slots), ``filling`` (prompts mid-prefill), per-tick
              deltas of the engine counters (``tokens_emitted``,
              ``requests_finished``, and for paged engines
              ``blocks_allocated``/``blocks_released``/``preemptions``/
              ``prefix_hits``), plus whatever the engine packed this tick:
              ``decode_rows``, ``prefill_tokens``, ``budget_used``/
-             ``token_budget`` (ragged), ``programs`` (short labels of the
-             compiled programs dispatched, e.g. ``ragged_step:12:4``), and
-             ``compiles`` (program-cache misses paid inside the tick).
+             ``token_budget`` and ``rows`` (ragged: one ``[rid, rows,
+             kv_end]`` per sequence in the pack, summing to
+             ``budget_used``), and ``programs`` (short labels of the
+             compiled programs dispatched, e.g. ``ragged_step:12:4``).
 ``compile``  one program-cache MISS: ``key`` (short label), ``wall_s``
              (host wall time of the program's first dispatch — trace +
              XLA compile + first execution), ``engine``, ``provenance``
@@ -115,7 +120,6 @@ import contextlib
 import functools
 import json
 import logging
-import os
 import threading
 import time
 import uuid
@@ -126,9 +130,24 @@ from .utils.stats import (DEFAULT_TIME_BUCKETS, StatRegistry,
 
 __all__ = ["Tracer", "RequestTimeline", "RequestTraceIndex", "TraceContext",
            "TrainMonitor", "program_label", "chrome_trace_from_jsonl",
-           "instrument_train_step", "set_active_monitor", "current_monitor"]
+           "instrument_train_step", "set_active_monitor", "current_monitor",
+           "PHASE_TICK", "PHASE_ADMIT", "PHASE_PACK", "PHASE_DISPATCH",
+           "PHASE_SYNC", "PHASE_UNPACK", "PHASES"]
 
 _PCTS = (50.0, 95.0, 99.0)
+
+# The spans of one scheduler round, on the host plane of a profiler trace
+# and (as seconds) on the round's ``tick`` event.  ``engine.tick`` encloses
+# the five phases, which follow one another and do not overlap:
+PHASE_TICK = "engine.tick"
+PHASE_ADMIT = "engine.admit"        # queue -> slots (bucketed engines: the
+#                                     admission prefill runs in here too)
+PHASE_PACK = "engine.pack"          # block growth, preemption, the pack
+PHASE_DISPATCH = "engine.dispatch"  # operands to the device, the call
+#                                     into the compiled program
+PHASE_SYNC = "engine.sync"          # the host waits for the sampled tokens
+PHASE_UNPACK = "engine.unpack"      # tokens to requests, callbacks, retire
+PHASES = (PHASE_ADMIT, PHASE_PACK, PHASE_DISPATCH, PHASE_SYNC, PHASE_UNPACK)
 
 
 class TraceContext:
@@ -212,14 +231,19 @@ class RequestTimeline:
     the replayed prefill is not double-counted, the request simply has one
     TTFT: queued → the first token that was never rolled back."""
 
-    __slots__ = ("rid", "prompt_len", "queued_at", "admitted_at",
+    __slots__ = ("rid", "prompt_len", "queued_at", "due_at", "admitted_at",
                  "first_token_at", "token_times", "preempted_spans",
                  "retired_at", "replays", "tokens_delivered")
 
-    def __init__(self, rid: int, queued_at: float, prompt_len: int = 0):
+    def __init__(self, rid: int, queued_at: float, prompt_len: int = 0,
+                 due_at: Optional[float] = None):
         self.rid = rid
         self.prompt_len = prompt_len
         self.queued_at = queued_at
+        # when the caller says the request was DUE (add_request(due_at=)):
+        # under an open loop the injection can run late, and a TTFT from
+        # queued_at would not count that wait
+        self.due_at = due_at
         self.admitted_at: Optional[float] = None
         self.first_token_at: Optional[float] = None
         self.token_times: List[float] = []
@@ -232,7 +256,8 @@ class RequestTimeline:
     def ttft_s(self) -> Optional[float]:
         if self.first_token_at is None:
             return None
-        return self.first_token_at - self.queued_at
+        return self.first_token_at - (self.queued_at if self.due_at is None
+                                      else self.due_at)
 
     def inter_token_s(self) -> List[float]:
         return [b - a for a, b in zip(self.token_times,
@@ -262,12 +287,45 @@ class RequestTimeline:
 
     def to_dict(self) -> Dict[str, Any]:
         return {"rid": self.rid, "prompt_len": self.prompt_len,
-                "queued_at": self.queued_at, "admitted_at": self.admitted_at,
+                "queued_at": self.queued_at, "due_at": self.due_at,
+                "admitted_at": self.admitted_at,
                 "first_token_at": self.first_token_at,
                 "retired_at": self.retired_at, "replays": self.replays,
                 "tokens_delivered": self.tokens_delivered,
                 "ttft_s": self.ttft_s,
                 "preempted_spans": [list(s) for s in self.preempted_spans]}
+
+
+def _annotation(name: str, **kw):
+    import jax
+    return jax.profiler.TraceAnnotation(name, **kw)
+
+
+class _Phase:
+    """What ``Tracer.phase`` returns; see there."""
+
+    __slots__ = ("_acc", "_name", "_ann", "_t0")
+
+    def __init__(self, note, name):
+        if note is None:
+            self._acc, self._ann = None, _annotation(name)
+        else:
+            self._acc = note["phases"]
+            self._ann = _annotation(name, tick=note["tick"])
+        self._name = name
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        acc = self._acc
+        if acc is not None:
+            acc[self._name] = acc.get(self._name, 0.0) + dt
+        return False
 
 
 class Tracer:
@@ -302,6 +360,11 @@ class Tracer:
         self._post_warm_misses = 0
         self._warned_storm = False
         self._ticks = 0
+        # the scheduler round in flight: {"tick": seq, "phases": {...}}
+        # between open_tick() and tick(), else None
+        self._tick_seq = 0
+        self._open_tick: Optional[Dict[str, Any]] = None
+        self._tick_span = None
         self._warmup_depth = 0            # expected_compiles nesting
         self._prov_resolver = None        # compile provenance (jit/aot.py)
         self._expected_keys = None        # warmup-grid labels, or None=all
@@ -328,12 +391,9 @@ class Tracer:
         # opts the ENGINES into probing cost on program fetches (one
         # extra .lower().compile() per program family, digest-cached
         # process-wide — hapi/dynamic_flops.py); compile_aot attaches
-        # cost for free either way.  peak_flops (default from
-        # PADDLE_TPU_PEAK_FLOPS) turns model FLOPs/s into MFU.
+        # cost for free either way.  peak_flops, given by the caller for
+        # the chip at hand, turns model FLOPs/s into MFU.
         self.attribute_cost = bool(attribute_cost)
-        if peak_flops is None:
-            env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
-            peak_flops = float(env) if env else None
         self.peak_flops = (None if not peak_flops
                            else float(peak_flops))
         self._costs: Dict[str, Dict[str, float]] = {}
@@ -453,6 +513,28 @@ class Tracer:
                 led.record(bucket, float(fields.get("dur_s", 0.0)))
         return ev
 
+    def open_tick(self) -> Dict[str, Any]:
+        """Begin one scheduler round: number it, enter the ``engine.tick``
+        span (a ``jax.profiler.TraceAnnotation``, so in a profiler session
+        the round lies on the host plane on the device operations' clock;
+        inert otherwise) and return the note that ``tick()`` closes the
+        round with: ``{"tick": seq, "phases": {}}``.  The engine adds
+        whatever it packed to the same dict; until ``tick()`` every
+        ``phase()`` adds its seconds to it and every request event carries
+        its number.  One engine per tracer: rounds do not nest."""
+        self._tick_seq += 1
+        self._open_tick = note = {"tick": self._tick_seq, "phases": {}}
+        self._tick_span = _annotation(PHASE_TICK, tick=self._tick_seq)
+        self._tick_span.__enter__()
+        return note
+
+    def phase(self, name: str) -> "_Phase":
+        """``with tracer.phase(PHASE_PACK): ...`` — one phase of the round
+        in flight: a clock pair whose seconds add to the round's
+        ``phases[name]``, and a ``TraceAnnotation`` of that name carrying
+        the round's number.  Outside a round it is only the annotation."""
+        return _Phase(self._open_tick, name)
+
     def tick(self, engine: str, dur_s: float, **fields):
         """One scheduler round; observes the tick-duration histogram and
         arms the post-warmup recompile accounting.  When the dispatched
@@ -460,12 +542,15 @@ class Tracer:
         the tick additionally carries its model-FLOPs (``flops`` /
         ``bytes``) — the per-tick roofline attribution ``summary()``
         folds into MFU.  Engines add free-form composition fields; the
-        ragged spec engine notes ``spec_rows`` (draft+verify rows packed
-        this tick) next to ``decode_rows``/``prefill_tokens``, and its
+        ragged spec engine's verify chunks are the ``rows`` entries of
+        more than one row, and its
         per-engine registry carries the acceptance counters
         (``tokens_drafted``/``tokens_accepted``) whose per-tick deltas
         ride the tick event — accepted-tokens/s over the same MFU
         attribution is the spec roofline story."""
+        if self._tick_span is not None:     # the round open_tick() began
+            self._tick_span.__exit__(None, None, None)
+            self._tick_span = self._open_tick = None
         self.registry.add("ticks")
         self.registry.observe("tick_seconds", dur_s)
         progs = fields.get("programs")
@@ -637,7 +722,8 @@ class Tracer:
             tl = self._live.get(rid)
             if tl is None and what == "queued":
                 tl = self._live[rid] = RequestTimeline(
-                    rid, ts, fields.get("prompt_len", 0))
+                    rid, ts, fields.get("prompt_len", 0),
+                    fields.get("due_at"))
             elif tl is None:
                 # transition for an untracked request (tracer attached
                 # mid-flight): open a timeline so spans stay well-formed
@@ -704,6 +790,8 @@ class Tracer:
                 self._done.append(tl)
                 self._trace_binds.pop(rid, None)
             ev = {"kind": "request", "ts": ts, "rid": rid, "what": what}
+            if self._open_tick is not None:
+                ev["tick"] = self._open_tick["tick"]
             if ctx is not None:
                 ev.update(ctx.to_dict())
             ev.update(fields)

@@ -180,6 +180,43 @@ def test_ragged_serving_step_compiles(v5e):
     assert KERNEL in compiled.as_text()
 
 
+def kernel_op_names(text):
+    """The ``op_name`` of every Pallas kernel call in compiled HLO text."""
+    import re
+    return [re.search(r'op_name="([^"]*)"', line).group(1)
+            for line in text.splitlines()
+            if KERNEL in line and "custom-call(" in line]
+
+
+def test_flash_kernels_are_called_under_their_own_name(v5e):
+    """A trace finds a kernel by the region and the name of its call, not
+    by the file it lives in: forward, dQ and dK/dV say ``flash_attention``,
+    the backward ones on the transposed path."""
+    q = on_one(v5e, FLASH_SHAPES[1], jnp.bfloat16)
+    names = kernel_op_names(compile_for(
+        jax.grad(flash_loss(), argnums=(0, 1, 2)), q, q, q).as_text())
+    assert len(names) == 3
+    # the region reaches the call inside the wrapper that transformed it:
+    # jvp(flash_attention)/..., transpose(jvp(flash_attention))/...
+    assert all("(flash_attention)" in n for n in names)
+    assert sorted(n.split("/")[-2] for n in names) == [
+        "flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
+    assert sum("transpose(" in n for n in names) == 2
+
+
+def test_ragged_kernel_is_called_under_its_own_name(v5e):
+    from paddle_tpu.models._decode import ragged_attention
+    nh, pool, table, per_slot = pool_args(v5e, 128, int8=False)
+    q = on_one(v5e, (BUDGET, nh, 128), jnp.bfloat16)
+    per_row = on_one(v5e, (BUDGET,), jnp.int32)
+    names = kernel_op_names(compile_for(
+        ragged_attention, q, pool, pool, table, per_row, per_row,
+        per_slot).as_text())
+    assert names and all(
+        "/ragged_paged_attention/ragged_paged_attention/" in n
+        for n in names)
+
+
 # ---- the fused LayerNorm epilogue and the fused AdamW sweep: off by default
 # (core/flags.py), so not on the main path.  The forward compiles; the other
 # two are refused for a block of one row over a taller array.  Pinned strict,
