@@ -64,17 +64,19 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec
 __all__ = [
     "CATALOG_VERSION", "ShardingRules", "activation_batch_spec",
     "attention_specs", "batch_spec", "build_param_specs", "build_state_shardings",
-    "make_spec", "match_partition_rules", "override_leading_axis",
+    "constrain_activation", "constrain_batch", "data_parallel_axes",
+    "loop_collectives", "make_spec", "match_partition_rules",
+    "override_leading_axis",
     "register_rules", "replica_stacked_spec", "replicated_spec",
     "replication_fallback", "resolve_flat_shard_spec",
     "sep_activation_spec", "sharding_rules_digest", "spec_tree_digest",
-    "unregister_rules",
+    "unregister_rules", "zero3_layout_comm",
 ]
 
 #: Bump when the SEMANTICS of the built-in inference below change without
 #: the code path changing shape — the catalog digest folds it in, so every
 #: AOT-cached executable compiled under the old semantics is invalidated.
-CATALOG_VERSION = 1
+CATALOG_VERSION = 2
 
 #: The built-in rule catalog: one row per layout decision this module
 #: makes.  ``sharding_rules_digest()`` digests these rows, so editing a
@@ -84,17 +86,23 @@ _RULE_CATALOG: Tuple[Tuple[str, str], ...] = (
     ("tp", "params with _dims_mapping={dim: axis} shard that dim on the "
            "axis when the axis exists, has size>1, and divides the dim"),
     ("pp", "_pipe_stacked params shard dim 0 over 'pipe' when divisible"),
-    ("zero3", "zero_stage>=3 shards the first free divisible param dim "
-              "over 'sharding'"),
+    ("zero3", "zero_stage>=3 shards one free divisible param dim over "
+              "'sharding': the largest INSIDE the layer for a parameter "
+              "the layer scan stacks (never dim 0, which the scan slices), "
+              "the first for any other"),
     ("slots", "optimizer slots follow their param's spec; zero_stage>=1 "
               "adds 'sharding' on the first free divisible dim"),
     ("scalars", "scalar/size-1 leaves are always replicated"),
     ("dp_update", "plain-DP weight-update sharding: flat optimizer shards "
                   "carry a leading replica dim over the dp axis "
                   "(update_sharding.py)"),
-    ("attention", "Pallas attention kernels run per shard: batch over "
-                  "'data' and 'sharding', heads over 'model', each where "
-                  "it divides (attention_specs)"),
+    ("batch", "the batch dim lives on the data-parallel axes: 'data', "
+              "then 'sharding', each where its size > 1 and divides the "
+              "batch (data_parallel_axes; batch_spec, "
+              "activation_batch_spec and attention_specs read it)"),
+    ("attention", "Pallas attention kernels run per shard: batch over the "
+                  "data-parallel axes, heads over 'model' where it "
+                  "divides (attention_specs)"),
     ("flat_residual", "flat comm residuals ride an axis only when the "
                       "length divides; otherwise replicate WITH byte "
                       "accounting (resolve_flat_shard_spec)"),
@@ -130,48 +138,86 @@ def replica_stacked_spec(leaf, axis: str) -> PartitionSpec:
     return PartitionSpec(axis, *([None] * (np.ndim(leaf) - 1)))
 
 
-def batch_spec(mesh: Mesh, axis: str = "data") -> PartitionSpec:
-    """Batch-dim layout: ``P(axis)`` when the axis exists with size > 1 on
-    ``mesh``, else replicated (single-replica CPU fallback)."""
-    if axis in mesh.axis_names and mesh.shape[axis] > 1:
-        return PartitionSpec(axis)
-    return PartitionSpec()
+def data_parallel_axes(mesh: Mesh, batch: Optional[int] = None
+                        ) -> Tuple[str, ...]:
+    """The mesh axes a batch is split over, in order: "data", then
+    "sharding" (ZeRO is data parallelism with the state split as well),
+    each only where its size is > 1 and, when ``batch`` is given, where it
+    still divides what the axes before it left of the batch.  The ONE
+    place that names them: :func:`batch_spec`,
+    :func:`activation_batch_spec` and :func:`attention_specs` read it."""
+    axes, split = [], 1
+    for ax in ("data", "sharding"):
+        n = mesh.shape.get(ax, 1)
+        if n > 1 and (batch is None or batch % (split * n) == 0):
+            axes.append(ax)
+            split *= n
+    return tuple(axes)
 
 
-def activation_batch_spec(mesh: Mesh) -> Optional[PartitionSpec]:
-    """(B, L, H) activation layout for the GPT builders: batch on "data",
-    sequence on "sep" when sequence parallelism is on; None when the mesh
-    gives no reason to constrain (single data replica, no sep)."""
-    if "sep" in mesh.shape and mesh.shape["sep"] > 1:
-        return PartitionSpec("data", "sep", None)
-    if "data" in mesh.shape and mesh.shape["data"] > 1:
-        return PartitionSpec("data", None, None)
-    return None
+def batch_spec(mesh: Mesh, batch: Optional[int] = None) -> PartitionSpec:
+    """Batch-dim layout: dim 0 over :func:`data_parallel_axes`, replicated
+    when there is none (single replica, or no axis divides ``batch``)."""
+    axes = data_parallel_axes(mesh, batch)
+    return PartitionSpec(axes) if axes else PartitionSpec()
+
+
+def activation_batch_spec(mesh: Mesh, batch: Optional[int] = None
+                          ) -> Optional[PartitionSpec]:
+    """(B, L, H) activation layout for the GPT builders: batch over
+    :func:`data_parallel_axes`, sequence on "sep" when sequence
+    parallelism is on; None when the mesh gives no reason to constrain
+    (one replica, no sep)."""
+    axes = data_parallel_axes(mesh, batch)
+    sep = "sep" if mesh.shape.get("sep", 1) > 1 else None
+    if not axes and sep is None:
+        return None
+    return PartitionSpec(axes or None, sep, None)
+
+
+def _constrained(x, mesh: Mesh, spec: Optional[PartitionSpec]):
+    if spec is None:
+        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def constrain_batch(x, mesh: Optional[Mesh]):
+    """A step's batch operand with dim 0 pinned to the data-parallel axes
+    inside the jitted program; ``x`` itself where no axis splits it (and
+    for a scalar, such as the step's key).  A layout hint only: the
+    values are the same either way."""
+    if mesh is None or np.ndim(x) == 0:
+        return x
+    axes = data_parallel_axes(mesh, x.shape[0])
+    return _constrained(x, mesh, PartitionSpec(axes) if axes else None)
+
+
+def constrain_activation(h, mesh: Optional[Mesh]):
+    """A (B, L, H) activation pinned to :func:`activation_batch_spec` —
+    what the layer scan carries, so every block runs on its own rows."""
+    if mesh is None:
+        return h
+    return _constrained(h, mesh, activation_batch_spec(mesh, h.shape[0]))
 
 
 def attention_specs(mesh: Mesh, batch: int, heads: int):
     """shard_map layouts for a Pallas attention kernel under ``mesh``
-    (ops/attention.py): the batch dim over the data-parallel axes ("data",
-    "sharding") and the head dim over "model", each only where the axis
-    has size > 1 and divides the dim — an axis that does not divide is
-    left out, so its devices each compute the whole dim (correct, just
-    not split).  Returns ``(specs, sharded_axes)`` with ``specs`` keyed
-    "qkv" for (B, L, H, D), "kmask" for (B, L), "stat" for (B, H, L) and
-    "rep" for replicated operands."""
-    b_axes, split = [], 1
-    for ax in ("data", "sharding"):
-        n = mesh.shape.get(ax, 1)
-        if n > 1 and batch % (split * n) == 0:
-            b_axes.append(ax)
-            split *= n
-    b = tuple(b_axes) if b_axes else None
+    (ops/attention.py): the batch dim over :func:`data_parallel_axes` and
+    the head dim over "model", each only where the axis has size > 1 and
+    divides the dim — an axis that does not divide is left out, so its
+    devices each compute the whole dim (correct, just not split).
+    Returns ``(specs, sharded_axes)`` with ``specs`` keyed "qkv" for
+    (B, L, H, D), "kmask" for (B, L), "stat" for (B, H, L) and "rep" for
+    replicated operands."""
+    b_axes = data_parallel_axes(mesh, batch)
+    b = b_axes or None
     mp = mesh.shape.get("model", 1)
     h = "model" if mp > 1 and heads % mp == 0 else None
     specs = {"qkv": PartitionSpec(b, None, h, None),
              "kmask": PartitionSpec(b, None),
              "stat": PartitionSpec(b, h, None),
              "rep": PartitionSpec()}
-    return specs, tuple(b_axes) + ((h,) if h else ())
+    return specs, b_axes + ((h,) if h else ())
 
 
 def sep_activation_spec(ndim: int = 4, axis: str = "sep",
@@ -244,7 +290,14 @@ def resolve_flat_shard_spec(name: str, length: int, mesh: Mesh, axis: str,
 # --------------------------------------------------------------------------
 
 def _spec_for_param(name: str, p, mesh: Mesh, named_params: Dict,
-                    zero_stage: int, stacked_pipe: bool) -> PartitionSpec:
+                    zero_stage: int, stacked_pipe: bool,
+                    scan_stacked: bool = False) -> PartitionSpec:
+    """``scan_stacked``: dim 0 of ``p`` is the layer-stack axis that the
+    model's layer scan slices (the model says so: ``stacked_param_names``).
+    ZeRO-3 never shards that axis — a scan over a sharded stack makes the
+    partitioner gather the WHOLE stack in every iteration — and takes the
+    largest free divisible dim inside the layer, so one iteration gathers
+    one layer."""
     ndim = len(p.shape)
     entries = [None] * ndim
     meta = getattr(named_params.get(name), "_dims_mapping", None) \
@@ -262,18 +315,67 @@ def _spec_for_param(name: str, p, mesh: Mesh, named_params: Dict,
         entries[0] = "pipe"
     if zero_stage >= 3 and "sharding" in mesh.axis_names and \
             mesh.shape["sharding"] > 1:
-        for d in range(ndim):
-            if entries[d] is None and p.shape[d] % mesh.shape["sharding"] == 0:
-                entries[d] = "sharding"
-                break
+        free = [d for d in range(1 if scan_stacked else 0, ndim)
+                if entries[d] is None
+                and p.shape[d] % mesh.shape["sharding"] == 0]
+        if free:
+            d = max(free, key=lambda d: p.shape[d]) if scan_stacked \
+                else free[0]
+            entries[d] = "sharding"
     return PartitionSpec(*entries)
 
 
 def build_param_specs(params: Dict[str, Any], mesh: Mesh, layer=None,
                       zero_stage: int = 0) -> Dict[str, PartitionSpec]:
     named = dict(layer.named_parameters()) if layer is not None else {}
-    return {name: _spec_for_param(name, p, mesh, named, zero_stage, True)
-            for name, p in params.items()}
+    # whose dim 0 the layer scan slices: the model's own list, the scan's
+    # source of truth; with no layer the rule knows of no scan
+    stacked = frozenset(getattr(layer, "stacked_param_names", tuple)())
+    specs = {name: _spec_for_param(name, p, mesh, named, zero_stage, True,
+                                   name in stacked)
+             for name, p in params.items()}
+    if zero_stage >= 3 and mesh.shape.get("sharding", 1) > 1:
+        zero3_layout_comm(params, specs, mesh, stacked)
+    return specs
+
+
+def zero3_layout_comm(params: Dict[str, Any],
+                      specs: Dict[str, PartitionSpec], mesh: Mesh,
+                      stacked: Iterable[str] = ()) -> Dict[str, int]:
+    """What one step of a ZeRO-3 layout must move over "sharding", per
+    device, from the shapes alone (no compile): ``gather_bytes`` — every
+    leaf split over the axis is gathered where it is used, once forward
+    and once backward, and a leaf split on the axis the layer scan slices
+    is gathered WHOLE in every one of its iterations; ``reduce_bytes`` —
+    gradients land on the parameter's layout, a reduce-scatter for a
+    split leaf, an all-reduce for a replicated one; ``scan_axis_leaves`` —
+    leaves whose spec names the scanned axis (0 under the built-in rule).
+    Bytes are at the leaves' stored dtype; a model that casts before the
+    gather moves that fraction of ``gather_bytes``.  Also the gauges
+    ``sharding_zero3_*`` of the stats registry."""
+    from ..utils.stats import stat_registry
+    n = mesh.shape.get("sharding", 1)
+    stacked = frozenset(stacked)
+    out = {"gather_bytes": 0, "reduce_bytes": 0, "scan_axis_leaves": 0}
+
+    def names(entry):
+        return entry if isinstance(entry, tuple) else (entry,)
+
+    for name, p in params.items():
+        spec = tuple(specs[name])
+        moved = _leaf_nbytes(p) * (n - 1) // n
+        if not any("sharding" in names(e) for e in spec):
+            out["reduce_bytes"] += 2 * moved
+            continue
+        passes = 2
+        if name in stacked and "sharding" in names(spec[0]):
+            out["scan_axis_leaves"] += 1
+            passes *= p.shape[0]
+        out["gather_bytes"] += passes * moved
+        out["reduce_bytes"] += moved
+    for key, val in out.items():
+        stat_registry().set(f"sharding_zero3_{key}", val)
+    return out
 
 
 def _slot_spec(param_spec: PartitionSpec, p, mesh: Mesh,
@@ -316,6 +418,63 @@ def build_state_shardings(state, params_specs: Dict[str, PartitionSpec],
                         for k, v in state["opt"]["slots"].items()}}
     buf_sh = {k: rep for k in state["buffers"]}
     return {"params": p_sh, "opt": opt_sh, "buffers": buf_sh}
+
+
+# --------------------------------------------------------------------------
+# what a compiled program moves inside its loops
+# --------------------------------------------------------------------------
+
+# "%x = <type> <op>(": the type may hold "=" (/*index=5*/) and parentheses
+# (tiled layouts), but never another operation's " name("
+_COLLECTIVE_OP = re.compile(
+    r" = (?P<type>(?:(?! [a-z][\w\-]*\().)*?) (?P<op>all-gather|all-reduce|"
+    r"reduce-scatter|all-to-all|collective-permute)(?:-start)?\(")
+_COMPUTATION_HEAD = re.compile(r"^(?:ENTRY )?%?(?P<name>[\w.\-]+) \(.*\{$")
+_CALLED = re.compile(
+    r"(?:calls|to_apply|body|condition|branch_computations|"
+    r"called_computations)=\{?(?P<names>[%\w.\-, ]+)")
+
+
+def loop_collectives(hlo_text: str) -> list:
+    """The collectives of a compiled program (``compiled.as_text()``) that
+    run inside a ``while`` body, directly or through the fusions and
+    calls the body makes: ``[{"op", "dims", "computation"}]`` with
+    ``dims`` the dimension tuples of the result.  The check a layout
+    wants before a chip run: under ZeRO-3 no ``all-gather`` in the layer
+    scan may have the layer count among its dims (one layer an iteration,
+    not the stack).  The TPU compiler writes a reduce-scatter as a fusion
+    named ``all-reduce-scatter`` around an ``all-reduce``; the CPU's as an
+    ``all-reduce`` and a slice."""
+    lines_of: Dict[str, list] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION_HEAD.match(line)
+        if head:
+            current = lines_of.setdefault(head["name"], [])
+        elif current is not None:
+            current.append(line)
+
+    def called(name):
+        for line in lines_of.get(name, ()):
+            for m in _CALLED.finditer(line):
+                yield from (n.strip(" %") for n in m["names"].split(","))
+
+    todo = [m.strip(" %") for m in re.findall(r"body=(%?[\w.\-]+)", hlo_text)]
+    inside = set()
+    while todo:
+        name = todo.pop()
+        if name in lines_of and name not in inside:
+            inside.add(name)
+            todo.extend(called(name))
+    out = []
+    for name in sorted(inside):
+        for line in lines_of[name]:
+            m = _COLLECTIVE_OP.search(line)
+            if m:
+                dims = [tuple(int(d) for d in g.split(",") if d)
+                        for g in re.findall(r"\w+\[([\d,]*)\]", m["type"])]
+                out.append({"op": m["op"], "dims": dims, "computation": name})
+    return out
 
 
 # --------------------------------------------------------------------------

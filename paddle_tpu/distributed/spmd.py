@@ -18,8 +18,9 @@ Sharding rules (build_param_specs):
         PartitionSpec entries on "model".
 - PP:   params carry ``_pp_stage`` or are stage-stacked on dim 0 ("pipe").
 - ZeRO: optimizer slots (+ params at stage 3) additionally sharded over
-        "sharding" on the largest divisible free dim.
-- DP:   batch dim of inputs on "data"; params replicated over "data".
+        "sharding": a free divisible dim, never the axis a layer scan slices.
+- DP:   batch dim of inputs on the data-parallel axes ("data", "sharding");
+        params replicated over "data".
 """
 
 from __future__ import annotations
@@ -39,7 +40,7 @@ from ..core.tensor import Tensor
 # test suite historically imported it from spmd.
 from .sharding_rules import (_slot_spec, _spec_for_param, batch_spec,
                              build_param_specs, build_state_shardings,
-                             replicated_spec)
+                             constrain_batch, replicated_spec)
 
 
 # --------------------------------------------------------------------------
@@ -207,8 +208,6 @@ def make_spmd_train_step(layer, loss_fn, optimizer, hcg, zero_stage: int = 0,
     if policy.stateful:
         state0["comm_e"] = policy.residual_for(params0)
         state_sh["comm_e"] = NamedSharding(mesh, replicated_spec())
-    batch_sh = NamedSharding(mesh, batch_spec(mesh))
-    rep = NamedSharding(mesh, replicated_spec())
 
     def place(state):
         return jax.tree_util.tree_map(
@@ -262,7 +261,11 @@ def make_spmd_train_step(layer, loss_fn, optimizer, hcg, zero_stage: int = 0,
 def _make_gspmd_step(loss_of, optimizer, mesh, p_specs, donate,
                      grad_comm=None):
     """The shared jitted step kernel: fwd+bwd+update with params
-    re-constrained each step so shardings stay stable under donation.
+    re-constrained each step so shardings stay stable under donation, and
+    every batch operand pinned to the data-parallel axes INSIDE the step
+    (callers hand it uncommitted arrays): with the rows split and the
+    weights split inside the layer, a weight gradient is a partial sum
+    per device that lands on a split layout — a reduce-scatter.
 
     ``grad_comm``: gradient-communication policy applied in LOCAL mode at
     the post-backward seam (GSPMD owns the collective schedule here —
@@ -274,6 +277,8 @@ def _make_gspmd_step(loss_of, optimizer, mesh, p_specs, donate,
 
     @functools.partial(jax.jit, donate_argnums=(0,) if donate else ())
     def step(state, lr, *batch):
+        batch = jax.tree_util.tree_map(
+            lambda x: constrain_batch(x, mesh), batch)
         loss, grads = jax.value_and_grad(loss_of)(state["params"], *batch)
         grads, comm_state = apply_policy_local(policy, grads, state)
         with jax.named_scope("optimizer"):  # a region, like the model's
@@ -313,10 +318,15 @@ def make_gspmd_step_from_loss(loss_of, params0, optimizer, mesh, layer=None,
 
 
 def shard_batch(batch, hcg):
+    """Each leaf placed with its rows over the data-parallel axes that
+    divide them (sharding_rules.batch_spec)."""
     mesh = hcg.mesh
-    sh = NamedSharding(mesh, batch_spec(mesh))
-    return jax.tree_util.tree_map(
-        lambda x: jax.device_put(getattr(x, "_data", x), sh), batch)
+
+    def put(x):
+        x = getattr(x, "_data", x)
+        return jax.device_put(
+            x, NamedSharding(mesh, batch_spec(mesh, x.shape[0])))
+    return jax.tree_util.tree_map(put, batch)
 
 
 def make_gspmd_sharded_init_step(loss_of, build_params, optimizer, mesh,

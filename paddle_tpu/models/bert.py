@@ -186,6 +186,8 @@ class BertModel(Layer):
                         sl["blocks_fc2_b"])
 
     def scan_blocks(self, params, h, attn_mask=None, remat=True, mesh=None):
+        from ..distributed.sharding_rules import constrain_activation
+        h = constrain_activation(h, mesh)   # the carry: rows on the batch axes
         stacked = {k: params[k] for k in self.stacked_param_names()}
 
         def fn(sl, hh):

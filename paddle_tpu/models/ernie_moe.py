@@ -170,6 +170,8 @@ class ErnieMoeModel(CausalDecoderMixin, Layer):
         return self._moe_residual(sl, h, mesh=mesh)
 
     def scan_blocks(self, params, h, mesh=None, remat=True):
+        from ..distributed.sharding_rules import constrain_activation
+        h = constrain_activation(h, mesh)   # the carry: rows on the batch axes
         stacked = {k: params[k] for k in self.stacked_param_names()}
         fn = self.block_fn
         if remat:

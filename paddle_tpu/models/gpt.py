@@ -280,7 +280,12 @@ class GPTModel(CausalDecoderMixin, Layer):
         recompute (≙ RecomputeOptimizer, fluid/optimizer.py:5930); "dots" =
         selective policy that saves MXU (matmul) outputs and recomputes only
         elementwise interiors — near-full-speed backward at a fraction of the
-        activation memory (the TPU-idiomatic default for large batches)."""
+        activation memory (the TPU-idiomatic default for large batches).
+        Under a ``mesh`` the carry is pinned to the batch axes (and "sep"):
+        every block runs on its own rows, and a weight split inside the
+        layer is gathered, one layer an iteration, where it is used."""
+        from ..distributed.sharding_rules import constrain_activation
+        h = constrain_activation(h, mesh)
         stacked = {k: params[k] for k in self.stacked_param_names()}
         if remat:
             policy = None
@@ -485,9 +490,7 @@ def make_gpt_train_step(model: GPTModel, optimizer, hcg, n_microbatches: int = 1
     """
     from ..distributed.grad_comm import comm_info, resolve_policy
     from ..distributed.pipeline_engine import make_stacked_pipeline_step
-    from ..distributed.sharding_rules import activation_batch_spec
     from ..distributed.spmd import make_gspmd_step_from_loss
-    from jax.sharding import NamedSharding
 
     policy = resolve_policy(grad_comm)
     mesh = hcg.mesh
@@ -523,12 +526,8 @@ def make_gpt_train_step(model: GPTModel, optimizer, hcg, n_microbatches: int = 1
             donate=donate, remat=remat, virtual_pp_degree=virtual_pp_degree,
             monitor=monitor)
 
-    seq_spec = activation_batch_spec(mesh)
-
     def loss_of(params, key, x, labels):
         h = model.embed_fn(params, x, key)
-        if seq_spec is not None:
-            h = jax.lax.with_sharding_constraint(h, NamedSharding(mesh, seq_spec))
         h = model.scan_blocks(params, h, key, remat=remat, mesh=mesh)
         return model.head_loss_fn(params, h, labels)
 
@@ -552,8 +551,8 @@ def make_gpt_train_step(model: GPTModel, optimizer, hcg, n_microbatches: int = 1
         from ..distributed.update_sharding import \
             make_dp_update_sharded_train_step
 
-        # inside the dp shard_map the batch is already local — no GSPMD
-        # activation constraint to thread (seq_spec is a GSPMD-path hint)
+        # inside the dp shard_map the batch is already local — no mesh, so
+        # scan_blocks threads no GSPMD activation constraint
         def loss_of_local(params, key, x, labels):
             h = model.embed_fn(params, x, key)
             h = model.scan_blocks(params, h, key, remat=remat)

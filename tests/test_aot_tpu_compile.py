@@ -117,6 +117,67 @@ def test_flash_under_dp2_mp2_mesh_compiles(v5e):
         compile_for(jax.grad(flash_loss(None), argnums=(0, 1, 2)), q, q, q)
 
 
+def test_zero3_step_gathers_one_layer_for_v5e_2x2(v5e):
+    """The ZeRO-3 GPT step for the four described chips (12 layers, and no
+    other dim of it is 12; widths a fraction of the 1.3B cell's, whose own
+    compile is ``benchmarks/tools/compile_real.py c1p3b-train-x4``): no
+    all-gather inside the layer scan's loops has the layer count among
+    its dims — a layer an iteration, never the stack — and the block
+    gradients are reduced there (this compiler writes a reduce-scatter as
+    an ``all-reduce-scatter`` fusion round an ``all-reduce``)."""
+    from paddle_tpu.core import rng
+    from paddle_tpu.distributed import spmd
+    from paddle_tpu.distributed.sharding_rules import loop_collectives
+    from paddle_tpu.models.gpt import GPTConfig, GPTModel
+    from paddle_tpu.optimizer import AdamW
+
+    L, H, I, B, S = 12, 256, 1024, 8, 1024
+    cfg = GPTConfig(vocab_size=2048, hidden_size=H, num_layers=L,
+                    num_attention_heads=2, intermediate_size=I,
+                    max_position_embeddings=S, compute_dtype="bfloat16")
+    mesh = Mesh(np.array(v5e.devices).reshape(1, 4), ("data", "sharding"))
+    optimizer = AdamW(3e-4, weight_decay=0.01)
+    holder = {}
+
+    def init_state(key):
+        with rng.rng_scope(key):
+            holder["model"] = GPTModel(cfg)
+        params = {n: p._data for n, p in holder["model"].named_parameters()}
+        return {"params": params, "opt": optimizer.init_state(params),
+                "buffers": {}}
+
+    state_abs = jax.eval_shape(init_state, jax.random.key(0))
+    model = holder["model"]
+
+    def loss_of(params, key, x, labels):
+        h = model.embed_fn(params, x, key)
+        h = model.scan_blocks(params, h, key, remat="dots", mesh=mesh)
+        return model.head_loss_fn(params, h, labels)
+
+    p_specs = spmd.build_param_specs(state_abs["params"], mesh, model, 3)
+    state_sh = spmd.build_state_shardings(state_abs, p_specs, mesh, 3,
+                                          state_abs["params"])
+    step = spmd._make_gspmd_step(loss_of, optimizer, mesh, p_specs, True)
+    rep = NamedSharding(mesh, P())
+    abstract = lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                  sharding=sh)
+    state = jax.tree.map(abstract, state_abs, state_sh)
+    key = abstract(jax.eval_shape(lambda: jax.random.key(0)), rep)
+    ids = jax.ShapeDtypeStruct((B, S), jnp.int32, sharding=rep)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=rep)
+    text = step.lower(state, lr, key, ids, ids).compile().as_text()
+
+    rows = loop_collectives(text)
+    gathers = [d for r in rows if r["op"] == "all-gather" for d in r["dims"]]
+    assert gathers and all(L not in d for d in gathers), gathers
+    assert {(H, 3 * H), (H, I)} <= {d[-2:] for d in gathers}
+    reduced = {d[-2:] for r in rows if r["op"] == "all-reduce"
+               for d in r["dims"] if r["computation"].startswith(
+                   "all-reduce-scatter")}
+    assert {(H, 3 * H), (H, I)} <= reduced, reduced
+    assert text.count(KERNEL) == 4      # flash forward (twice: remat), dQ, dK/dV
+
+
 def pool_args(topo, hd, int8):
     nh = HEADS[hd]
     n_blocks = SLOTS * COLS + 1

@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from paddle_tpu.distributed import sharding_rules as sr
 from paddle_tpu.distributed.update_sharding import (
@@ -37,6 +38,31 @@ def _mesh(n, names=("data",), shape=None):
     if shape is not None:
         devs = devs.reshape(shape)
     return Mesh(devs, names)
+
+
+# (data, sharding) -> the axes a batch of 8 rows is split over: "data" first,
+# then "sharding", each where its size is over 1 and still divides the rows
+BATCH_AXES_CASES = [
+    (1, 1, ()), (1, 2, ("sharding",)), (1, 4, ("sharding",)),
+    (2, 1, ("data",)), (2, 2, ("data", "sharding")),
+    (2, 4, ("data", "sharding")),
+    (4, 1, ("data",)), (4, 2, ("data", "sharding")),
+    (4, 4, ("data",)),                       # 16 does not divide 8
+]
+
+GPT_STACKED = ["blocks_ln1_w", "blocks_ln1_b", "blocks_qkv_w", "blocks_qkv_b",
+               "blocks_proj_w", "blocks_proj_b", "blocks_ln2_w",
+               "blocks_ln2_b", "blocks_fc1_w", "blocks_fc1_b",
+               "blocks_fc2_w", "blocks_fc2_b"]
+ERNIE_STACKED = ["blocks_ln1_w", "blocks_ln1_b", "blocks_qkv_w",
+                 "blocks_qkv_b", "blocks_proj_w", "blocks_proj_b",
+                 "blocks_ln2_w", "blocks_ln2_b", "blocks_gate_w",
+                 "blocks_expert_w1", "blocks_expert_b1", "blocks_expert_w2",
+                 "blocks_expert_b2"]
+UNSTACKED = ["wte", "wpe", "lnf_w", "lnf_b"]
+STACKED_CASES = [("gpt", n) for n in GPT_STACKED] + \
+    [("ernie", n) for n in ERNIE_STACKED]
+UNSTACKED_CASES = [(m, n) for m in ("gpt", "ernie") for n in UNSTACKED]
 
 
 def _fallback_stats():
@@ -218,16 +244,57 @@ class TestSpecConstructors:
                                        "data") == P("data", None, None)
         assert sr.replica_stacked_spec(np.zeros((4,)), "data") == P("data")
 
-    def test_batch_spec_falls_back_when_axis_is_trivial(self):
+    def test_batch_spec_without_a_batch_size_takes_every_axis_over_one(self):
         assert sr.batch_spec(_mesh(2)) == P("data")
         assert sr.batch_spec(_mesh(1)) == P()
-        assert sr.batch_spec(_mesh(2), "model") == P()
+        # "model" is no data-parallel axis: it never carries the batch
+        assert sr.batch_spec(_mesh(2, ("model",))) == P()
+        assert sr.batch_spec(
+            _mesh(4, ("data", "sharding"), shape=(2, 2))) == \
+            P(("data", "sharding"))
 
     def test_activation_batch_spec_per_mesh_shape(self):
         assert sr.activation_batch_spec(_mesh(2)) == P("data", None, None)
         assert sr.activation_batch_spec(
-            _mesh(2, ("data", "sep"), shape=(1, 2))) == P("data", "sep", None)
+            _mesh(2, ("data", "sep"), shape=(1, 2))) == P(None, "sep", None)
+        assert sr.activation_batch_spec(
+            _mesh(4, ("data", "sep"), shape=(2, 2))) == P("data", "sep", None)
+        assert sr.activation_batch_spec(
+            _mesh(2, ("sharding",))) == P("sharding", None, None)
         assert sr.activation_batch_spec(_mesh(1)) is None
+
+    @pytest.mark.parametrize("data,sharding,axes", BATCH_AXES_CASES,
+                             ids=[f"data{d}-sharding{s}"
+                                  for d, s, _ in BATCH_AXES_CASES])
+    def test_batch_axes_agree_everywhere(self, data, sharding, axes):
+        """One helper names the axes a batch of 8 is split over; the batch
+        spec, the activation spec and the attention kernels' shard_map
+        specs all say the same."""
+        mesh = AbstractMesh((data, sharding), ("data", "sharding"))
+        entry = axes or None
+        assert sr.data_parallel_axes(mesh, 8) == axes
+        assert sr.batch_spec(mesh, 8) == (P(axes) if axes else P())
+        assert sr.activation_batch_spec(mesh, 8) == \
+            (P(entry, None, None) if axes else None)
+        specs, sharded = sr.attention_specs(mesh, 8, 4)
+        assert specs["qkv"] == P(entry, None, None, None)
+        assert specs["kmask"] == P(entry, None)
+        assert specs["stat"] == P(entry, None, None)
+        assert sharded == axes
+
+    def test_constrain_helpers_leave_a_single_replica_alone(self):
+        x = jnp.zeros((8, 4, 2))
+        assert sr.constrain_batch(x, None) is x
+        assert sr.constrain_batch(x, _mesh(1)) is x
+        assert sr.constrain_activation(x, _mesh(1)) is x
+        key = jax.random.key(0)
+        assert sr.constrain_batch(
+            key, _mesh(2, ("sharding",))) is key      # a scalar: no rows
+        mesh = _mesh(2, ("sharding",))
+        out = jax.jit(lambda a: sr.constrain_batch(a, mesh))(x)
+        assert out.sharding.spec == P("sharding")
+        odd = jnp.zeros((3, 4))                       # 2 does not divide 3
+        assert sr.constrain_batch(odd, mesh) is odd
 
     def test_sep_activation_spec(self):
         assert sr.sep_activation_spec() == P(None, "sep", None, None)
@@ -512,3 +579,216 @@ class TestUpdateSharding:
         with pytest.raises(NotImplementedError, match="non-trivial axes"):
             make_dp_update_sharded_train_step(
                 _MLP.loss, _MLP.params(), Adam(0.05), hybrid)
+
+
+# ==========================================================================
+# 5. the ZeRO-3 layout: weights split inside the layer, never on the axis
+#    the layer scan slices; what a step must move, from the shapes alone
+# ==========================================================================
+
+LAYERS = 8          # the toy models' depth: no other dim of theirs is 8
+
+
+@pytest.fixture(scope="module")
+def meta_models():
+    """{"gpt" | "ernie": (model, abstract params)} — built under
+    eval_shape, so only shapes and metadata are real."""
+    from paddle_tpu.core import rng
+    from paddle_tpu.models.ernie_moe import ErnieMoeConfig, ErnieMoeModel
+    from paddle_tpu.models.gpt import GPTConfig, GPTModel
+
+    def meta(cls, cfg):
+        holder = {}
+
+        def build(key):
+            with rng.rng_scope(key):
+                holder["model"] = cls(cfg)
+            return {n: p._data
+                    for n, p in holder["model"].named_parameters()}
+
+        shapes = jax.eval_shape(build, jax.random.key(0))
+        return holder["model"], shapes
+
+    return {
+        "gpt": meta(GPTModel, GPTConfig(
+            vocab_size=128, hidden_size=64, num_layers=LAYERS,
+            num_attention_heads=2, intermediate_size=256,
+            max_position_embeddings=32)),
+        "ernie": meta(ErnieMoeModel, ErnieMoeConfig(
+            vocab_size=128, hidden_size=64, num_layers=LAYERS,
+            num_attention_heads=2, num_experts=4, expert_hidden_size=128,
+            max_position_embeddings=32)),
+    }
+
+
+def _sharding_mesh(n):
+    return _mesh(n, ("data", "sharding"), shape=(1, n))
+
+
+def _first_divisible(shape, n):
+    """The rule before PR 26, and still the rule for a parameter no scan
+    stacks: the first dim that ``n`` divides."""
+    entries = [None] * len(shape)
+    for d, size in enumerate(shape):
+        if size % n == 0:
+            entries[d] = "sharding"
+            break
+    return P(*entries)
+
+
+class TestZero3Layout:
+    def test_the_literal_names_are_the_models_own(self, meta_models):
+        assert GPT_STACKED == meta_models["gpt"][0].stacked_param_names()
+        assert ERNIE_STACKED == meta_models["ernie"][0].stacked_param_names()
+        for model, shapes in meta_models.values():
+            assert set(shapes) == set(model.stacked_param_names()) | \
+                set(UNSTACKED)
+
+    @pytest.mark.parametrize("degree", [2, 4], ids=["sharding2", "sharding4"])
+    @pytest.mark.parametrize("which,name", STACKED_CASES,
+                             ids=[f"{m}-{n}" for m, n in STACKED_CASES])
+    def test_stacked_parameter_is_split_inside_the_layer(
+            self, meta_models, which, name, degree):
+        model, shapes = meta_models[which]
+        mesh = _sharding_mesh(degree)
+        shape = shapes[name].shape
+        assert shape[0] == LAYERS
+        spec = sr.build_param_specs(shapes, mesh, model, 3)[name]
+        assert len(spec) == len(shape)
+        assert spec[0] is None                 # the scanned axis stays whole
+        at = [d for d, e in enumerate(spec) if e == "sharding"]
+        assert len(at) == 1 and at[0] >= 1
+        inside = [d for d in range(1, len(shape)) if shape[d] % degree == 0]
+        assert shape[at[0]] == max(shape[d] for d in inside)
+        # optimizer slots follow their parameter
+        assert sr._slot_spec(spec, shapes[name], mesh, 3) == spec
+
+    @pytest.mark.parametrize("degree", [2, 4], ids=["sharding2", "sharding4"])
+    @pytest.mark.parametrize("which,name", STACKED_CASES,
+                             ids=[f"{m}-{n}" for m, n in STACKED_CASES])
+    def test_below_stage_3_a_stacked_parameter_is_as_before(
+            self, meta_models, which, name, degree):
+        model, shapes = meta_models[which]
+        mesh = _sharding_mesh(degree)
+        for stage in (0, 1, 2):
+            spec = sr.build_param_specs(shapes, mesh, model, stage)[name]
+            assert spec == P(*[None] * len(shapes[name].shape))
+            # ... and its slots still take the first divisible dim
+            assert sr._slot_spec(spec, shapes[name], mesh, stage) == (
+                spec if stage == 0
+                else _first_divisible(shapes[name].shape, degree))
+
+    @pytest.mark.parametrize("degree", [2, 4], ids=["sharding2", "sharding4"])
+    @pytest.mark.parametrize("which,name", UNSTACKED_CASES,
+                             ids=[f"{m}-{n}" for m, n in UNSTACKED_CASES])
+    def test_unstacked_parameter_keeps_the_first_divisible_dim(
+            self, meta_models, which, name, degree):
+        model, shapes = meta_models[which]
+        mesh = _sharding_mesh(degree)
+        spec = sr.build_param_specs(shapes, mesh, model, 3)[name]
+        assert spec == _first_divisible(shapes[name].shape, degree)
+        for stage in (0, 1, 2):
+            assert sr.build_param_specs(shapes, mesh, model, stage)[name] \
+                == P(*[None] * len(shapes[name].shape))
+
+    def test_with_no_layer_the_old_rule_stands(self, meta_models):
+        """Stackedness reaches the rule through the layer; a bare call (as
+        tests/test_northstar_67b.py makes) knows of no scan."""
+        _, shapes = meta_models["gpt"]
+        mesh = _sharding_mesh(4)
+        specs = sr.build_param_specs(shapes, mesh, None, 3)
+        assert specs["blocks_qkv_w"] == P("sharding", None, None)
+        assert sr._spec_for_param("blocks_qkv_w", shapes["blocks_qkv_w"],
+                                  mesh, {}, 3, False) == \
+            P("sharding", None, None)
+
+    def test_a_stacked_leaf_with_no_divisible_dim_inside_stays_whole(self):
+        class Layer:
+            stacked_param_names = staticmethod(lambda: ["odd", "vec"])
+            named_parameters = staticmethod(lambda: [])
+
+        shapes = {"odd": jax.ShapeDtypeStruct((LAYERS, 3, 5), jnp.float32),
+                  "vec": jax.ShapeDtypeStruct((LAYERS, 12), jnp.float32)}
+        specs = sr.build_param_specs(shapes, _sharding_mesh(4), Layer(), 3)
+        assert specs["odd"] == P(None, None, None)    # never dim 0
+        assert specs["vec"] == P(None, "sharding")
+
+    def test_the_catalog_says_so_and_its_digest_moved(self):
+        rows = dict(sr._RULE_CATALOG)
+        assert "never dim 0" in rows["zero3"]
+        assert "data_parallel_axes" in rows["batch"]
+        assert sr.CATALOG_VERSION >= 2
+
+    def test_layout_comm_counts_what_a_step_must_move(self, meta_models):
+        model, shapes = meta_models["gpt"]
+        mesh = _sharding_mesh(4)
+        stacked = model.stacked_param_names()
+        specs = sr.build_param_specs(shapes, mesh, model, 3)
+        good = sr.zero3_layout_comm(shapes, specs, mesh, stacked)
+        nbytes = {n: int(np.prod(a.shape)) * 4 for n, a in shapes.items()}
+        total = sum(nbytes.values())
+        assert good["scan_axis_leaves"] == 0
+        assert good["gather_bytes"] == sum(2 * (b * 3 // 4)
+                                           for b in nbytes.values())
+        assert good["reduce_bytes"] == sum(b * 3 // 4
+                                           for b in nbytes.values())
+        assert good["gather_bytes"] <= 2 * total
+        # the gauges a dashboard reads, set when the specs are built
+        stats = get_all_stats()
+        assert stats["sharding_zero3_scan_axis_leaves"] == 0
+        assert stats["sharding_zero3_gather_bytes"] == good["gather_bytes"]
+        # the layout before PR 26: every stacked leaf split on the scanned
+        # axis, so each of the scan's iterations gathers the whole stack
+        bad_specs = sr.build_param_specs(shapes, mesh, None, 3)
+        bad = sr.zero3_layout_comm(shapes, bad_specs, mesh, stacked)
+        assert bad["scan_axis_leaves"] == len(stacked)
+        extra = sum(2 * (LAYERS - 1) * (nbytes[n] * 3 // 4) for n in stacked)
+        assert bad["gather_bytes"] == good["gather_bytes"] + extra
+        assert bad["reduce_bytes"] == good["reduce_bytes"]
+
+    def test_layout_comm_all_reduces_a_replicated_leaf(self):
+        shapes = {"w": jax.ShapeDtypeStruct((3, 5), jnp.float32)}
+        out = sr.zero3_layout_comm(shapes, {"w": P(None, None)},
+                                   _sharding_mesh(2))
+        assert out == {"gather_bytes": 0, "reduce_bytes": 2 * 30,
+                       "scan_axis_leaves": 0}
+
+
+_HLO = """\
+HloModule toy
+
+%fused_gather (p: bf16[1,16,48]) -> bf16[1,64,48] {
+  %p = bf16[1,16,48]{2,1,0} parameter(0)
+  ROOT %all-gather.1 = bf16[1,64,48]{2,1,0:T(8,128)(2,1)} all-gather(%p), dimensions={1}
+}
+
+%body.1 (arg: (s32[], f32[8,64])) -> (s32[], f32[8,64]) {
+  %arg = (s32[], f32[8,64]) parameter(0)
+  %fusion.1 = bf16[1,64,48]{2,1,0} fusion(%x), kind=kCustom, calls=%fused_gather
+  %all-reduce.2 = (f32[64]{0}, f32[64,192]{1,0}, /*index=2*/f32[192]{0}) all-reduce(%a, %b, %c), to_apply=%add
+  %ags = (bf16[16,48], bf16[64,48]) all-gather-start(%y), dimensions={0}
+  ROOT %t = (s32[], f32[8,64]) tuple(%i, %h)
+}
+
+%cond.1 (arg: (s32[], f32[8,64])) -> pred[] {
+  ROOT %lt = pred[] compare(%i, %n), direction=LT
+}
+
+ENTRY %main (x: f32[8,64]) -> f32[8,64] {
+  %all-gather.9 = f32[8,64,64]{2,1,0} all-gather(%w), dimensions={0}
+  %while.1 = (s32[], f32[8,64]) while(%init), condition=%cond.1, body=%body.1
+  ROOT %out = f32[8,64] get-tuple-element(%while.1), index=1
+}
+"""
+
+
+def test_loop_collectives_reads_bodies_and_what_they_call():
+    rows = sr.loop_collectives(_HLO)
+    got = sorted((r["op"], r["dims"], r["computation"]) for r in rows)
+    assert got == [
+        ("all-gather", [(1, 64, 48)], "fused_gather"),
+        ("all-gather", [(16, 48), (64, 48)], "body.1"),
+        ("all-reduce", [(64,), (64, 192), (192,)], "body.1"),
+    ]
+    # the whole-stack gather in the entry computation is outside any loop
+    assert all(LAYERS not in d for r in rows for d in r["dims"])

@@ -135,6 +135,75 @@ class TestGPTZero:
         np.testing.assert_allclose(serial, sharded, rtol=2e-5, atol=1e-6)
 
 
+    def test_zero3_step_gathers_one_layer_and_matches_one_device(self):
+        """The compiled ZeRO-3 step of a GPT whose blocks are stacked for
+        the layer scan (L = 8, and no other dim of it is 8): inside the
+        scan's loops no all-gather has the layer count among its dims (one
+        layer an iteration, never the stack), the block weights ARE
+        gathered there, and the block gradients are reduced over the four
+        devices (the CPU compiler writes a reduce-scatter as an all-reduce
+        and a slice).  Loss and updated parameters are the one-device
+        step's."""
+        from paddle_tpu.distributed.sharding_rules import loop_collectives
+        from paddle_tpu.models.gpt import (GPTConfig,
+                                           make_sharded_gpt_train_step)
+        from paddle_tpu.optimizer import Momentum
+
+        L, H, I = 8, 64, 128
+        cfg = GPTConfig(vocab_size=128, hidden_size=H, num_layers=L,
+                        num_attention_heads=2, intermediate_size=I,
+                        max_position_embeddings=256,
+                        compute_dtype="float32")
+        # 256 positions a row: the rows a device owns outweigh a layer's
+        # weights, as they do at real sizes, so the partitioner moves the
+        # weights and not the rows
+        x = jnp.asarray(np.random.RandomState(0).randint(0, 128, (4, 256)))
+        y = jnp.asarray(np.random.RandomState(1).randint(0, 128, (4, 256)))
+        lr = np.float32(0.1)
+
+        def run(sharding, stage):
+            strategy = fleet.DistributedStrategy()
+            strategy.hybrid_configs = {"dp_degree": 1, "mp_degree": 1,
+                                       "pp_degree": 1,
+                                       "sharding_degree": sharding}
+            fleet.fleet.init(is_collective=True, strategy=strategy)
+            hcg = fleet.fleet.get_hybrid_communicate_group()
+            step, state = make_sharded_gpt_train_step(
+                cfg, Momentum(0.1, momentum=0.9), hcg, zero_stage=stage,
+                seed=3, remat="dots", donate=False)
+            text = step.lower(state, lr, jax.random.key(0), x,
+                              y).compile().as_text()
+            losses = []
+            for _ in range(3):
+                state, loss = step(state, lr, jax.random.key(0), x, y)
+                losses.append(float(loss))
+            return losses, state["params"], text
+
+        serial, p1, _ = run(1, 0)
+        sharded, p4, text = run(4, 3)
+        np.testing.assert_allclose(serial, sharded, rtol=2e-5, atol=1e-6)
+        for k in p1:
+            np.testing.assert_allclose(np.asarray(p1[k]), np.asarray(p4[k]),
+                                       rtol=2e-5, atol=2e-6, err_msg=k)
+            if k.startswith("blocks_"):    # the scanned axis stays whole
+                assert p4[k].sharding.shard_shape(p4[k].shape)[0] == L, k
+
+        rows = loop_collectives(text)
+
+        def weights_of(ops):
+            """Result dims of those collectives in the loops, as sorted
+            (rows, columns) of a block matrix (a leading 1 dropped)."""
+            return {tuple(sorted(d[-2:])) for r in rows if r["op"] in ops
+                    for d in r["dims"] if len(d) >= 2}
+
+        gathers = [d for r in rows if r["op"] == "all-gather"
+                   for d in r["dims"]]
+        assert gathers and all(L not in d for d in gathers), gathers
+        matrices = {(H, 3 * H), (H, H), (H, I)}   # qkv, proj, fc1 and fc2
+        assert matrices <= weights_of(("all-gather",))
+        assert matrices <= weights_of(("all-reduce", "reduce-scatter"))
+
+
 @needs8
 class TestLossScaling:
     def test_found_inf_skips_update_and_backs_off(self):
