@@ -4,3 +4,4 @@ from .bert import (BERT_CONFIGS, BertConfig, BertModel, bert_preset,  # noqa: F4
                    make_bert_train_step)
 from .ernie_moe import (ErnieMoeConfig, ErnieMoeModel,  # noqa: F401
                         make_ernie_moe_train_step)
+from .pangu_moe import PanguMoeConfig, PanguMoeModel  # noqa: F401

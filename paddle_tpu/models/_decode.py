@@ -11,10 +11,47 @@ conventions and the sampler cannot drift between model families.
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+
+
+class CacheLeaf(NamedTuple):
+    """One cache leaf as the model states it: ``layers`` of them stacked,
+    ``tail`` the shape of one position's entry, ``dtype`` its dtype.  A
+    paged engine stores it as ``(layers, NB + 1, block_size) + tail``
+    (block 0 the trash block), a dense cache as ``(layers, B, max_len) +
+    tail``."""
+    layers: int
+    tail: Tuple[int, ...]
+    dtype: str
+
+
+class CacheSpec(NamedTuple):
+    """What a model caches per token (docs/CACHE_SPEC.md).  The serving
+    engines read this, never the model's class.
+
+    ``pools``: a tuple of entries in the order ``decode_ragged`` takes and
+    returns them, each a pytree of ``CacheLeaf`` (an int8 K plane is a
+    ``(values, scales)`` pair of leaves).  ``layout``: "kv" for one K and
+    one V entry per head and layer (what the tiered KV store and the
+    bucketed paged programs are written for); any other name is the
+    model's own.  ``tick_stats``: names of the int32 counters
+    ``decode_ragged`` returns as a third output, one vector entry each
+    (empty: it returns two outputs)."""
+    pools: tuple
+    layout: str = "kv"
+    tick_stats: Tuple[str, ...] = ()
+
+
+def build_pools(spec: CacheSpec, lead: Tuple[int, ...]):
+    """Zeroed storage for ``spec``: every leaf ``(layers,) + lead + tail``
+    (``lead`` is ``(NB + 1, block_size)`` for a block pool)."""
+    return jax.tree.map(
+        lambda leaf: jnp.zeros((leaf.layers,) + tuple(lead) + leaf.tail,
+                               jnp.dtype(leaf.dtype)),
+        spec.pools, is_leaf=lambda x: isinstance(x, CacheLeaf))
 
 
 def cached_attention(q, ck, cv, t, pad_lens=None):
@@ -151,13 +188,9 @@ def ragged_attention(q_rows, pool_k, pool_v, table, row_seq, row_pos,
     mode for CPU CI — the ops/fused.py flag convention shared with
     cached_attention's paged arm) and the XLA gather fallback; int8 pools
     take the kernel too (dequant is fused in-kernel)."""
-    from ..core.flags import flag
     from ..ops.ragged_paged_attention import (ragged_attention_ref,
                                               ragged_attention_rows)
-    interp = (bool(flag("FLAGS_paged_attn_interpret"))
-              and jax.default_backend() != "tpu")
-    use = flag("FLAGS_use_pallas_kernels") and \
-        (jax.default_backend() == "tpu" or interp)
+    use, interp = _pallas_dispatch()
     if use:
         return ragged_attention_rows(q_rows, pool_k, pool_v, table,
                                      row_seq, row_pos, pad_lens,
@@ -166,11 +199,44 @@ def ragged_attention(q_rows, pool_k, pool_v, table, row_seq, row_pos,
                                 row_pos, pad_lens)
 
 
-def ragged_write(pool, chunk, table, row_seq, row_pos):
+def _pallas_dispatch():
+    """(use the Pallas kernel, run it interpreted): the flag convention
+    ``ragged_attention`` and ``cached_attention`` share."""
+    from ..core.flags import flag
+    interp = (bool(flag("FLAGS_paged_attn_interpret"))
+              and jax.default_backend() != "tpu")
+    use = flag("FLAGS_use_pallas_kernels") and \
+        (jax.default_backend() == "tpu" or interp)
+    return bool(use), interp
+
+
+def ragged_latent_attention(q_abs, q_r, pool, table, row_seq, row_pos,
+                            pad_lens=None, *, scale, layer=None):
+    """Absorbed latent (MLA) attention for a flattened ragged pack over
+    ONE layer's latent pool: q_abs (T, nh, R), q_r (T, nh, Dr), pool
+    (NB+1, bs, W >= R + Dr) — or a stack's pools (L, NB+1, bs, W) and
+    ``layer``, read in place — the value of a key being its first R
+    columns; output (T, nh, R) in latent space.  Dispatched like
+    ``ragged_attention`` (ops/ragged_latent_attention.py)."""
+    from ..ops.ragged_latent_attention import (
+        ragged_latent_attention_ref, ragged_latent_attention_rows)
+    use, interp = _pallas_dispatch()
+    if use:
+        return ragged_latent_attention_rows(
+            q_abs, q_r, pool, table, row_seq, row_pos, pad_lens,
+            scale=scale, layer=layer, interpret=interp)
+    return ragged_latent_attention_ref(q_abs, q_r, pool, table, row_seq,
+                                       row_pos, pad_lens, scale=scale,
+                                       layer=layer)
+
+
+def ragged_write(pool, chunk, table, row_seq, row_pos, layer=None):
     """Scatter a flattened ragged chunk (T, nh, hd) into ONE layer's block
     pool at each row's (table-mapped block, offset); padding rows
     (row_pos < 0) land in the trash block.  int8 pools quantize the chunk
-    and write both planes (quantize_kv layout)."""
+    and write both planes (quantize_kv layout).  With ``layer`` the pool
+    is a whole stack's (L, NB+1, bs, ...) and the rows land in that
+    layer's blocks, in place."""
     if isinstance(pool, tuple):
         vals, scales = pool
         with jax.named_scope("kv_write"):
@@ -178,11 +244,13 @@ def ragged_write(pool, chunk, table, row_seq, row_pos):
         return (ragged_write(vals, q, table, row_seq, row_pos),
                 ragged_write(scales, s, table, row_seq, row_pos))
     with jax.named_scope("kv_write"):
-        bs = pool.shape[1]
+        bs = pool.shape[1 if layer is None else 2]
         seq = jnp.clip(row_seq, 0, table.shape[0] - 1)
         col = jnp.clip(row_pos // bs, 0, table.shape[1] - 1)
         pb = jnp.where(row_pos >= 0, table[seq, col], 0)
         off = jnp.where(row_pos >= 0, row_pos % bs, 0)
+        if layer is not None:
+            return pool.at[layer, pb, off].set(chunk.astype(pool.dtype))
         return pool.at[pb, off].set(chunk.astype(pool.dtype))
 
 
@@ -514,18 +582,22 @@ class CausalDecoderMixin:
                 wpe = params["wpe"][t_arr][:, None, :]
             return (wte + wpe).astype(dt)
 
-    def init_cache(self, batch_size: int, max_len: int):
+    def cache_spec(self) -> CacheSpec:
+        """One K and one V entry per head and layer (int8: each a
+        ``(values, scales)`` pair) — what GPT and ERNIE-MoE cache."""
         c = self.config
-        dt = jnp.dtype(c.compute_dtype)
         nh = c.num_attention_heads
         hd = c.hidden_size // nh
-        shape = (c.num_layers, batch_size, max_len, nh, hd)
         if getattr(c, "kv_cache_dtype", None) == "int8":
-            def one():
-                return (jnp.zeros(shape, jnp.int8),
-                        jnp.zeros(shape[:-1], jnp.float32))
-            return one(), one()
-        return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+            one = (CacheLeaf(c.num_layers, (nh, hd), "int8"),
+                   CacheLeaf(c.num_layers, (nh,), "float32"))
+        else:
+            one = CacheLeaf(c.num_layers, (nh, hd),
+                            str(jnp.dtype(c.compute_dtype)))
+        return CacheSpec(pools=(one, one))
+
+    def init_cache(self, batch_size: int, max_len: int):
+        return build_pools(self.cache_spec(), (batch_size, max_len))
 
     def generate(self, params, input_ids, max_new_tokens: int,
                  temperature: float = 1.0, top_k: Optional[int] = None,

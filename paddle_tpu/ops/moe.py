@@ -258,3 +258,80 @@ def moe_ffn_gather(x, gate_w, w1, b1, w2, b2, k: int = 2,
     # must hold at bf16 compute dtype too
     return jnp.sum(out.astype(jnp.float32) * gates[..., None],
                    axis=1).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# sigmoid routing over more experts than are held, no capacity, no drop
+# (DeepSeek-V3 / Pangu Ultra MoE serving: one chip's share of a layer whose
+# routed experts are spread expert-parallel over many)
+# ---------------------------------------------------------------------------
+
+def route_sigmoid_topk(x, gate_w, k: int, scaling: float = 1.0,
+                       normalize: bool = True):
+    """Scores ``sigmoid(x W_g)`` in float32 over ALL routed experts, the
+    ``k`` largest, their weights ``s / (sum s + 1e-20) * scaling``
+    (``normalize=False``: ``s * scaling``).  x (T, H); gate_w (H, E).
+    Returns (idx (T, k) int32, w (T, k) float32).  No group limit, no
+    selection bias, no capacity: routing never drops a token."""
+    s = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), gate_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top, idx = jax.lax.top_k(s, k)
+    if normalize:
+        top = top / (top.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), top * scaling
+
+
+def held_experts_ffn(x, idx, w, w_gate, w_up, w_down, first: int,
+                     valid=None):
+    """The held experts' part of a routed layer, dropless:
+    ``sum_{e held} w_e * W_down_e(silu(W_gate_e x) * (W_up_e x))``.
+
+    x (T, H); idx / w (T, k) from the router over all experts; w_gate /
+    w_up (Eh, H, F), w_down (Eh, F, H): the experts ``[first, first + Eh)``
+    held here.  ``valid`` (T,) bool leaves rows out (the pack's padding
+    rows).  Returns (out (T, H) float32, rows (Eh,) int32: the pairs each
+    held expert computed).
+
+    Token-expert pairs whose expert is held are sorted by expert (the
+    others sort behind them) and go through three grouped products
+    (``jax.lax.ragged_dot``).  No pair is dropped, whatever the imbalance:
+    the row buffer holds ``T * min(k, Eh)`` rows, and no routing can fill
+    more — a token's ``k`` experts are distinct (``top_k``), so at most
+    ``min(k, Eh)`` of them are held, and the held pairs, sorted first,
+    all lie inside the buffer.  Rows behind them are computed by no group
+    and weigh nothing."""
+    T, k = idx.shape
+    Eh = w_gate.shape[0]
+    R = T * min(k, Eh)
+    local = idx - first
+    held = (local >= 0) & (local < Eh)
+    if valid is not None:
+        held = held & valid[:, None]
+    key = jnp.where(held, local, Eh).reshape(-1)           # (T*k,)
+    with jax.named_scope("router"):                        # the sort
+        order = jnp.argsort(key, stable=True)
+        rows = jnp.zeros(Eh + 1, jnp.int32).at[key].add(1)[:Eh]
+        xs = x[order[:R] // k]                             # (R, H)
+    with jax.named_scope("experts"):
+        g = jax.lax.ragged_dot(xs, w_gate.astype(x.dtype), rows)
+        u = jax.lax.ragged_dot(xs, w_up.astype(x.dtype), rows)
+        y = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype),
+                               w_down.astype(x.dtype), rows)
+    with jax.named_scope("router"):                        # the un-sort
+        # each pair reads its row back by its place in the sorted order
+        # (a gather: a scatter-add of the rows costs fifteen times as much
+        # on the chip); pairs not held lie behind the buffer and weigh 0
+        place = jnp.minimum(jnp.argsort(order), R - 1)
+        y = jnp.where(held.reshape(-1, 1),
+                      y[place].astype(jnp.float32) * w.reshape(-1, 1), 0.0)
+        out = y.reshape(T, k, -1).sum(1)
+    return out, rows
+
+
+def gated_mlp(x, w_gate, w_up, w_down):
+    """``W_down(silu(W_gate x) * (W_up x))``, no biases: the dense MLP and
+    the shared expert of the gated-SiLU families."""
+    dt = x.dtype
+    return (jax.nn.silu(x @ w_gate.astype(dt)) * (x @ w_up.astype(dt))) \
+        @ w_down.astype(dt)

@@ -60,8 +60,8 @@ from .kv_store import KVPage, chain_hex
 from .telemetry import (PHASE_ADMIT, PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC,
                         PHASE_UNPACK)
 from .models._decode import (PagedKV, apply_repetition_penalty,
-                             greedy_verify, seed_presence, suppress_eos,
-                             suppress_eos_rows)
+                             build_pools, greedy_verify, seed_presence,
+                             suppress_eos, suppress_eos_rows)
 
 __all__ = ["PagedContinuousBatchingEngine",
            "PagedSpeculativeBatchingEngine",
@@ -103,6 +103,10 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
     the engine then admits/preempts against the real budget.
     """
 
+    # the bucketed prefill / decode programs below read one K and one V
+    # pool; the ragged engine's tick carries whatever the model states
+    _KV_PROGRAMS = True
+
     def __init__(self, model, params, max_slots: int, max_len: int,
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  enable_prefix_cache: bool = False, kv_store=None, **kw):
@@ -119,6 +123,12 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             raise ValueError(
                 "kv_store needs enable_prefix_cache=True — pages are "
                 "addressed by prefix-cache chain digests")
+        self.model = model             # _require_kv_layout names it
+        self.cache_spec = model.cache_spec()
+        if self._KV_PROGRAMS:
+            self._require_kv_layout(type(self).__name__)
+        if kv_store is not None:
+            self._require_kv_layout("kv_store")
         self.kv_store = kv_store
         self._kv_meta = None           # kv_page_meta() computes it once
         self.bs = int(block_size)
@@ -133,6 +143,12 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         if self.NB < 1:
             raise ValueError("num_blocks must be >= 1")
         super().__init__(model, params, max_slots, max_len, **kw)
+        if self.tracer is not None:     # static, so said once, not a tick
+            self.tracer.emit(
+                "cache", engine=type(self).__name__,
+                layout=self.cache_spec.layout,
+                pool_bytes=sum(leaf.nbytes
+                               for leaf in jax.tree.leaves(self.caches)))
         bad = [b for b in self.buckets if b % self.bs]
         if bad:
             raise ValueError(f"block_size ({self.bs}) must divide every "
@@ -180,23 +196,25 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
 
     # ------------------------------------------------------------ storage --
 
-    def _build_pool(self, c):
-        """Block pools for one model config (the paged-speculative
-        composition builds a second pool for the draft — SAME allocator
-        and tables, different pool storage)."""
-        nh = c.num_attention_heads
-        hd = c.hidden_size // nh
-        shape = (c.num_layers, self.NB + 1, self.bs, nh, hd)
-        if getattr(c, "kv_cache_dtype", None) == "int8":
-            def one():
-                return (jnp.zeros(shape, jnp.int8),
-                        jnp.zeros(shape[:-1], jnp.float32))
-            return one(), one()
-        dt = jnp.dtype(c.compute_dtype)
-        return jnp.zeros(shape, dt), jnp.zeros(shape, dt)
+    def _build_pool(self, spec):
+        """Block pools for one model's cache spec (models/_decode.py
+        ``CacheSpec``): every leaf it states, as ``(layers, NB + 1,
+        block_size) + tail``.  The paged-speculative composition builds a
+        second set for the draft — SAME allocator and tables, different
+        pool storage."""
+        return build_pools(spec, (self.NB + 1, self.bs))
 
     def _alloc_caches(self):
-        return self._build_pool(self.model.config)
+        return self._build_pool(self.cache_spec)
+
+    def _require_kv_layout(self, what: str):
+        """The tiered KV store moves pages of one K and one V entry per
+        head; a model that caches anything else is refused by name."""
+        if self.cache_spec.layout != "kv":
+            raise NotImplementedError(
+                f"{what} is written for the K/V cache layout; "
+                f"{type(self.model).__name__} caches "
+                f"{self.cache_spec.layout!r} leaves (docs/CACHE_SPEC.md)")
 
     def _paged_sig_suffix(self):
         from .core.flags import flag
@@ -380,6 +398,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         eviction demotes pages into it, admission lookups restore from
         it, and the gateway's migration path delivers cross-replica
         pages through it (docs/KV_TIERING.md)."""
+        if store is not None:
+            self._require_kv_layout("attach_kv_store")
         if store is not None and not self.prefix_caching:
             raise ValueError(
                 "kv_store needs enable_prefix_cache=True — pages are "
@@ -400,6 +420,7 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         (it is a constant of the engine config): restores sit on the
         TTFT-critical admission path, one tree-flatten per block would
         tax exactly what the tier speeds up."""
+        self._require_kv_layout("kv_page_meta")
         if self._kv_meta is None:
             leaves, _ = jax.tree.flatten(self.caches)
             self._kv_meta = ["kv1", self.bs, list(self.buckets),
@@ -1147,6 +1168,8 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
     alike.
     """
 
+    _KV_PROGRAMS = False
+
     def __init__(self, model, params, max_slots: int, max_len: int,
                  token_budget: Optional[int] = None, draft_model=None,
                  draft_params=None, draft_k: int = 4, **kw):
@@ -1188,6 +1211,11 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                     f"{type(draft_model).__name__} has no decode_ragged "
                     f"path — the ragged spec step ingests the pack into "
                     f"the draft pool through it")
+            if draft_model.cache_spec().layout != "kv":
+                raise NotImplementedError(
+                    f"the draft's proposal scan reads a K/V pool; "
+                    f"{type(draft_model).__name__} caches "
+                    f"{draft_model.cache_spec().layout!r} leaves")
             # the greedy speculative contract (models/_decode.py): the
             # acceptance rule compares ARGMAX predictions, so sampling
             # and the logits processors are out of scope — exactly the
@@ -1223,7 +1251,7 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             # tables and allocator: one allocation covers both models'
             # k/v for a position (the paged-spec composition's design,
             # now on the unified engine)
-            self.draft_caches = self._build_pool(dc)
+            self.draft_caches = self._build_pool(draft_model.cache_spec())
 
     @property
     def ragged_steps(self) -> int:
@@ -1496,17 +1524,24 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                 [len(self._slot_req[s].generated) if self._active[s] else 0
                  for s in range(self.S)], np.int32)
             run = self._ragged_prog(C)
-            ck, cv, ntok, self._presence = run(
-                self.params, self.caches[0], self.caches[1],
+            n = len(self.caches)
+            out = run(
+                self.params, self.caches,
                 jnp.asarray(toks), jnp.asarray(row_seq),
                 jnp.asarray(row_pos), jnp.asarray(self._table[:, :C]),
                 jnp.asarray(self._pad), jnp.asarray(sample_rows),
                 jnp.asarray(sample_active), jnp.asarray(emitted0),
                 self._next_key(), self._presence, self._plane_operands())
-            self.caches = (ck, cv)
+            self.caches, ntok, self._presence = out[:n], out[n], out[n + 1]
             self._stats.add("ragged_steps")
         with phase(PHASE_SYNC):
             ntok = np.asarray(ntok)
+            names = self.cache_spec.tick_stats
+            if names and self.tracer is not None:
+                # the model's own counters for this tick (one small
+                # vector), read with the tokens; never without a tracer
+                self._tick_note.update(
+                    zip(names, np.asarray(out[n + 2]).tolist()))
         with phase(PHASE_UNPACK):
             for slot in dec_slots:
                 self._t[slot] += 1
@@ -1573,15 +1608,18 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         rp, min_new, eos = self._sample_sig[4:]
         per_request = self.per_request
         row_sample = self._row_sample if per_request else None
+        with_stats = bool(self.cache_spec.tick_stats)
 
-        @partial(jax.jit, donate_argnums=(1, 2, 12))
-        def run(params, pool_ck, pool_cv, toks, row_seq, row_pos, table,
+        # the outputs are flat — the pools' entries, the tokens, presence,
+        # then the model's tick counters where its spec names any — so a
+        # two-entry model's program is the one it always was
+        @partial(jax.jit, donate_argnums=(1, 11))
+        def run(params, pools, toks, row_seq, row_pos, table,
                 pads, sample_rows, sample_active, emitted0, key, presence,
                 planes):
             h = model._embed_ragged(params, toks, row_seq, row_pos, pads)
-            h, (pool_ck, pool_cv) = model.decode_ragged(
-                params, h, (pool_ck, pool_cv), table, row_seq, row_pos,
-                pads)
+            h, pools, *stats = model.decode_ragged(
+                params, h, pools, table, row_seq, row_pos, pads)
             # ONE sampler over S gathered rows: each decode slot's row and
             # each completing prompt's last row (dummy row 0 for the rest
             # — computed, ignored host-side)
@@ -1606,7 +1644,8 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                     # tokens update presence in-program
                     presence = presence.at[jnp.arange(S), ntok].max(
                         sample_active)
-            return pool_ck, pool_cv, ntok, presence
+            return (*pools, ntok, presence,
+                    *(stats if with_stats else ()))
 
         return run
 
@@ -1625,16 +1664,17 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         n_spec = int(spec_active.sum())
         with phase(PHASE_DISPATCH):
             run = self._ragged_spec_prog(C)
-            ck, cv, dck, dcv, lead, block = run(
-                (self.params, self.draft_params), self.caches[0],
-                self.caches[1], self.draft_caches[0], self.draft_caches[1],
+            n = len(self.caches)
+            *pools, lead, block = run(
+                (self.params, self.draft_params), self.caches,
+                self.draft_caches,
                 jnp.asarray(toks), jnp.asarray(row_seq),
                 jnp.asarray(row_pos), jnp.asarray(self._table[:, :C]),
                 jnp.asarray(self._pad), jnp.asarray(sample_rows),
                 jnp.asarray(spec_row0), jnp.asarray(spec_active),
                 jnp.asarray(self._tok), jnp.asarray(self._t))
-            self.caches = (ck, cv)
-            self.draft_caches = (dck, dcv)
+            self.caches = tuple(pools[:n])
+            self.draft_caches = tuple(pools[n:])
             self._stats.add("ragged_steps")
             if n_spec:
                 self._stats.add("spec_rounds")
@@ -1706,11 +1746,12 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         K, S = self.K, self.S
         Ld = draft.config.num_layers
 
-        @partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-        def run(params_pair, pool_ck, pool_cv, dpool_ck, dpool_cv, toks,
+        @partial(jax.jit, donate_argnums=(1, 2))
+        def run(params_pair, pools, dpools, toks,
                 row_seq, row_pos, table, pads, sample_rows, spec_row0,
                 spec_active, dec_tok, dec_t):
             params, dparams = params_pair
+            dpool_ck, dpool_cv = dpools     # the draft's layout is "kv"
             # (1) draft proposal scan (S-wide; non-spec rows compute
             # garbage into the trash block via the gated table)
             tb = jnp.where(spec_active[:, None], table, 0)
@@ -1740,9 +1781,8 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             toks = toks.at[drows].set(d, mode="drop")
             # (3) one target pass over the whole mixed pack
             h = model._embed_ragged(params, toks, row_seq, row_pos, pads)
-            h, (pool_ck, pool_cv) = model.decode_ragged(
-                params, h, (pool_ck, pool_cv), table, row_seq, row_pos,
-                pads)
+            h, pools, *_ = model.decode_ragged(
+                params, h, pools, table, row_seq, row_pos, pads)
             # (4) the draft ingests the same pack (prompt currency +
             # d_{K-1} self-heal)
             hd = draft._embed_ragged(dparams, toks, row_seq, row_pos,
@@ -1760,7 +1800,7 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                 tpred = jnp.argmax(model.decode_logits(params, h_s),
                                    -1).astype(jnp.int32)   # (S, K+1)
                 lead, block = greedy_verify(d, tpred, active=spec_active)
-            return pool_ck, pool_cv, dpool_ck, dpool_cv, lead, block
+            return (*pools, dpool_ck, dpool_cv, lead, block)
 
         return run
 
@@ -1794,10 +1834,9 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         program: fresh pools (donated and freed), rows all parked on slot
         0 / the trash table — values are irrelevant, shapes and dtypes
         ARE the program signature (the purity test lowers through these)."""
-        ck, cv = self._alloc_caches()
         T, S = self.token_budget, self.S
         z = jnp.zeros(S, jnp.int32)
-        return (self.params, ck, cv, jnp.zeros(T, jnp.int32),
+        return (self.params, self._alloc_caches(), jnp.zeros(T, jnp.int32),
                 jnp.zeros(T, jnp.int32),
                 jnp.minimum(jnp.arange(T, dtype=jnp.int32),
                             C * self.bs - 1),
@@ -1813,11 +1852,10 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         """Scratch operands for one fused draft+verify program (fresh
         donated pools for BOTH models; rows parked on slot 0 / trash —
         shapes and dtypes ARE the signature, values are irrelevant)."""
-        ck, cv = self._alloc_caches()
-        dck, dcv = self._build_pool(self.draft_model.config)
         T, S = self.token_budget, self.S
         z = jnp.zeros(S, jnp.int32)
-        return ((self.params, self.draft_params), ck, cv, dck, dcv,
+        return ((self.params, self.draft_params), self._alloc_caches(),
+                self._build_pool(self.draft_model.cache_spec()),
                 jnp.zeros(T, jnp.int32), jnp.zeros(T, jnp.int32),
                 jnp.minimum(jnp.arange(T, dtype=jnp.int32),
                             C * self.bs - 1),

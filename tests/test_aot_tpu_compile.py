@@ -241,6 +241,37 @@ def test_ragged_serving_step_compiles(v5e):
     assert KERNEL in compiled.as_text()
 
 
+@pytest.mark.parametrize("cols", [1, 8, 256, 1040],
+                         ids=["C1", "C8", "C256", "C1040"])
+def test_ragged_latent_compiles(v5e, cols):
+    """The latent (MLA) kernel at the published widths — 128 heads, a
+    512 + 64 latent in a 640-wide row, 16-token blocks, 2,048 rows — over
+    a layer of a five-layer stack addressed in place, at table widths
+    from one block to the long-document cell's 1,040 (not a power of
+    two): up to 16 block specs of one pool in a grid step."""
+    from paddle_tpu.models._decode import ragged_latent_attention
+    T, nh, slots = 2048, 128, 16
+    pool = on_one(v5e, (5, slots * 1040 + 1, BLOCK, 640), jnp.bfloat16)
+    per_row = on_one(v5e, (T,), jnp.int32)
+
+    def attend(qa, qr, pool, table, seq, pos, pad, layer):
+        return ragged_latent_attention(qa, qr, pool, table, seq, pos, pad,
+                                       scale=192 ** -0.5, layer=layer)
+
+    compiled = compile_for(
+        attend, on_one(v5e, (T, nh, 512), jnp.bfloat16),
+        on_one(v5e, (T, nh, 64), jnp.bfloat16), pool,
+        on_one(v5e, (slots, cols), jnp.int32), per_row, per_row,
+        on_one(v5e, (slots,), jnp.int32), on_one(v5e, (), jnp.int32))
+    text = compiled.as_text()
+    assert KERNEL in text
+    assert kernel_op_names(text)[0].endswith(
+        "ragged_latent_attention/ragged_latent_attention/pallas_call") \
+        or "ragged_latent_attention" in kernel_op_names(text)[0]
+    # the pool reaches the kernel as it is stored: no copy of it is made
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 def kernel_op_names(text):
     """The ``op_name`` of every Pallas kernel call in compiled HLO text."""
     import re

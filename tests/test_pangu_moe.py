@@ -1,0 +1,386 @@
+"""openPangu-Ultra-MoE through the program: the model, the latent kernel,
+the held-experts layer and the engine's cache spec, each against the plain
+reference (``benchmarks/lib/reference_pangu_moe.py``) at widths a CPU
+holds.  Seeded weights, float32 and bfloat16."""
+
+import hashlib
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu.models._decode import CacheLeaf
+from paddle_tpu.models.gpt import GPTConfig, GPTModel
+from paddle_tpu.models.pangu_moe import (TICK_STATS, PanguMoeConfig,
+                                         PanguMoeModel)
+from paddle_tpu.ops import moe
+from paddle_tpu.ops.ragged_latent_attention import (
+    ragged_latent_attention_ref, ragged_latent_attention_rows)
+from paddle_tpu.serving import RaggedPagedContinuousBatchingEngine
+from paddle_tpu.telemetry import Tracer
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from benchmarks.lib import reference_pangu_moe as ref  # noqa: E402
+from benchmarks.lib import weights_pangu  # noqa: E402
+
+# the configuration file's keys at a small size: 16 experts routed, top-3,
+# of which this share holds 4 (ids 4-7); 1 dense + 2 expert layers
+CFG = dict(vocab_size=96, hidden_size=32, num_hidden_layers=3,
+           first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=16,
+           kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+           v_head_dim=8, intermediate_size=48, moe_intermediate_size=12,
+           n_routed_experts=4, router_width=16, experts_held=[4, 8],
+           n_shared_experts=1, num_experts_per_tok=3,
+           routed_scaling_factor=2.5, norm_topk_prob=True, rms_norm_eps=1e-5,
+           rope_theta=25600000, max_position_embeddings=128,
+           initializer_range=0.2)
+TOL = {"float32": 2e-5, "bfloat16": 0.25}
+
+
+def build(dtype, seed=7, cfg=CFG):
+    paddle.seed(0)
+    first, stop = cfg["experts_held"]
+    skip = ("router_width", "experts_held", "n_routed_experts")
+    model = PanguMoeModel(PanguMoeConfig(
+        **{k: v for k, v in cfg.items() if k not in skip},
+        n_routed_experts=cfg["router_width"],
+        experts_held=range(first, stop), compute_dtype=dtype))
+    params = weights_pangu.make_params(cfg, seed, dtype)
+    table = PanguMoeModel.param_table(model.config)
+    assert {n: v.shape for n, v in params.items()} \
+        == {n: shape for n, (shape, _) in table.items()}
+    return model, params
+
+
+@pytest.fixture
+def interpret(request):
+    paddle.set_flags({"FLAGS_paged_attn_interpret": request.param})
+    yield request.param
+    paddle.set_flags({"FLAGS_paged_attn_interpret": False})
+
+
+def engine(model, params, **kw):
+    return RaggedPagedContinuousBatchingEngine(
+        model, params, max_slots=3, max_len=64, block_size=8, num_blocks=20,
+        token_budget=16, prompt_buckets=list(range(8, 65, 8)), **kw)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_forward_matches_the_reference(dtype):
+    model, params = build(dtype)
+    ids = np.random.default_rng(1).integers(1, 96, (2, 32))
+    h, _ = model.prefill(params, jnp.asarray(ids), 32)
+    got = model.decode_logits(params, h)
+    for b in range(2):
+        want, _ = ref.logits(CFG, params, jnp.asarray(ids[b]), block=16,
+                             head_group=2)
+        assert float(jnp.abs(got[b] - want).max()) < TOL[dtype]
+        assert float(want.std()) > 0.5      # the comparison is not of zeros
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("interpret", [True, False], indirect=True,
+                         ids=["kernel", "xla"])
+def test_engine_prefill_then_decode_matches_the_reference(dtype, interpret):
+    """Served tokens through the paged latent cache (chunked prefill,
+    mixed ticks, left-padded buckets) against the reference's full forward
+    over prompt + served tokens; and the tick counters on the event."""
+    model, params = build(dtype)
+    tracer = Tracer()
+    eng = engine(model, params, tracer=tracer)
+    ids = np.random.default_rng(2).integers(1, 96, 40)
+    prompts = [ids[:21].tolist(), ids[5:18].tolist(), ids[3:32].tolist(),
+               ids[:9].tolist()]
+    served = {}
+    rids = [eng.add_request(p, 6, on_token=lambda rid, t, d:
+                            served.setdefault(rid, []).append(int(t)))
+            for p in prompts]
+    eng.run_to_completion()
+    for rid, p in zip(rids, prompts):
+        full = p + served[rid][:-1]
+        L = -(-len(full) // 16) * 16
+        logits, _ = ref.logits(CFG, params, jnp.asarray(
+            full + [0] * (L - len(full))), block=16, head_group=2)
+        rows = logits[len(p) - 1:len(full)]
+        gap = rows.max(-1) - jnp.take_along_axis(
+            rows, jnp.asarray(served[rid])[:, None], -1)[:, 0]
+        assert float(gap.max()) < TOL[dtype], (rid, gap)
+    ticks = [e for e in tracer.events("tick") if e.get("budget_used")]
+    assert ticks and all(set(TICK_STATS) <= set(e) for e in ticks)
+    layers = model.config.num_expert_layers
+    for e in ticks:
+        assert e["expert_pairs"] == e["budget_used"] * 3 * layers
+        assert 0 <= e["expert_rows_max"] <= e["expert_rows"] \
+            <= e["expert_pairs"]
+    assert sum(e["expert_rows"] for e in ticks) > 0
+    # what is static is said once, when the engine is built
+    (cache,) = tracer.events("cache")
+    assert cache["layout"] == "latent" and cache["pool_bytes"] == \
+        3 * 21 * 8 * 128 * jnp.dtype(dtype).itemsize
+
+
+def test_generate_agrees_with_the_engine():
+    model, params = build("float32")
+    prompt = np.random.default_rng(3).integers(1, 96, 19).tolist()
+    eng = engine(model, params)
+    rid = eng.add_request(prompt, 5)
+    want = eng.run_to_completion()[rid]
+    got = model.generate(params, jnp.asarray([prompt]), 5)[0].tolist()
+    assert got == list(want)
+
+
+@pytest.mark.parametrize("real", [5, 8, 11], ids=["few", "edge", "over"])
+def test_a_pack_of_few_rows_runs_the_same_blocks_over_those_rows(real):
+    """A program over twice as wide as the engine has slots runs its
+    row-wise work over the first ``slots`` rows alone when no real row
+    lies behind them: the real rows' hidden states, the pools and the
+    counters are those of the same pack with as many idle slots as make
+    the program too narrow to have the branch."""
+    model, params = build("float32")
+    C, bs, T = 16, 8, 24
+    from paddle_tpu.models._decode import build_pools
+    blocks = np.random.default_rng(8).permutation(3 * C).reshape(3, C) + 1
+
+    def run(slots):
+        rng = np.random.default_rng(8)
+        table = np.zeros((slots, C), np.int32)
+        table[:3] = blocks
+        pads = np.zeros(slots, np.int32)
+        pads[1] = 2
+        seq = np.full(T, -1, np.int32)
+        pos = np.full(T, -1, np.int32)
+        seq[:real] = np.arange(real) % 3
+        # each sequence's rows at consecutive positions, as a chunk has them
+        for q in range(3):
+            mine = np.flatnonzero(seq[:real] == q)
+            pos[mine] = 3 + np.arange(len(mine))
+        toks = jnp.asarray(rng.integers(1, 96, T) * (pos >= 0), jnp.int32)
+        h = model._embed_ragged(params, toks, None, None, None)
+        return model.decode_ragged(
+            params, h, build_pools(model.cache_spec(), (3 * C + 1, bs)),
+            jnp.asarray(table), jnp.asarray(seq), jnp.asarray(pos),
+            jnp.asarray(pads))
+
+    h_big, pools_big, stats_big = run(8)            # 24 > 2 x 8: the branch
+    h_ref, pools_ref, stats_ref = run(12)           # 24 = 2 x 12: none
+    assert float(jnp.abs(h_big[0, :real] - h_ref[0, :real]).max()) < 1e-5
+    for a, b in zip(pools_big, pools_ref):
+        assert float(jnp.abs(a[:, 1:] - b[:, 1:]).max()) < 1e-5
+    assert stats_big.tolist() == stats_ref.tolist()
+
+
+def pack(rng, dtype, nh=4, R=32, Dr=8, NB=20, bs=4, S=3, C=8, W=None):
+    """A mixed pack: a prefill chunk of 10 rows, one decode row deep in
+    its sequence, a 9-row chunk from position 0 behind a left pad, and 4
+    padding rows; the trash block holds garbage that must not be read."""
+    T = 24
+    qa = jnp.asarray(rng.normal(size=(T, nh, R)), dtype)
+    qr = jnp.asarray(rng.normal(size=(T, nh, Dr)), dtype)
+    pool = jnp.asarray(rng.normal(size=(NB + 1, bs, W or R + Dr)), dtype)
+    pool = pool.at[0].set(1e4)
+    table = jnp.asarray(rng.integers(1, NB + 1, (S, C)), jnp.int32)
+    row_seq = jnp.asarray([0] * 10 + [1] + [2] * 9 + [0] * 4, jnp.int32)
+    row_pos = jnp.asarray(list(range(5, 15)) + [30] + list(range(3, 12))
+                          + [-1] * 4, jnp.int32)
+    pads = jnp.asarray([2, 0, 3], jnp.int32)
+    return qa, qr, pool, table, row_seq, row_pos, pads
+
+
+@pytest.mark.parametrize("dtype,tol", [("float32", 2e-5), ("bfloat16", 3e-2)])
+@pytest.mark.parametrize("blocks_per_step", [1, 2, 4, 16])
+@pytest.mark.parametrize("padded", [False, True], ids=["exact", "padded-row"])
+def test_latent_kernel_matches_its_reference(dtype, tol, blocks_per_step,
+                                             padded):
+    rng = np.random.default_rng(0)
+    args = pack(rng, jnp.dtype(dtype), W=64 if padded else None)
+    got = ragged_latent_attention_rows(
+        *args, scale=0.3, interpret=True, blocks_per_step=blocks_per_step)
+    want = ragged_latent_attention_ref(*args, scale=0.3)
+    assert float(jnp.abs(got.astype(jnp.float32)
+                         - want.astype(jnp.float32)).max()) < tol
+    assert bool(jnp.all(got[-4:] == 0)) and bool(jnp.all(want[-4:] == 0))
+    assert bool(jnp.all(jnp.isfinite(got.astype(jnp.float32))))
+
+
+def test_latent_kernel_reads_a_layer_of_a_stack_in_place():
+    rng = np.random.default_rng(1)
+    qa, qr, pool, *rest = pack(rng, jnp.float32)
+    stack = jnp.stack([pool * 0 + 7.0, pool, pool * 0 - 3.0])
+    want = ragged_latent_attention_ref(qa, qr, pool, *rest, scale=0.3)
+    for fn, kw in ((ragged_latent_attention_rows, {"interpret": True}),
+                   (ragged_latent_attention_ref, {})):
+        got = jax.jit(lambda ly: fn(qa, qr, stack, *rest, scale=0.3,
+                                    layer=ly, **kw))(jnp.int32(1))
+        assert float(jnp.abs(got - want).max()) < 2e-5
+
+
+def expert_weights(rng, E=16, H=8, F=6):
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    return f(H, E), f(E, H, F), f(E, H, F), f(E, F, H)
+
+
+@pytest.mark.parametrize("held", [1, 2, 4, 16])
+def test_the_shares_add_up_to_the_uncut_layer(held):
+    """The 16 / held shares of one expert layer — each routing over all
+    16 experts and computing its own — and the shared expert counted once
+    add up to the uncut layer, which the reference computes with every
+    expert held."""
+    rng = np.random.default_rng(4)
+    T, H, F, E, k = 12, 8, 6, 16, 4
+    gate, w_g, w_u, w_d = expert_weights(rng, E, H, F)
+    s_g, s_u = (jnp.asarray(rng.normal(size=(H, F)), jnp.float32)
+                for _ in range(2))
+    s_d = jnp.asarray(rng.normal(size=(F, H)), jnp.float32)
+    m = jnp.asarray(rng.normal(size=(T, H)), jnp.float32)
+    idx, w = moe.route_sigmoid_topk(m, gate, k, 2.5)
+    total = moe.gated_mlp(m, s_g, s_u, s_d)
+    rows = 0
+    for first in range(0, E, held):
+        sl = slice(first, first + held)
+        part, n = moe.held_experts_ffn(m, idx, w, w_g[sl], w_u[sl], w_d[sl],
+                                       first)
+        total, rows = total + part, rows + int(n.sum())
+    assert rows == T * k                       # every pair, exactly once
+    cfg = dict(num_experts_per_tok=k, experts_held=[0, E],
+               n_routed_experts=E, routed_scaling_factor=2.5)
+    want, _ = ref._experts(cfg, dict(
+        router_w=gate, e_gate_w=w_g, e_up_w=w_u, e_down_w=w_d, s_gate_w=s_g,
+        s_up_w=s_u, s_down_w=s_d), m, None)
+    assert float(jnp.abs(total - want).max()) < 1e-3 * float(
+        jnp.abs(want).max())
+
+
+@pytest.mark.parametrize("case", ["all-to-one-held", "none-held",
+                                  "all-held-experts-full", "padding-rows"])
+def test_no_pair_is_dropped_under_the_worst_imbalance(case):
+    rng = np.random.default_rng(5)
+    T, H, F, k, Eh, first = 10, 8, 6, 4, 3, 5
+    _, w_g, w_u, w_d = expert_weights(rng, Eh, H, F)
+    m = jnp.asarray(rng.normal(size=(T, H)), jnp.float32)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, (T, k)), jnp.float32)
+    valid = None
+    if case == "all-to-one-held":       # every row: expert 6 and 3 unheld
+        idx = np.tile([6, 0, 1, 2], (T, 1))
+    elif case == "none-held":
+        idx = np.tile([0, 1, 2, 3], (T, 1))
+    elif case == "all-held-experts-full":   # every row: all 3 held + 1
+        idx = np.tile([5, 6, 7, 9], (T, 1))
+    else:
+        idx = np.tile([6, 5, 1, 2], (T, 1))
+        valid = jnp.arange(T) < 4
+    idx = jnp.asarray(idx, jnp.int32)
+    got, rows = moe.held_experts_ffn(m, idx, w, w_g, w_u, w_d, first, valid)
+    want = np.zeros((T, H), np.float32)
+    count = np.zeros(Eh, np.int32)
+    for t in range(T if valid is None else 4):
+        for j in range(k):
+            e = int(idx[t, j]) - first
+            if 0 <= e < Eh:
+                y = (jax.nn.silu(m[t] @ w_g[e]) * (m[t] @ w_u[e])) @ w_d[e]
+                want[t] += float(w[t, j]) * np.asarray(y)
+                count[e] += 1
+    assert rows.tolist() == count.tolist()
+    assert float(np.abs(np.asarray(got) - want).max()) < 1e-4 * max(
+        1.0, float(np.abs(want).max()))
+
+
+def test_cache_spec_states_what_each_model_caches():
+    model, _ = build("bfloat16")
+    spec = model.cache_spec()
+    assert spec.layout == "latent" and spec.tick_stats == TICK_STATS
+    assert spec.pools == (CacheLeaf(1, (128,), "bfloat16"),
+                          CacheLeaf(2, (128,), "bfloat16"))
+    gpt = GPTModel(GPTConfig(vocab_size=97, hidden_size=32, num_layers=2,
+                             num_attention_heads=4,
+                             max_position_embeddings=96))
+    kv = gpt.cache_spec()
+    assert kv.layout == "kv" and kv.tick_stats == ()
+    assert kv.pools == (CacheLeaf(2, (4, 8), "bfloat16"),) * 2
+
+
+@pytest.mark.parametrize("how", ["attach", "constructor", "page_meta"])
+def test_the_kv_store_refuses_a_latent_cache(how):
+    from paddle_tpu.kv_store import TieredKVStore
+    model, params = build("float32")
+    with pytest.raises(NotImplementedError, match="K/V cache layout"):
+        if how == "constructor":
+            engine(model, params, enable_prefix_cache=True,
+                   kv_store=TieredKVStore())
+        eng = engine(model, params, enable_prefix_cache=True)
+        if how == "attach":
+            eng.attach_kv_store(TieredKVStore())
+        eng.kv_page_meta()
+
+
+def test_the_bucketed_paged_engine_refuses_a_latent_cache():
+    """Its prefill and decode programs read a K and a V pool: refused when
+    the engine is built, by name, not inside the first prefill."""
+    from paddle_tpu.serving_paged import PagedContinuousBatchingEngine
+    model, params = build("float32")
+    with pytest.raises(NotImplementedError,
+                       match="PagedContinuousBatchingEngine is written for "
+                             "the K/V cache layout; PanguMoeModel"):
+        PagedContinuousBatchingEngine(model, params, max_slots=3, max_len=64,
+                                      block_size=8, num_blocks=20)
+
+
+def test_prefix_lookup_works_on_a_latent_cache():
+    """Keyed on token ids, not on leaves: a second request with the same
+    prompt reuses the first one's blocks and serves the same tokens."""
+    model, params = build("float32")
+    eng = engine(model, params, enable_prefix_cache=True)
+    prompt = np.random.default_rng(6).integers(1, 96, 24).tolist()
+    a = eng.add_request(prompt, 4)
+    first = eng.run_to_completion()[a]
+    b = eng.add_request(prompt, 4)
+    assert eng.run_to_completion()[b] == first and eng.prefix_hits >= 1
+
+
+# sha256 of the GPT ragged tick's lowering at the parent commit (fdbe221),
+# at this size and under this suite's settings (conftest.py: matmul
+# precision "highest"): the pool generalisation must not move a byte of it.
+# Made by lowering the same five programs from a checkout of the parent.
+PARENT_TICK = {
+    "float": "9510c232e8a3bfbb4a67a8bb679631b27b173f20a9d269b8f272002bfae2572b",
+    "int8": "5f51d3e4b68c7e5e28e591de4c8b5505071c8f9dd24a5dc01518e43f35f5ffee",
+    "kernel": "b0bb031528a5941100e4a354ae33d424845f0150edbf92e042ebb89b57235d30",
+    "spec": "ccc72f4057c9ab7d9bbebeed5e526a6b1c16062d7279141182ff3f8215dcc712",
+    "per-request": "77bf30637f6d2458d3bd1e11ae54d40118728f286683e29317d706d3c935d372",
+}
+
+
+@pytest.mark.parametrize("case", ["float", "int8", "kernel", "spec",
+                                  "per-request"])
+def test_the_gpt_tick_lowers_as_at_the_parent(case):
+    paddle.seed(3)
+    mk = lambda layers: GPTModel(GPTConfig(
+        vocab_size=97, hidden_size=32, num_layers=layers,
+        num_attention_heads=4, max_position_embeddings=96,
+        compute_dtype="float32",
+        kv_cache_dtype="int8" if case == "int8" else None))
+    params_of = lambda m: {n: p._data for n, p in m.named_parameters()}
+    model = mk(2)
+    kw = {}
+    if case == "spec":
+        draft = mk(1)
+        kw = dict(draft_model=draft, draft_params=params_of(draft), draft_k=2)
+    if case == "per-request":
+        kw = dict(per_request_sampling=True)
+    paddle.set_flags({"FLAGS_paged_attn_interpret": case == "kernel"})
+    try:
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params_of(model), max_slots=3, max_len=64, block_size=8,
+            num_blocks=12, token_budget=16, **kw)
+        if case == "spec":
+            text = eng._build_ragged_spec_step(16, 4).lower(
+                *eng._ragged_spec_scratch_args(4)).as_text()
+        else:
+            text = eng._build_ragged_step(16, 4).lower(
+                *eng._ragged_scratch_args(4)).as_text()
+    finally:
+        paddle.set_flags({"FLAGS_paged_attn_interpret": False})
+    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_TICK[case]
