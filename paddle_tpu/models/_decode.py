@@ -177,10 +177,11 @@ class PagedKV:
 
 
 def ragged_attention(q_rows, pool_k, pool_v, table, row_seq, row_pos,
-                     pad_lens=None):
+                     pad_lens=None, layer=None):
     """Attention for a flattened ragged pack of rows over ONE layer's block
     pools (the mixed prefill+decode serving step): q_rows (T, nh, hd),
     pools (NB+1, bs, nh, hd) — int8 ``(values, scales)`` pairs included —
+    or a stack's pools (L, NB+1, bs, nh, hd) and ``layer``, read in place;
     table (S, C), row_seq/row_pos (T,) per-row metadata (see
     ops/ragged_paged_attention.ragged_rows), pad_lens (S,).
 
@@ -194,9 +195,9 @@ def ragged_attention(q_rows, pool_k, pool_v, table, row_seq, row_pos,
     if use:
         return ragged_attention_rows(q_rows, pool_k, pool_v, table,
                                      row_seq, row_pos, pad_lens,
-                                     interpret=interp)
+                                     layer=layer, interpret=interp)
     return ragged_attention_ref(q_rows, pool_k, pool_v, table, row_seq,
-                                row_pos, pad_lens)
+                                row_pos, pad_lens, layer=layer)
 
 
 def _pallas_dispatch():
@@ -235,14 +236,14 @@ def ragged_write(pool, chunk, table, row_seq, row_pos, layer=None):
     pool at each row's (table-mapped block, offset); padding rows
     (row_pos < 0) land in the trash block.  int8 pools quantize the chunk
     and write both planes (quantize_kv layout).  With ``layer`` the pool
-    is a whole stack's (L, NB+1, bs, ...) and the rows land in that
-    layer's blocks, in place."""
+    is a whole stack's (L, NB+1, bs, ...) — each plane of an int8 pair
+    too — and the rows land in that layer's blocks, in place."""
     if isinstance(pool, tuple):
         vals, scales = pool
         with jax.named_scope("kv_write"):
             q, s = quantize_kv(chunk)
-        return (ragged_write(vals, q, table, row_seq, row_pos),
-                ragged_write(scales, s, table, row_seq, row_pos))
+        return (ragged_write(vals, q, table, row_seq, row_pos, layer),
+                ragged_write(scales, s, table, row_seq, row_pos, layer))
     with jax.named_scope("kv_write"):
         bs = pool.shape[1 if layer is None else 2]
         seq = jnp.clip(row_seq, 0, table.shape[0] - 1)
@@ -523,6 +524,8 @@ class CausalDecoderMixin:
     max_position_embeddings, num_layers, num_attention_heads, hidden_size),
     ``prefill(params, ids, max_len) -> (h, caches)``,
     ``decode_step(params, h, caches, t) -> (h, caches)``,
+    ``_block_decode_ragged(sl, h, pool_k, pool_v, table, row_seq, row_pos,
+    pad_lens, layer) -> (h, pool_k, pool_v)`` (for ``decode_ragged``),
     ``decode_logits(params, h) -> fp32 (B, 1, V)``, and wte/wpe param keys.
     """
 
@@ -759,6 +762,39 @@ class CausalDecoderMixin:
                            params["wpe"].shape[0] - 1)
             h = jnp.take(params["wte"], toks, axis=0) + params["wpe"][pos]
             return h[None].astype(dt)
+
+    def decode_ragged(self, params, h, pools, table, row_seq, row_pos,
+                      pad_lens):
+        """All blocks for one mixed ragged step (the serving engine's
+        fused prefill+decode tick), for the "kv" cache layout: h (1, T, H)
+        from _embed_ragged, ``pools`` = (pool_ck, pool_cv) stacked over
+        layers (int8 ``(values, scales)`` pairs included), table (S, C)
+        shared across layers, row metadata per
+        ops/ragged_paged_attention.ragged_rows.  Returns (h_out, pools).
+
+        The scan carries both pools whole and hands the host class's
+        ``_block_decode_ragged`` the layer's index: the block writes and
+        attends in that layer's blocks in place.  (As ``xs``/``ys`` of the
+        scan, every layer's pool is sliced out of one stack and written
+        into a second one each tick, and the program holds the pool twice.)
+
+        Speculative VERIFY chunks are just another ragged row group: a
+        slot's [prev, d_0..d_{K-1}] rows at kv positions [t, t+K] ride
+        the same write-then-attend order (each draft row attends its
+        predecessors' freshly written k/v), so the ragged spec engine
+        needs no separate verify program — the pack IS the verify."""
+        stacked = {k: params[k] for k in self.stacked_param_names()}
+
+        def body(carry, xs):
+            sl, i = xs
+            return self._block_decode_ragged(
+                sl, *carry, table, row_seq, row_pos, pad_lens, layer=i), None
+
+        with jax.named_scope("layers"):
+            (h, *pools), _ = jax.lax.scan(
+                body, (h, *pools),
+                (stacked, jnp.arange(self.config.num_layers)))
+        return h, tuple(pools)
 
     def generate_speculative(self, params, input_ids, max_new_tokens: int,
                              draft_model, draft_params, draft_k: int = 4,

@@ -292,37 +292,22 @@ class ErnieMoeModel(CausalDecoderMixin, Layer):
         return h, (jnp.pad(ks.astype(cdt), pad), jnp.pad(vs.astype(cdt), pad))
 
     def _block_decode_ragged(self, sl, h, pck, pcv, table, row_seq,
-                             row_pos, pad_lens):
+                             row_pos, pad_lens, layer=None):
         """One block for a flattened ragged pack (the mixed serving step;
         see GPTModel._block_decode_ragged): scatter each row's k/v to its
-        table-mapped pool position BEFORE attention, then the gather-
-        dispatch MoE FFN — the no-drop decode hot path."""
+        table-mapped pool position (in layer ``layer`` of the stack's
+        pools) BEFORE attention, then the gather-dispatch MoE FFN — the
+        no-drop decode hot path.  CausalDecoderMixin.decode_ragged scans
+        it over the layers, so MoE targets ride the ragged engine
+        (speculative verification included) as GPT does."""
         from ._decode import ragged_attention, ragged_write
         q, k, v = self._block_qkv(sl, h)               # (1, T, nh, hd)
-        pck = ragged_write(pck, k[0], table, row_seq, row_pos)
-        pcv = ragged_write(pcv, v[0], table, row_seq, row_pos)
+        pck = ragged_write(pck, k[0], table, row_seq, row_pos, layer)
+        pcv = ragged_write(pcv, v[0], table, row_seq, row_pos, layer)
         att = ragged_attention(q[0], pck, pcv, table, row_seq, row_pos,
-                               pad_lens)
+                               pad_lens, layer)
         h = self._attn_residual(sl, h, att[None])
         return self._moe_residual_gather(sl, h), pck, pcv
-
-    def decode_ragged(self, params, h, pools, table, row_seq, row_pos,
-                      pad_lens):
-        """All blocks for one mixed ragged step (the ragged serving
-        engine's fused prefill+decode+verify tick) — the MoE counterpart
-        of GPTModel.decode_ragged, so MoE targets ride the ragged engine
-        (speculative verification included) through the same mixin
-        contract."""
-        stacked = {k: params[k] for k in self.stacked_param_names()}
-
-        def body(carry, xs):
-            sl, pck, pcv = xs
-            out, pck, pcv = self._block_decode_ragged(
-                sl, carry, pck, pcv, table, row_seq, row_pos, pad_lens)
-            return out, (pck, pcv)
-
-        h, (cks, cvs) = jax.lax.scan(body, h, (stacked, pools[0], pools[1]))
-        return h, (cks, cvs)
 
     def decode_step(self, params, h, caches, t, pad_lens=None):
         stacked = {k: params[k] for k in self.stacked_param_names()}

@@ -382,47 +382,22 @@ class GPTModel(CausalDecoderMixin, Layer):
         return h, (jnp.pad(ks.astype(dt), pad), jnp.pad(vs.astype(dt), pad))
 
     def _block_decode_ragged(self, sl, h, pck, pcv, table, row_seq,
-                             row_pos, pad_lens):
+                             row_pos, pad_lens, layer=None):
         """One block for a flattened ragged pack: h (1, T, H); pck/pcv are
-        this layer's block pools (NB+1, bs, nh, hd).  Each row's k/v is
+        the whole stack's block pools (L, NB+1, bs, nh, hd) of which this
+        block is layer ``layer`` (CausalDecoderMixin.decode_ragged), or
+        one layer's own without it.  Each row's k/v is
         scattered to its table-mapped pool position BEFORE attention, so
         intra-pack causal attention (a prefill chunk's rows attending each
         other) reads the freshly written keys — the _block_decode
         write-then-attend order over the ragged layout."""
         with jax.named_scope("attn"):
             q, k, v = self._block_qkv(sl, h)           # (1, T, nh, hd)
-            pck = ragged_write(pck, k[0], table, row_seq, row_pos)
-            pcv = ragged_write(pcv, v[0], table, row_seq, row_pos)
+            pck = ragged_write(pck, k[0], table, row_seq, row_pos, layer)
+            pcv = ragged_write(pcv, v[0], table, row_seq, row_pos, layer)
             att = ragged_attention(q[0], pck, pcv, table, row_seq, row_pos,
-                                   pad_lens)
+                                   pad_lens, layer)
         return self._block_post_attn(sl, h, att[None]), pck, pcv
-
-    def decode_ragged(self, params, h, pools, table, row_seq, row_pos,
-                      pad_lens):
-        """All blocks for one mixed ragged step (the serving engine's
-        fused prefill+decode tick): h (1, T, H) from _embed_ragged,
-        ``pools`` = (pool_ck, pool_cv) stacked over layers (int8
-        ``(values, scales)`` pairs included), table (S, C) shared across
-        layers, row metadata per ops/ragged_paged_attention.ragged_rows.
-        Returns (h_out, pools).
-
-        Speculative VERIFY chunks are just another ragged row group: a
-        slot's [prev, d_0..d_{K-1}] rows at kv positions [t, t+K] ride
-        the same write-then-attend order (each draft row attends its
-        predecessors' freshly written k/v), so the ragged spec engine
-        needs no separate verify program — the pack IS the verify."""
-        stacked = {k: params[k] for k in self.stacked_param_names()}
-
-        def body(carry, xs):
-            sl, pck, pcv = xs
-            out, pck, pcv = self._block_decode_ragged(
-                sl, carry, pck, pcv, table, row_seq, row_pos, pad_lens)
-            return out, (pck, pcv)
-
-        with jax.named_scope("layers"):
-            h, (cks, cvs) = jax.lax.scan(body, h,
-                                         (stacked, pools[0], pools[1]))
-        return h, (cks, cvs)
 
     def decode_step(self, params, h, caches, t, pad_lens=None):
         """All blocks for one token: h (B,1,H), caches = (ck, cv) stacked

@@ -128,11 +128,14 @@ def _ragged_kernel(table_ref, seq_ref, pos_ref, pad_ref, q_ref, *rest,
 
 
 def ragged_attention_rows(q, pool_k, pool_v, table, row_seq, row_pos,
-                          pad_lens=None, *, interpret=False):
+                          pad_lens=None, *, layer=None, interpret=False):
     """Row-metadata entry point (the engine packs rows directly).
 
     q (T, nh, hd); pool_k/pool_v (NB+1, bs, nh, hd) — or int8
-    ``(values, scales)`` pairs with scales (NB+1, bs, nh); table (S, C)
+    ``(values, scales)`` pairs with scales (NB+1, bs, nh) — or, with
+    ``layer`` (a traced int32 scalar), the pools of a whole stack
+    (L, NB+1, bs, nh[, hd]) of which the kernel reads that layer's blocks
+    in place (no slice of the stack is ever made); table (S, C)
     int32 (block 0 = trash); row_seq (T,) int32 in [0, S); row_pos (T,)
     int32 kv position per row, -1 for padding rows; pad_lens (S,) int32
     left-pad masks (positions < pad masked), or None.
@@ -146,6 +149,16 @@ def ragged_attention_rows(q, pool_k, pool_v, table, row_seq, row_pos,
 
     T, nh, hd = q.shape
     quantized = isinstance(pool_k, tuple)
+    if layer is not None:
+        # a stack's blocks numbered through: block b of layer i is block
+        # i * (NB + 1) + b of the (L * (NB + 1), bs, ...) view (leading
+        # dims merged: nothing moves), and the table is offset to match.
+        # The kernel and its index maps need not know the layer; padding
+        # rows read layer 0's trash block
+        n_blocks = jax.tree.leaves(pool_k)[0].shape[1]
+        pool_k, pool_v = jax.tree.map(
+            lambda p: p.reshape((-1,) + p.shape[2:]), (pool_k, pool_v))
+        table = table + jnp.asarray(layer, jnp.int32) * n_blocks
     vals_k = pool_k[0] if quantized else pool_k
     NB1, bs = vals_k.shape[:2]
     S, C = table.shape
@@ -205,14 +218,19 @@ def ragged_attention_rows(q, pool_k, pool_v, table, row_seq, row_pos,
 
 
 def ragged_attention_ref(q, pool_k, pool_v, table, row_seq, row_pos,
-                         pad_lens=None):
+                         pad_lens=None, *, layer=None):
     """XLA fallback/oracle: densify each row's table-selected blocks and
     reuse cached_attention's kq=1 per-row form — EXACTLY the numerics of
     the paged engine's gather path, so kernel parity tests pin against
     the same oracle the serving engine is locked to.  int8 pools
-    dequantize after the gather (only selected blocks pay the convert)."""
+    dequantize after the gather (only selected blocks pay the convert).
+    With ``layer`` the pools are a whole stack's, indexed here."""
     from ..models._decode import cached_attention, dequantize_cache
 
+    if layer is not None:
+        pool_k, pool_v = jax.tree.map(
+            lambda p: lax.dynamic_index_in_dim(p, layer, 0, keepdims=False),
+            (pool_k, pool_v))
     S, C = table.shape
     if pad_lens is None:
         pad_lens = jnp.zeros((S,), jnp.int32)
@@ -231,14 +249,16 @@ def ragged_attention_ref(q, pool_k, pool_v, table, row_seq, row_pos,
 
 
 def ragged_paged_attention(q, pool_k, pool_v, table, cu_q_lens, kv_lens,
-                           pad_lens=None, *, interpret=False):
+                           pad_lens=None, *, layer=None, interpret=False):
     """Ragged paged attention over per-SEQUENCE metadata (the PAPERS.md
     kernel interface): q (T, nh, hd) flattened mixed batch, cu_q_lens
     (S+1,) cumulative query lengths, kv_lens (S,) post-write kv extents,
     ``table`` (S, C) block tables into the (NB+1, bs, nh, hd) pools
     (int8 ``(values, scales)`` pairs supported — dequant fused into the
-    in-kernel gather).  Rows past cu_q_lens[S] are padding.  See
-    ragged_attention_rows for the row-level contract."""
+    in-kernel gather), or a whole stack's pools and ``layer``.  Rows past
+    cu_q_lens[S] are padding.  See ragged_attention_rows for the row-level
+    contract."""
     row_seq, row_pos = ragged_rows(cu_q_lens, kv_lens, q.shape[0])
     return ragged_attention_rows(q, pool_k, pool_v, table, row_seq,
-                                 row_pos, pad_lens, interpret=interpret)
+                                 row_pos, pad_lens, layer=layer,
+                                 interpret=interpret)

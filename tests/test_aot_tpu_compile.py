@@ -206,32 +206,54 @@ def test_paged_decode_compiles(v5e, hd):
     assert KERNEL in compiled.as_text()
 
 
+@pytest.mark.parametrize("layers", [None, 24], ids=["one-layer", "stack"])
 @pytest.mark.parametrize("hd,int8", [(64, False), (64, True),
                                      (128, False), (128, True)],
                          ids=["hd64-bf16", "hd64-int8",
                               "hd128-bf16", "hd128-int8"])
-def test_ragged_paged_compiles(v5e, hd, int8):
+def test_ragged_paged_compiles(v5e, hd, int8, layers):
+    """One layer's pools, or a stack of 24 addressed by a traced layer
+    index: the pools reach the kernel as they are stored, no layer of
+    them is sliced out first."""
     from paddle_tpu.models._decode import ragged_attention
     nh, pool, table, per_slot = pool_args(v5e, hd, int8)
     q = on_one(v5e, (BUDGET, nh, hd), jnp.bfloat16)
     per_row = on_one(v5e, (BUDGET,), jnp.int32)
+    layer = ()
+    if layers:
+        pool = jax.tree.map(
+            lambda p: on_one(v5e, (layers,) + p.shape, p.dtype), pool)
+        layer = (on_one(v5e, (), jnp.int32),)
     compiled = compile_for(ragged_attention, q, pool, pool, table, per_row,
-                           per_row, per_slot)
+                           per_row, per_slot, *layer)
     assert KERNEL in compiled.as_text()
+    # a (16, 128) tail is whole tiles and the stack is read where it lies.
+    # gpt2-small's (12, 64) is not: the device keeps such an array in
+    # another dimension order than the kernel's row-major, and the compiler
+    # copies it (one layer's pool or the stack's alike)
+    if layers and hd == 128:
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def test_ragged_serving_step_compiles(v5e):
-    """The engine's whole tick at gpt2-small width (depth cut to two
-    layers): embed, scatter into the pools, ragged kernel, sampler."""
+@pytest.mark.parametrize("hidden,heads,vocab", [(768, 12, 50304),
+                                                (2048, 16, 8192)],
+                         ids=["hd64", "hd128"])
+def test_ragged_serving_step_compiles(v5e, hidden, heads, vocab):
+    """The engine's whole tick at gpt2-small width and at Cerebras-GPT
+    1.3B's (depth cut to two layers, the second one's vocabulary cut
+    too): embed, scatter into the pools, ragged kernel, sampler."""
     import paddle_tpu as paddle
     from paddle_tpu.models.gpt import GPTConfig, GPTModel
     from paddle_tpu.serving import RaggedPagedContinuousBatchingEngine
     paddle.seed(0)
     model = GPTModel(GPTConfig(
-        vocab_size=50304, hidden_size=768, num_layers=2,
-        num_attention_heads=12, max_position_embeddings=1024,
+        vocab_size=vocab, hidden_size=hidden, num_layers=2,
+        num_attention_heads=heads, max_position_embeddings=1024,
         compute_dtype="bfloat16"))
-    params = {n: p._data for n, p in model.named_parameters()}
+    # weights as the cells serve them: float32 ones are cast once for the
+    # whole stack, and that cast would be the program's temporaries
+    params = {n: p._data.astype(jnp.bfloat16)
+              for n, p in model.named_parameters()}
     eng = RaggedPagedContinuousBatchingEngine(
         model, params, max_slots=SLOTS, max_len=BLOCK * COLS,
         block_size=BLOCK, prompt_buckets=[64, 128], token_budget=BUDGET)
@@ -239,6 +261,16 @@ def test_ragged_serving_step_compiles(v5e):
                         eng._ragged_scratch_args(COLS))
     compiled = eng._build_ragged_step(BUDGET, COLS).lower(*args).compile()
     assert KERNEL in compiled.as_text()
+    # the tick holds its pools once: both are donated into the outputs,
+    # and no second copy of a side (nor a layer of one) is a temporary.
+    # (At hd 64 the compiler still re-orders both pools for the kernel,
+    # once a tick: see test_ragged_paged_compiles.)
+    side = min(x.nbytes for x in eng.caches)
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= sum(x.nbytes for x in eng.caches)
+    if hidden // heads == 128:
+        assert ma.temp_size_in_bytes < side // 2, (ma.temp_size_in_bytes,
+                                                   side)
 
 
 @pytest.mark.parametrize("cols", [1, 8, 256, 1040],

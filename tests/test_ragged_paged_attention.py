@@ -8,6 +8,7 @@ Mosaic lowering is exercised by the -m tpu smoke suite on hardware."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.models._decode import quantize_kv
@@ -114,3 +115,70 @@ class TestRaggedKernelParity:
             q, pk, pv, table, jnp.zeros_like(cu), jnp.zeros_like(kv),
             None, interpret=True)
         assert np.isfinite(np.asarray(empty)).all()
+
+
+def _stack_case(seed, quantized, L=3, NB1=11):
+    """``_case`` with a pool per layer, stacked as the engine stores them
+    (every leaf gains a leading layer axis)."""
+    q, _, _, table, cu, kv, pad, _ = _case(seed, NB1=NB1)
+    layers = [_case(seed + 100 * i, NB1=NB1, quantized=quantized)[1:3]
+              for i in range(L)]
+    pk, pv = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
+    return q, pk, pv, layers, table, cu, kv, pad
+
+
+class TestAddressedByLayer:
+    """With ``layer`` the pools are a whole stack's and that layer's
+    blocks are read, or written, in place: the same bits as the call on
+    ``stack[layer]`` — padding rows and left pads included (``_case``
+    draws both)."""
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["float", "int8"])
+    @pytest.mark.parametrize("path", ["kernel", "ref"])
+    def test_attention_over_a_stack_equals_the_sliced_call(self, path,
+                                                           quantized):
+        q, pk, pv, layers, table, cu, kv, pad = _stack_case(21, quantized)
+        rs, rp = ragged_rows(cu, kv, q.shape[0])
+        assert (np.asarray(rp) < 0).any() and (np.asarray(pad) > 0).any()
+        if path == "kernel":
+            fn = lambda pk, pv, layer: ragged_paged_attention(
+                q, pk, pv, table, cu, kv, pad, layer=layer, interpret=True)
+        else:
+            fn = lambda pk, pv, layer: ragged_attention_ref(
+                q, pk, pv, table, rs, rp, pad, layer=layer)
+        whole = jax.jit(fn)                     # the layer is a traced value
+        for i, (lk, lv) in enumerate(layers):
+            np.testing.assert_array_equal(
+                np.asarray(whole(pk, pv, jnp.int32(i))),
+                np.asarray(fn(lk, lv, None)))
+
+    @pytest.mark.parametrize("quantized", [False, True],
+                             ids=["float", "int8"])
+    def test_write_lands_in_its_layer_only(self, quantized):
+        """Both planes of an int8 pair: the scale plane used to be written
+        with the layer dropped (into the stack's first axis as if it were
+        the block axis)."""
+        from paddle_tpu.models._decode import ragged_write
+        q, pk, _, layers, table, cu, kv, _ = _stack_case(22, quantized,
+                                                         NB1=17)
+        # a block of its own per (sequence, column): no two rows collide
+        table = jnp.arange(1, table.size + 1,
+                           dtype=jnp.int32).reshape(table.shape)
+        rs, rp = ragged_rows(cu, kv, q.shape[0])
+        # both sides compiled: the quantizer's division rounds another
+        # way op by op
+        write = jax.jit(lambda pool, layer: ragged_write(
+            pool, q, table, rs, rp, layer))
+        for i, (lk, _) in enumerate(layers):
+            got = write(pk, jnp.int32(i))
+            want = write(lk, None)
+            for g, w, before in zip(jax.tree.leaves(got),
+                                    jax.tree.leaves(want),
+                                    jax.tree.leaves(pk)):
+                assert g.shape == before.shape
+                np.testing.assert_array_equal(np.asarray(g[i]),
+                                              np.asarray(w))
+                others = np.arange(before.shape[0]) != i
+                np.testing.assert_array_equal(np.asarray(g)[others],
+                                              np.asarray(before)[others])

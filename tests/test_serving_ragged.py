@@ -12,6 +12,7 @@ oracle is the framework's own single-request generation path."""
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
@@ -519,3 +520,92 @@ class TestRaggedFuzz:
             assert eng.blocks_in_use == cached
         else:
             assert eng.blocks_in_use == 0
+
+
+class TestPoolsCarriedWhole:
+    """``CausalDecoderMixin.decode_ragged`` carries both pools whole
+    through the layer scan and addresses the layer in place.  The oracle
+    is the walk it replaced, written plainly: a Python loop over the
+    layers, each block on its own layer's pool sliced out of the stack.
+
+    Served tokens are pinned by the tests above, which this class does not
+    repeat: mixed prefill + decode by TestRaggedParity
+    .test_interleaved_matches_solo_generate and
+    .test_one_program_serves_the_mixed_tick, int8 pools (kernel on and
+    off) by .test_int8_kv_pool, a preemption with replay by
+    TestRaggedAllocator.test_preemption_stays_exact_and_signals_replay,
+    the fused draft + verify tick and the MoE target by TestRaggedSpec."""
+
+    @staticmethod
+    def _models(family, kv_cache_dtype):
+        paddle.seed(23)
+        kw = dict(vocab_size=97, hidden_size=32, num_layers=3,
+                  num_attention_heads=4, max_position_embeddings=96,
+                  compute_dtype="float32")
+        if family == "gpt":
+            return GPTModel(GPTConfig(kv_cache_dtype=kv_cache_dtype, **kw))
+        from paddle_tpu.models.ernie_moe import (ErnieMoeConfig,
+                                                 ErnieMoeModel)
+        return ErnieMoeModel(ErnieMoeConfig(num_experts=4, top_k=2, **kw))
+
+    @pytest.mark.parametrize("interp", [False, True],
+                             ids=["gather", "kernel"])
+    @pytest.mark.parametrize("family,kv", [
+        ("gpt", None), ("gpt", "int8"), ("ernie-moe", None)],
+        ids=["gpt-float", "gpt-int8", "ernie-moe-float"])   # no int8 MoE
+    def test_equals_a_loop_over_sliced_pools(self, family, kv, interp):
+        from paddle_tpu.models._decode import build_pools
+        model = self._models(family, kv)
+        params = {n: p._data for n, p in model.named_parameters()}
+        L, S, C, bs, T = 3, 3, 4, 4, 16
+        rng = np.random.default_rng(5)
+        zeros = build_pools(model.cache_spec(), (S * C + 1, bs))
+        pools = jax.tree.map(
+            lambda z: jnp.asarray(rng.standard_normal(z.shape) * 0.5
+                                  if z.dtype != jnp.int8 else
+                                  rng.integers(-127, 128, z.shape),
+                                  z.dtype), zeros)
+        table = jnp.arange(1, S * C + 1, dtype=jnp.int32).reshape(S, C)
+        # a prefill chunk (seq 0, positions 2..7, two of them left pad), a
+        # decode row deep in seq 1, a chunk's head for seq 2, padding rows
+        row_seq = jnp.asarray([0] * 6 + [1] + [2] * 4 + [-1] * 5, jnp.int32)
+        row_pos = jnp.asarray(list(range(2, 8)) + [13] + list(range(4))
+                              + [-1] * 5, jnp.int32)
+        pads = jnp.asarray([2, 0, 0], jnp.int32)
+        toks = jnp.asarray(rng.integers(1, 97, T), jnp.int32)
+
+        def whole(params, pools):
+            h = model._embed_ragged(params, toks, row_seq, row_pos, pads)
+            return model.decode_ragged(params, h, pools, table, row_seq,
+                                       row_pos, pads)
+
+        # the block compiled alone and called once a layer: unrolled into
+        # one program, XLA's CPU backend fuses across the layers and rounds
+        # the last bit another way than inside a loop's body
+        block = jax.jit(lambda sl, h, pck, pcv: model._block_decode_ragged(
+            sl, h, pck, pcv, table, row_seq, row_pos, pads))
+
+        def sliced(params, pools):
+            h = model._embed_ragged(params, toks, row_seq, row_pos, pads)
+            pck, pcv = pools
+            ks, vs = [], []
+            for i in range(L):
+                sl = {k: params[k][i] for k in model.stacked_param_names()}
+                at = lambda pool: jax.tree.map(lambda p: p[i], pool)
+                h, k, v = block(sl, h, at(pck), at(pcv))
+                ks.append(k)
+                vs.append(v)
+            stack = lambda xs: jax.tree.map(lambda *p: jnp.stack(p), *xs)
+            return h, (stack(ks), stack(vs))
+
+        set_flags({"FLAGS_paged_attn_interpret": interp})
+        try:
+            got = jax.jit(whole)(params, pools)
+            want = sliced(params, pools)
+        finally:
+            set_flags({"FLAGS_paged_attn_interpret": False})
+        got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+        assert len(got) == len(want) == (5 if kv else 3)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape and g.dtype == w.dtype
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
