@@ -9,8 +9,8 @@ one process at a time:
              and ragged paged attention (bf16 and int8 pools): each compiled
              by the chip's compiler, shown to be in the compiled program, and
              compared with its XLA oracle — a quiet dense fallback fails here
-    train    ``make_gpt_train_step`` on bench.py's gpt2s geometry (16 x 1024),
-             five steps at bench.py's learning rate; losses finite and lower
+    train    ``make_gpt_train_step`` at 16 x 1024 tokens a step, five steps at
+             learning rate 3e-4; losses finite and lower
     serve    ``RaggedPagedContinuousBatchingEngine``: ``warmup()``, eight
              requests of mixed prompt length served to the end with no
              compile after warm-up, first-token logits against the plain
@@ -39,7 +39,7 @@ import os
 import sys
 import time
 
-# bench.py's gpt2s cell
+# gpt2-small's widths, the vocabulary padded to a multiple of 128
 GPT2_SMALL = dict(vocab_size=50304, hidden_size=768, num_layers=12,
                   num_attention_heads=12, max_position_embeddings=1024,
                   compute_dtype="bfloat16", scan_unroll=12)
@@ -49,7 +49,7 @@ REAL = dict(
     cfg=GPT2_SMALL, train_batch=(16, 1024), train_steps=5,
     attn=(4, 1024, 12, 64),             # B, L, H, D of the flash cases
     ce=(4096, 50304),                   # tokens, vocabulary
-    # the serving cell of bench.py: 8 slots x 512 positions, 16-token blocks
+    # serving at head dim 64: 8 slots x 512 positions, 16-token blocks
     slots=8, max_len=512, block=16, budget=256, buckets=[64, 128],
     prompts=[7, 23, 41, 64, 77, 100, 128, 128],
     new_tokens=[24, 16, 32, 8, 20, 12, 28, 16],
@@ -277,7 +277,7 @@ def phase_kernels(size, seed, on_chip):
 # ----------------------------------------------------------------- train --
 
 def build_step(size, seed, zero_stage=0, **degrees):
-    """bench.py's gpt2s path: fleet.init, GPTModel, make_gpt_train_step."""
+    """The one-chip train path: fleet.init, GPTModel, make_gpt_train_step."""
     import paddle_tpu as paddle
     from paddle_tpu.distributed import fleet
     from paddle_tpu.models.gpt import GPTConfig, GPTModel, make_gpt_train_step

@@ -30,8 +30,8 @@ def main():
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--ticks_per_sync", type=int, default=4)
     ap.add_argument("--speculative", action="store_true",
-                    help="speculative engine (1-layer draft): lossless, "
-                         "fewer rounds")
+                    help="speculate inside the ragged paged engine (1-layer "
+                         "draft): lossless, fewer rounds")
     ap.add_argument("--paged", action="store_true",
                     help="paged KV cache (block pool + tables): lazy HBM, "
                          "preemption, prefix caching")
@@ -56,26 +56,22 @@ def main():
         from jax.sharding import Mesh
         mesh = Mesh(np.array(jax.devices()[:args.mp]), ("model",))
 
-    if args.paged and args.mp > 1:
-        raise SystemExit("--paged is single-mesh; drop --mp")
+    if (args.paged or args.speculative) and args.mp > 1:
+        raise SystemExit("--paged and --speculative are single-mesh; "
+                         "drop --mp")
     if args.speculative:
         dcfg = GPTConfig(vocab_size=512, hidden_size=128, num_layers=1,
                          num_attention_heads=4, max_position_embeddings=256,
                          compute_dtype="float32")
         draft = GPTModel(dcfg)
         dparams = {n: p._data for n, p in draft.named_parameters()}
-        if args.paged:
-            from paddle_tpu.serving import PagedSpeculativeBatchingEngine
-            eng = PagedSpeculativeBatchingEngine(
-                model, params, draft, dparams, max_slots=args.slots,
-                max_len=128, draft_k=3, prompt_buckets=[16, 32],
-                block_size=16)
-        else:
-            from paddle_tpu.serving import SpeculativeBatchingEngine
-            eng = SpeculativeBatchingEngine(
-                model, params, draft, dparams, max_slots=args.slots,
-                max_len=128, draft_k=3, prompt_buckets=[16, 32],
-                mesh=mesh)
+        # speculation runs inside the ragged paged engine's tick, so the
+        # cache is paged with or without --paged
+        from paddle_tpu.serving import RaggedPagedContinuousBatchingEngine
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=args.slots, max_len=128,
+            block_size=16, prompt_buckets=[16, 32], draft_model=draft,
+            draft_params=dparams, draft_k=3)
     elif args.paged:
         from paddle_tpu.serving import PagedContinuousBatchingEngine
         # per-request sampling + prefix caching ride along: requests may
@@ -110,7 +106,7 @@ def main():
     for rid in wave1 + wave2:
         print(f"request {rid}: {len(out[rid])} tokens, "
               f"first 8 = {out[rid][:8]}")
-    extra = (f", spec rounds={eng.rounds}" if args.speculative else "")
+    extra = (f", spec rounds={eng.spec_rounds}" if args.speculative else "")
     if args.paged:
         extra += f", blocks hw={eng.blocks_high_water}"
         if not args.speculative:

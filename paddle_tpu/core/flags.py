@@ -78,17 +78,6 @@ define_flag("FLAGS_benchmark", False, "Block on every eager op result (perf debu
 define_flag("FLAGS_eager_nudge_after", 20000,
             "Warn once after this many consecutive grad-recording eager "
             "dispatches with no jit step (0 disables).")
-define_flag("FLAGS_use_fused_ln", False,
-            "Route LN+residual+dropout through the Pallas kernel (ops/fused.py); "
-            "off by default — flip only where tools/fused_probe.py shows XLA "
-            "leaving step time on the table.")
 define_flag("FLAGS_paged_attn_interpret", False,
             "Run the paged-attention decode kernel in Pallas interpret "
             "mode (CPU CI of the in-kernel table walk).")
-define_flag("FLAGS_fused_ln_interpret", False,
-            "Allow the fused-LN Pallas kernel in interpret mode off-TPU (tests).")
-define_flag("FLAGS_use_fused_adamw", False,
-            "Reserved for the flat fused AdamW sweep (ops/fused.py:"
-            "fused_adamw_flat — kernel shipped + tested; tree-level wiring "
-            "lands only if tools/fused_probe.py shows XLA's own fusion of the "
-            "update chain leaving >5% step time).")

@@ -34,7 +34,8 @@ The optimizer state is kept FLAT: one fused (n_pad,) vector per slot over
 the whole param tree (the ``grad_comm`` flatten, zero-padded so R always
 divides), because the reduce-scatter shard boundary cuts across parameter
 boundaries.  ``zero.per_device_state_bytes`` measures the saving
-directly; ``bench.py gpt_weight_update_sharding`` pins it ≥ 1.8× at R=2.
+directly; ``tests/test_sharding_rules.py::TestUpdateSharding`` pins it at
+exactly half at R=2.
 """
 
 from __future__ import annotations

@@ -21,7 +21,7 @@ scenario.  This module makes fault injection a first-class subsystem:
   whole chaos scenario and the SAME plan replays the SAME scenario;
 - a :class:`FaultyEngine` wrapper that injects the plan into any real
   engine's scheduling surface (``add_request`` / ``step`` / ``cancel`` /
-  ``warmup``) without the engine's cooperation — it works on the five
+  ``warmup``) without the engine's cooperation — it works on the three
   serving classes and on :class:`~paddle_tpu.simulation.SimEngine`
   alike, and everything else delegates through untouched.
 
@@ -221,8 +221,8 @@ class FaultPlan:
     probabilistic consumer must draw from (:class:`FaultyEngine` derives
     a per-replica ``random.Random`` from it), so one plan value replays
     one trajectory.  JSON round-trips via :meth:`to_dict` /
-    :meth:`from_dict` / :meth:`from_json` — the shape ``bench.py
-    gpt_chaos`` records and ``tools/serve_gateway.py --chaos`` parses."""
+    :meth:`from_dict` / :meth:`from_json` — the shape
+    ``tools/serve_gateway.py --chaos`` parses."""
 
     def __init__(self, faults: Sequence[Fault] = (), seed: int = 0):
         self.faults: List[Fault] = sorted(faults, key=lambda f: f.at_s)

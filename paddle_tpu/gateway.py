@@ -4,7 +4,7 @@ PRs 1-7 built everything *behind* the socket — ragged/paged/speculative
 engines, AOT-warmed compile caches, telemetry, a live ops endpoint — but
 ``add_request`` has no deadline, no cancel, no backpressure, and nothing
 routes across more than one engine.  :class:`ServingGateway` is that
-missing subsystem: it fronts N engine replicas (any mix of the five engine
+missing subsystem: it fronts N engine replicas (any mix of the three engine
 classes in ``paddle_tpu.serving``) and turns a fast engine into a service
 that stays fast under overload, replica stalls, and rolling restarts.
 
@@ -774,7 +774,7 @@ class ServingGateway:
 
     def add_replica(self, engine, name: Optional[str] = None,
                     role: str = "unified") -> str:
-        """Register an engine replica (any of the five serving classes —
+        """Register an engine replica (any of the three serving classes —
         it only needs the shared scheduling surface: ``add_request`` /
         ``step`` / ``pop_finished`` / ``cancel`` / ``pending``).
 
@@ -848,7 +848,7 @@ class ServingGateway:
                                  ) -> Optional[Callable[[], Any]]:
         """Register (or with None clear) the engine factory that elastic
         scale-out spawns replicas from — a zero-arg callable returning a
-        FRESH engine (any of the five serving classes).  The gateway never
+        FRESH engine (any of the three serving classes).  The gateway never
         calls it itself; ``autoscaler.ElasticAutoscaler`` does, then warms
         and ``add_replica``s the result."""
         if factory is not None and not callable(factory):

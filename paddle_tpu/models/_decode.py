@@ -67,8 +67,8 @@ def cached_attention(q, ck, cv, t, pad_lens=None):
         from ..core.flags import flag
         kernel_ok = (q.shape[1] == 1                 # the decode tick
                      and not isinstance(ck.pool, tuple))   # fp pools only
-        # FLAGS_use_pallas_kernels stays the authoritative kill switch (the
-        # ops/fused.py convention); the interpret arm applies only OFF-TPU
+        # FLAGS_use_pallas_kernels stays the authoritative kill switch: off,
+        # no kernel runs anywhere.  The interpret arm applies only OFF-TPU
         # (CPU CI of the in-kernel table walk)
         interp = (bool(flag("FLAGS_paged_attn_interpret"))
                   and jax.default_backend() != "tpu")
@@ -185,10 +185,10 @@ def ragged_attention(q_rows, pool_k, pool_v, table, row_seq, row_pos,
     table (S, C), row_seq/row_pos (T,) per-row metadata (see
     ops/ragged_paged_attention.ragged_rows), pad_lens (S,).
 
-    Dispatches between the Pallas in-kernel table walk (TPU, or interpret
-    mode for CPU CI — the ops/fused.py flag convention shared with
-    cached_attention's paged arm) and the XLA gather fallback; int8 pools
-    take the kernel too (dequant is fused in-kernel)."""
+    Dispatches between the Pallas in-kernel table walk (compiled on the TPU;
+    interpreted off it where FLAGS_paged_attn_interpret asks, for CPU CI;
+    never with FLAGS_use_pallas_kernels off: ``_pallas_dispatch``) and the XLA
+    gather fallback; int8 pools take the kernel too (dequant fused in-kernel)."""
     from ..ops.ragged_paged_attention import (ragged_attention_ref,
                                               ragged_attention_rows)
     use, interp = _pallas_dispatch()

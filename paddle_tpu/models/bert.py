@@ -162,17 +162,10 @@ class BertModel(Layer):
         else:
             att = dense_attention(q, k, v, mask=attn_mask, causal=False)
         att = att.reshape(B, Lq, H)
-        from ..core.flags import flag as _flag
 
         def epilogue(x, residual, ln_w, ln_b, bias):
-            """LN(residual + x + bias): Pallas fused epilogue (ops/fused.py ≙
-            fused_layernorm_residual_dropout_bias.h) when FLAGS_use_fused_ln,
-            else the plain _ln path — identical math up to fp32 rounding."""
-            if _flag("FLAGS_use_fused_ln"):
-                from ..ops.fused import fused_ln_residual_dropout
-                return fused_ln_residual_dropout(
-                    x, residual, ln_w, ln_b, bias=bias,
-                    eps=c.layer_norm_eps)[0].astype(dt)
+            """LN(residual + x + bias), the post-norm epilogue of both
+            halves of the block."""
             return self._ln(residual + x + bias.astype(dt), ln_w, ln_b).astype(dt)
 
         h = epilogue(att @ sl["blocks_proj_w"].astype(dt), h,

@@ -14,9 +14,8 @@ live: a ``ThreadingHTTPServer`` (stdlib only, no new deps) that any engine,
 ``GET /healthz``
     liveness JSON; **503** when the last observed step/tick/heartbeat is
     older than ``stall_threshold_s`` — the load-balancer / watchdog dial.
-    ``?probe=1`` additionally runs an in-process compute probe with the
-    same semantics as ``bench.py``'s backend probe (a jitted matmul
-    ROUND-TRIP to host, never a bare ``jax.devices()`` — a half-up
+    ``?probe=1`` additionally runs an in-process compute probe (a jitted
+    matmul ROUND-TRIP to host, never a bare ``jax.devices()`` — a half-up
     backend enumerates devices while compile/execute hangs), bounded by
     ``probe_timeout_s``.
 ``GET /ledger``
@@ -107,8 +106,7 @@ __all__ = ["OpsServer", "compute_probe"]
 
 
 def compute_probe(timeout_s: float = 10.0, n: int = 256) -> Dict[str, Any]:
-    """In-process compute health probe — the same semantics as
-    ``bench.py``'s backend probe: health is a jitted ``n×n`` matmul
+    """In-process compute health probe: health is a jitted ``n×n`` matmul
     round-trip to host (compile + execute + fetch), never a bare device
     enumeration.  Runs on a worker thread bounded by ``timeout_s``; on
     timeout the thread is abandoned (reported unhealthy), not killed — an
@@ -122,7 +120,7 @@ def compute_probe(timeout_s: float = 10.0, n: int = 256) -> Dict[str, Any]:
             import numpy as np
             t0 = time.perf_counter()
             x = jnp.ones((n, n), jnp.float32)
-            # tpulint: disable=jit-in-hot-loop(one-shot probe — paying trace+compile+execute is the health check itself, bench.py probe parity)
+            # tpulint: disable=jit-in-hot-loop(one-shot probe — paying trace+compile+execute is the health check itself)
             v = float(np.asarray(jax.jit(lambda a: a @ a)(x)[0, 0]))
             result.update(ok=True, value=v,
                           wall_s=round(time.perf_counter() - t0, 4),

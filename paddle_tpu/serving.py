@@ -57,8 +57,7 @@ from .models._decode import (apply_repetition_penalty, make_row_sampler,
                              suppress_eos, suppress_eos_rows,
                              validate_sampler_args)
 
-__all__ = ["ContinuousBatchingEngine", "SpeculativeBatchingEngine",
-           "Request"]
+__all__ = ["ContinuousBatchingEngine", "Request"]
 
 
 # what _step_impl enters for each phase of a round when no tracer is
@@ -105,15 +104,6 @@ def _program_cost(run, a, kw):
         logging.getLogger(__name__).debug(
             "serving cost attribution failed", exc_info=True)
         return None
-
-
-def _default_buckets(max_len: int) -> List[int]:
-    """The engines' default prompt-bucket ladder for ``max_len`` — ONE
-    copy shared by the base constructor and the speculative shims (which
-    need the resolved ladder before construction to derive a block
-    size)."""
-    return [b for b in (16, 32, 64, 128, 256, 512, 1024)
-            if b <= max_len] or [int(max_len)]
 
 
 def _slot_write(slot):
@@ -211,7 +201,8 @@ class ContinuousBatchingEngine:
         self.S = int(max_slots)
         self.max_len = int(max_len)
         if prompt_buckets is None:
-            prompt_buckets = _default_buckets(max_len)
+            prompt_buckets = [b for b in (16, 32, 64, 128, 256, 512, 1024)
+                              if b <= max_len] or [int(max_len)]
         self.buckets = sorted(set(int(b) for b in prompt_buckets))
         self.eos_token_id = eos_token_id
         self.ticks_per_sync = int(ticks_per_sync)
@@ -934,8 +925,8 @@ class ContinuousBatchingEngine:
         CHUNK-ROUNDED decode: the first token comes from prefill (no decode
         position), the remaining budget-1 tokens consume ceil((budget-1)/k)
         * k positions (decode advances k ticks per sync; pad slots occupy
-        physical positions).  The speculative engine overrides this with
-        its over-proposal arithmetic."""
+        physical positions).  The ragged engine overrides this with its
+        speculative over-proposal arithmetic."""
         k = self.ticks_per_sync
         return P + -(-(mnt - 1) // k) * k
 
@@ -1388,19 +1379,14 @@ class ContinuousBatchingEngine:
         return self.pop_finished()
 
 
-# The speculative engines and every paged (block-table) variant are
-# defined in serving_paged.py and re-exported here LAZILY (PEP 562) so
-# `paddle_tpu.serving` stays the single public serving namespace without
-# a circular import (serving_paged imports this module at its top).
-# `SpeculativeBatchingEngine` / `PagedSpeculativeBatchingEngine` are now
-# deprecation SHIMS over the unified ragged engine: speculation runs
+# Every paged (block-table) variant is defined in serving_paged.py and
+# re-exported here LAZILY (PEP 562) so `paddle_tpu.serving` stays the
+# single public serving namespace without a circular import
+# (serving_paged imports this module at its top).  Speculation runs
 # inside `RaggedPagedContinuousBatchingEngine` as part of the one-
-# program-per-tick ragged pack (draft_model=/draft_k= constructor args),
-# so the legacy engines' separate program families are gone.
+# program-per-tick ragged pack (draft_model=/draft_k= constructor args).
 _PAGED_NAMES = ("PagedContinuousBatchingEngine",
-                "PagedSpeculativeBatchingEngine",
-                "RaggedPagedContinuousBatchingEngine",
-                "SpeculativeBatchingEngine")
+                "RaggedPagedContinuousBatchingEngine")
 __all__ += [n for n in _PAGED_NAMES if n not in __all__]
 
 

@@ -45,7 +45,6 @@ from __future__ import annotations
 
 import collections
 import logging
-import math
 import time
 from functools import partial
 from typing import Optional
@@ -54,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .serving import ContinuousBatchingEngine, _default_buckets
+from .serving import ContinuousBatchingEngine
 from .jit.bucketing import pow2_bucket, pow2_grid, select_bucket
 from .kv_store import KVPage, chain_hex
 from .telemetry import (PHASE_ADMIT, PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC,
@@ -64,9 +63,7 @@ from .models._decode import (PagedKV, apply_repetition_penalty,
                              suppress_eos, suppress_eos_rows)
 
 __all__ = ["PagedContinuousBatchingEngine",
-           "PagedSpeculativeBatchingEngine",
-           "RaggedPagedContinuousBatchingEngine",
-           "SpeculativeBatchingEngine"]
+           "RaggedPagedContinuousBatchingEngine"]
 
 
 # ---------------------------------------------------------------------------
@@ -1267,10 +1264,6 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         """Steps that carried at least one slot's draft+verify rows."""
         return int(self._stats.value("spec_rounds"))
 
-    # legacy spec engines' efficiency-reporting attribute (the shims'
-    # oracle tests and tools/serve_bench.py read it)
-    rounds = spec_rounds
-
     @property
     def tokens_drafted(self) -> int:
         return int(self._stats.value("tokens_drafted"))
@@ -1892,95 +1885,3 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             m["acceptance_rate"] = float(self.acceptance_rate)
             m["accepted_tokens_per_s"] = self.tokens_accepted / dt
         return m
-
-
-# ---------------------------------------------------------------------------
-# legacy speculative engines — deprecation shims over the ragged spec path
-# ---------------------------------------------------------------------------
-
-_SPEC_SHIM_WARNED: set = set()
-
-
-def _warn_spec_shim(name: str):
-    """Warn ONCE per legacy engine class (the deprecation contract)."""
-    if name in _SPEC_SHIM_WARNED:
-        return
-    _SPEC_SHIM_WARNED.add(name)
-    import warnings
-    warnings.warn(
-        f"{name} is deprecated: speculative decoding now runs INSIDE "
-        f"RaggedPagedContinuousBatchingEngine (draft_model=/draft_k= "
-        f"constructor args) as part of the one-program-per-tick ragged "
-        f"pack; this shim maps the legacy constructor onto the unified "
-        f"engine", DeprecationWarning, stacklevel=3)
-
-
-class SpeculativeBatchingEngine(RaggedPagedContinuousBatchingEngine):
-    """DEPRECATED shim: the pre-ragged speculative engine (its own
-    spec_prefill-per-bucket + spec_round program family) is gone —
-    speculation now runs inside the ragged engine's single fused
-    draft+verify program per (token_budget, table-width) bucket.  This
-    shim maps the legacy contiguous constructor (no storage knobs) onto
-    the unified engine, deriving a block size from max_len and the
-    bucket ladder.  Outputs keep the greedy contract: token for token
-    equal to plain decode, with rounds shrinking by the acceptance rate
-    (``engine.rounds`` still reports them)."""
-
-    _SUPPORTED_CACHE_KW = frozenset({"tracer"})
-
-    def __init__(self, model, params, draft_model, draft_params,
-                 max_slots: int, max_len: int, draft_k: int = 4,
-                 prompt_buckets=None, eos_token_id=None, key=None,
-                 mesh=None, **cache_kw):
-        _warn_spec_shim(type(self).__name__)
-        if mesh is not None:
-            raise NotImplementedError(
-                "speculative engine v1 is single-mesh")
-        # the legacy scope guard: sampler knobs the greedy round would
-        # silently ignore (and storage knobs this shim has no notion of)
-        # are rejected loudly, exactly as before
-        bad = set(cache_kw) - self._SUPPORTED_CACHE_KW
-        if bad:
-            raise NotImplementedError(
-                f"{type(self).__name__} does not support {sorted(bad)}")
-        buckets = (_default_buckets(max_len) if prompt_buckets is None
-                   else sorted(set(int(b) for b in prompt_buckets)))
-        # the contiguous engine had no block size; pick the largest one
-        # that divides max_len and every bucket (>= 1 always works)
-        bs = math.gcd(int(max_len), *[int(b) for b in buckets])
-        super().__init__(model, params, max_slots, max_len,
-                         draft_model=draft_model,
-                         draft_params=draft_params, draft_k=draft_k,
-                         prompt_buckets=buckets,
-                         eos_token_id=eos_token_id, key=key,
-                         block_size=bs, **cache_kw)
-
-
-class PagedSpeculativeBatchingEngine(SpeculativeBatchingEngine):
-    """DEPRECATED shim: the paged-speculative composition (dual-pool
-    prefill/seg programs + spec_round_paged per table width) is gone —
-    the unified ragged engine already keeps the draft pool behind the
-    target's tables and allocator, so this shim only forwards the
-    storage knobs.  ``prefill_chunk`` is accepted and dropped: the
-    ragged engine chunks prefill inherently via token_budget."""
-
-    _SUPPORTED_CACHE_KW = frozenset({"block_size", "num_blocks",
-                                     "enable_prefix_cache",
-                                     "prefill_chunk", "tracer"})
-
-    def __init__(self, model, params, draft_model, draft_params,
-                 max_slots: int, max_len: int, draft_k: int = 4,
-                 prompt_buckets=None, eos_token_id=None, key=None,
-                 block_size: int = 16, num_blocks=None, **kw):
-        _warn_spec_shim(type(self).__name__)
-        bad = set(kw) - self._SUPPORTED_CACHE_KW
-        if bad:
-            raise NotImplementedError(
-                f"{type(self).__name__} does not support {sorted(bad)}")
-        kw.pop("prefill_chunk", None)   # ragged chunks via token_budget
-        RaggedPagedContinuousBatchingEngine.__init__(
-            self, model, params, max_slots, max_len,
-            draft_model=draft_model, draft_params=draft_params,
-            draft_k=draft_k, prompt_buckets=prompt_buckets,
-            eos_token_id=eos_token_id, key=key, block_size=block_size,
-            num_blocks=num_blocks, **kw)

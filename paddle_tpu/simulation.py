@@ -41,9 +41,9 @@ Pieces:
   empty (the zero-drop contract across every transition).
 
 This doubles as the scenario-diversity workload generator the ROADMAP
-north star asks for: the same arrival processes drive the real engines
-in ``bench.py`` A/Bs (``gpt_autoscale``) and the CPU tier-1 scenario
-tests (``tests/test_autoscaler.py``).
+north star asks for: the arrival processes that drive the CPU tier-1
+scenario tests (``tests/test_autoscaler.py``,
+``tests/test_gateway_resilience.py``).
 
 Everything here is stdlib + telemetry — importing it never touches JAX,
 so policy tests cost milliseconds.

@@ -62,8 +62,7 @@ Exports:
 - ``request_summary()``           exact p50/p95/p99 TTFT and inter-token
   latency over the retained per-request timelines;
 - ``summary()``                   one JSON-able snapshot (tick histogram,
-  compile counts, request percentiles) — what ``bench.py`` attaches to
-  BENCH rounds.
+  compile counts, request percentiles).
 
 Recompile visibility: every program-cache miss after the engine's first
 completed tick is counted as a *post-warmup* recompile; once
@@ -518,12 +517,16 @@ class Tracer:
         span (a ``jax.profiler.TraceAnnotation``, so in a profiler session
         the round lies on the host plane on the device operations' clock;
         inert otherwise) and return the note that ``tick()`` closes the
-        round with: ``{"tick": seq, "phases": {}}``.  The engine adds
-        whatever it packed to the same dict; until ``tick()`` every
-        ``phase()`` adds its seconds to it and every request event carries
-        its number.  One engine per tracer: rounds do not nest."""
+        round with: ``{"tick": seq, "ts_open": now, "phases": {}}``
+        (``ts_open`` on the clock every event's ``ts`` is on, so the round
+        spans ``[ts_open, ts]`` of its ``tick`` event and an event stamped
+        inside it lies inside that).  The engine adds whatever it packed to
+        the same dict; until ``tick()`` every ``phase()`` adds its seconds
+        to it and every request event carries its number.  One engine per
+        tracer: rounds do not nest."""
         self._tick_seq += 1
-        self._open_tick = note = {"tick": self._tick_seq, "phases": {}}
+        self._open_tick = note = {"tick": self._tick_seq,
+                                  "ts_open": self.now(), "phases": {}}
         self._tick_span = _annotation(PHASE_TICK, tick=self._tick_seq)
         self._tick_span.__enter__()
         return note
@@ -1534,8 +1537,7 @@ class TrainMonitor:
         return self.tracer.events(kind)
 
     def summary(self) -> Dict[str, Any]:
-        """One JSON-able snapshot — what ``bench.py`` attaches to gpt
-        training BENCH rounds: step-wall percentiles, device-blocked
+        """One JSON-able snapshot: step-wall percentiles, device-blocked
         percentiles, throughput, compile counts, watchdog/AMP counters,
         HBM peaks."""
         from .utils.stats import get_stat

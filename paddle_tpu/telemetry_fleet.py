@@ -69,7 +69,7 @@ Targets come in three transports, all sharing one scrape path:
 - ``url=``     a live ops endpoint scraped over HTTP (stdlib urllib,
   per-request timeout);
 - ``server=``  an in-process :class:`OpsServer` (rendered directly, no
-  socket — what ``bench.py`` and the sim fleet use);
+  socket — what the sim fleet uses);
 - ``fetch=``   a callable ``fetch(path) -> str | dict | None`` (the
   fake-clock test harness; ``None`` = endpoint absent).
 

@@ -510,20 +510,18 @@ class TestEngineWarmup:
 
     @pytest.mark.slow
     def test_speculative_engines_warmup(self):
-        """The legacy speculative engines are now shims over the unified
-        ragged spec path: their grid is ONE fused draft+verify program
-        per table-width bucket (the dual-pool prefill / seg / spec-round
-        families are gone), and a warmed shim still serves with zero
-        in-serve misses."""
-        from paddle_tpu.serving import (PagedSpeculativeBatchingEngine,
-                                        SpeculativeBatchingEngine)
+        """With a draft the ragged engine's grid is ONE fused
+        draft+verify program per table-width bucket (no dual-pool
+        prefill / seg / spec-round families), at one bucket or two, and
+        a warmed engine serves with zero in-serve misses."""
         model, params = _model()
         paddle.seed(1)
         draft = GPTModel(GPTConfig(**CFG))
         dparams = {n: p._data for n, p in draft.named_parameters()}
-        eng = SpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=32,
-            draft_k=2, prompt_buckets=[8])
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=32, block_size=8,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=2)
         labels = eng.compile_grid()
         assert labels == [f"ragged_spec:{eng.token_budget}:{C}"
                           for C in pow2_grid(eng.MB)]
@@ -534,10 +532,10 @@ class TestEngineWarmup:
         assert eng._compile_misses == m0 and len(out[rid]) == 4
 
         model.__dict__.pop("_serving_programs", None)
-        eng2 = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=32,
-            draft_k=2, prompt_buckets=[8, 16], block_size=8,
-            prefill_chunk=8)       # legacy knob: accepted and dropped
+        eng2 = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=32, block_size=8,
+            prompt_buckets=[8, 16], draft_model=draft, draft_params=dparams,
+            draft_k=2)
         labels = eng2.compile_grid()
         assert all(lbl.startswith("ragged_spec:") for lbl in labels)
         assert len(labels) == len(pow2_grid(eng2.MB))

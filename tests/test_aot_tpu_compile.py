@@ -339,49 +339,3 @@ def test_ragged_kernel_is_called_under_its_own_name(v5e):
     assert names and all(
         "/ragged_paged_attention/ragged_paged_attention/" in n
         for n in names)
-
-
-# ---- the fused LayerNorm epilogue and the fused AdamW sweep: off by default
-# (core/flags.py), so not on the main path.  The forward compiles; the other
-# two are refused for a block of one row over a taller array.  Pinned strict,
-# so whoever repairs one learns it here and turns its flag question (ROADMAP
-# D4) into a measurement.
-
-LN_ROWS, LN_WIDTH = 16 * 1024, 768
-
-
-def fused_ln(x, res, w, b):
-    from paddle_tpu.ops.fused import _fused_ln_core
-    return _fused_ln_core(x, res, w, b, None, jnp.zeros((1,), jnp.uint32),
-                          0.0, 1e-5, False)
-
-
-def ln_args(topo):
-    x = on_one(topo, (LN_ROWS, LN_WIDTH), jnp.bfloat16)
-    w = on_one(topo, (LN_WIDTH,), jnp.float32)
-    return x, x, w, w
-
-
-def test_fused_ln_forward_compiles(v5e):
-    assert KERNEL in compile_for(fused_ln, *ln_args(v5e)).as_text()
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason="Mosaic refuses the (1, H) partial-sum blocks of "
-                          "_ln_bwd_kernel's outputs")
-def test_fused_ln_backward_compiles(v5e):
-    def loss(x, res, w, b):
-        out, rout = fused_ln(x, res, w, b)
-        return (out.astype(jnp.float32).sum()
-                + rout.astype(jnp.float32).sum())
-    compile_for(jax.grad(loss, argnums=(0, 1, 2, 3)), *ln_args(v5e))
-
-
-@pytest.mark.xfail(strict=True, raises=ValueError,
-                   reason="Mosaic refuses the (1, 8192) row blocks of "
-                          "fused_adamw_flat")
-def test_fused_adamw_compiles(v5e):
-    from paddle_tpu.ops.fused import fused_adamw_flat
-    p = on_one(v5e, (124_000_000,), jnp.float32)   # gpt2-small's parameters
-    compile_for(lambda p, g, m, v: fused_adamw_flat(p, g, m, v, 1, 1e-3),
-                p, p, p, p)

@@ -313,23 +313,26 @@ class TestRaggedSpec:
             assert got[rid] == _solo_greedy(model, params, p, n), rid
         assert eng.blocks_in_use == 0
 
-    def test_perfect_draft_rounds_stats_rollback(self, model_and_params):
-        """Self-draft: every proposal accepted — minimal round count,
-        acceptance_rate exactly 1.0 on the registry-backed stats, spec
-        counters in the Prometheus exposition (the gateway /metrics
-        merge concatenates it), and the rejected-page rollback leaves a
-        clean allocator."""
+    @pytest.mark.parametrize("block_size", [4, 8])
+    def test_perfect_draft_rounds_stats_rollback(self, model_and_params,
+                                                 block_size):
+        """Self-draft: every proposal accepted — one request of N tokens
+        finishes in exactly ceil((N-1)/(K+1)) rounds (the observable that
+        catches silent acceptance degradation), at a fine block size and
+        at the coarsest (gcd(max_len, bucket)); acceptance_rate exactly
+        1.0 on the registry-backed stats, spec counters in the Prometheus
+        exposition (the gateway /metrics merge concatenates it), and the
+        rejected-page rollback leaves a clean allocator."""
         model, params = model_and_params
         K, N = 3, 13
         eng = RaggedPagedContinuousBatchingEngine(
-            model, params, max_slots=1, max_len=48, block_size=4,
+            model, params, max_slots=1, max_len=48, block_size=block_size,
             prompt_buckets=[8], draft_model=model, draft_params=params,
             draft_k=K)
         rid = eng.add_request([5, 17, 3], N)
         got = eng.run_to_completion(max_ticks=100)
         assert got[rid] == _solo_greedy(model, params, [5, 17, 3], N)
         assert eng.spec_rounds == -(-(N - 1) // (K + 1))
-        assert eng.rounds == eng.spec_rounds       # legacy-compat alias
         m = eng.metrics()
         assert m["acceptance_rate"] == 1.0
         assert m["tokens_drafted"] == eng.spec_rounds * K

@@ -1,10 +1,11 @@
-"""Paged speculative continuous batching (PagedSpeculativeBatchingEngine):
-the two serving accelerations composed.  The draft pool shares the
-target's block tables and allocator; the spec round runs the SAME
-_spec_round_core with pools wrapped as PagedKV — so outputs must stay
-bit-lossless vs plain greedy (and vs the contiguous speculative engine),
-and the paged allocator's deferral/preemption must hold under tight
-pools.  Beyond-reference (the snapshot has no serving scheduler)."""
+"""Paged speculative continuous batching
+(RaggedPagedContinuousBatchingEngine with draft_model= / draft_params= /
+draft_k= and the storage knobs: block_size, num_blocks, prefix cache): the
+two serving accelerations composed.  The draft pool shares the target's
+block tables and allocator — so outputs must stay bit-lossless vs plain
+greedy (at every block size), and the paged allocator's
+deferral/preemption must hold under tight pools.  Beyond-reference (the
+snapshot has no serving scheduler)."""
 
 import numpy as np
 import pytest
@@ -13,8 +14,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.models.gpt import GPTConfig, GPTModel
-from paddle_tpu.serving import (PagedSpeculativeBatchingEngine,
-                                SpeculativeBatchingEngine)
+from paddle_tpu.serving import RaggedPagedContinuousBatchingEngine
 
 
 import functools
@@ -52,49 +52,38 @@ class TestPagedSpeculative:
     @pytest.mark.parametrize("K", [1, 3])
     def test_lossless_vs_solo_and_contiguous(self, K):
         """Mixed budgets through 2 slots (retirement + reuse): outputs
-        equal plain greedy solo AND the contiguous speculative engine,
+        equal plain greedy solo at a fine block size AND at the coarsest
+        one (gcd(max_len, bucket): a slot's pages all but contiguous),
         token for token, with the same round count."""
         model, params, draft, dparams = _models()
-        paged = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=48,
-            draft_k=K, prompt_buckets=[8], block_size=4)
+        paged = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=48, block_size=4,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=K)
         rids = [paged.add_request(p, n) for p, n in REQS]
         got = paged.run_to_completion(max_ticks=300)
-        cont = SpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=48,
-            draft_k=K, prompt_buckets=[8])
+        cont = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=48, block_size=8,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=K)
         rids_c = [cont.add_request(p, n) for p, n in REQS]
         got_c = cont.run_to_completion(max_ticks=300)
         for rid, rc, (p, n) in zip(rids, rids_c, REQS):
             want = _solo(model, params, p, n)
             assert got[rid] == want, f"paged diverged (K={K})"
             assert got_c[rc] == want
-        assert paged.rounds == cont.rounds      # same acceptance schedule
+        assert paged.spec_rounds == cont.spec_rounds  # same acceptance schedule
         assert paged.blocks_in_use == 0
-
-    def test_perfect_draft_minimal_rounds(self):
-        """draft == target: every proposal accepted — one request of N
-        tokens finishes in exactly ceil((N-1)/(K+1)) rounds (the
-        acceptance-degradation regression observable, now on the paged
-        layout)."""
-        model, params, draft, dparams = _models()
-        K, N = 3, 13
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, model, params, max_slots=1, max_len=48,
-            draft_k=K, prompt_buckets=[8], block_size=4)
-        rid = eng.add_request([5, 17, 3], N)
-        got = eng.run_to_completion(max_ticks=100)
-        assert got[rid] == _solo(model, params, [5, 17, 3], N)
-        assert eng.rounds == -(-(N - 1) // (K + 1))
 
     def test_tight_pool_preempts_and_stays_exact(self):
         """Two long requests cannot both fit: the younger is preempted
         and rerun, outputs stay greedy-exact, high water respects the
         cap — the paged allocator composing with spec growth spans."""
         model, params, draft, dparams = _models()
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=48,
-            draft_k=2, prompt_buckets=[8], block_size=4, num_blocks=10)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=48, block_size=4,
+            num_blocks=10, prompt_buckets=[8], draft_model=draft,
+            draft_params=dparams, draft_k=2)
         r0 = eng.add_request([5, 17, 3], 24)   # P+mnt+K-1 = 33 -> 9 blocks
         r1 = eng.add_request([40, 2], 24)
         got = eng.run_to_completion(max_ticks=500)
@@ -106,9 +95,10 @@ class TestPagedSpeculative:
     def test_int8_pools(self):
         """int8 target AND draft pools through the shared tables."""
         model, params, draft, dparams = _models(kv="int8")
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=48,
-            draft_k=2, prompt_buckets=[8], block_size=8)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=48, block_size=8,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=2)
         rids = [eng.add_request(p, n) for p, n in REQS[:3]]
         got = eng.run_to_completion(max_ticks=300)
         for rid, (p, n) in zip(rids, REQS[:3]):
@@ -119,9 +109,10 @@ class TestPagedSpeculative:
         model.__dict__.pop("_serving_programs", None)
 
         def make():
-            return PagedSpeculativeBatchingEngine(
-                model, params, draft, dparams, max_slots=2, max_len=48,
-                draft_k=2, prompt_buckets=[8], block_size=4)
+            return RaggedPagedContinuousBatchingEngine(
+                model, params, max_slots=2, max_len=48, block_size=4,
+                prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+                draft_k=2)
 
         eng = make()
         for p, n in REQS[:3]:
@@ -137,16 +128,17 @@ class TestPagedSpeculative:
         model, params, draft, dparams = _models()
         # sampler knobs the greedy round would ignore: rejected loudly
         with pytest.raises(NotImplementedError, match="min_new_tokens"):
-            PagedSpeculativeBatchingEngine(
-                model, params, draft, dparams, max_slots=2, max_len=48,
-                prompt_buckets=[8], block_size=4, min_new_tokens=2)
-        # the CONTIGUOUS spec engine still rejects chunked prefill (its
-        # step has no paged filler machinery); the paged composition
-        # supports it (TestPagedSpecChunked)
-        with pytest.raises(NotImplementedError, match="prefill_chunk"):
-            SpeculativeBatchingEngine(
-                model, params, draft, dparams, max_slots=2, max_len=48,
-                prompt_buckets=[8], prefill_chunk=4)
+            RaggedPagedContinuousBatchingEngine(
+                model, params, max_slots=2, max_len=48, block_size=4,
+                prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+                min_new_tokens=2)
+        # prefill_chunk is the bucketed engines' knob: the ragged engine
+        # chunks prefill through token_budget (TestPagedSpecChunked)
+        with pytest.raises(ValueError, match="prefill_chunk"):
+            RaggedPagedContinuousBatchingEngine(
+                model, params, max_slots=2, max_len=48, block_size=8,
+                prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+                prefill_chunk=4)
 
 
 class TestPagedSpecFuzz:
@@ -163,10 +155,10 @@ class TestPagedSpecFuzz:
         bs = int(rng.choice([4, 8]))
         worst = -(-(16 + 11 + K - 1) // bs)
         nb = int(rng.randint(worst, worst * 3))
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams,
-            max_slots=int(rng.randint(1, 4)), max_len=48, draft_k=K,
-            prompt_buckets=[8, 16], block_size=bs, num_blocks=nb)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=int(rng.randint(1, 4)), max_len=48,
+            block_size=bs, num_blocks=nb, prompt_buckets=[8, 16],
+            draft_model=draft, draft_params=dparams, draft_k=K)
         reqs = []
         for _ in range(int(rng.randint(3, 8))):
             p = [int(t) for t in rng.randint(1, 97, rng.randint(1, 15))]
@@ -189,29 +181,29 @@ class TestPagedSpecPrefixCache:
         vs warm — the cached DRAFT prefix must be right, not just the
         target's)."""
         model, params, draft, dparams = _models()
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=48,
-            draft_k=2, prompt_buckets=[16], block_size=4,
-            enable_prefix_cache=True)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=48, block_size=4,
+            prompt_buckets=[16], draft_model=draft, draft_params=dparams,
+            draft_k=2, enable_prefix_cache=True)
         LONG = list(range(3, 17))
         r0 = eng.add_request(LONG, 8)
         g0 = eng.run_to_completion(max_ticks=200)
-        cold = eng.rounds
+        cold = eng.spec_rounds
         r1 = eng.add_request(LONG, 8)
         g1 = eng.run_to_completion(max_ticks=200)
         want = _solo(model, params, LONG, 8)
         assert g0[r0] == want and g1[r1] == want
         assert eng.prefix_hits == 1 and eng.prefix_blocks_reused == 3
-        assert eng.rounds == 2 * cold
+        assert eng.spec_rounds == 2 * cold
 
     def test_concurrent_sharing_with_speculation(self):
         """Two same-prefix requests decode speculatively side by side with
         refcounted shared blocks; both stay exact."""
         model, params, draft, dparams = _models()
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=48,
-            draft_k=2, prompt_buckets=[16], block_size=4,
-            enable_prefix_cache=True)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=48, block_size=4,
+            prompt_buckets=[16], draft_model=draft, draft_params=dparams,
+            draft_k=2, enable_prefix_cache=True)
         a = [7] * 2 + list(range(20, 32))
         b = a[:8] + list(range(70, 76))         # same length, shared 8
         r0 = eng.add_request(a, 6)
@@ -224,10 +216,10 @@ class TestPagedSpecPrefixCache:
 
     def test_int8_dual_pool_prefix(self):
         model, params, draft, dparams = _models(kv="int8")
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=48,
-            draft_k=2, prompt_buckets=[16], block_size=8,
-            enable_prefix_cache=True)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=48, block_size=8,
+            prompt_buckets=[16], draft_model=draft, draft_params=dparams,
+            draft_k=2, enable_prefix_cache=True)
         LONG = list(range(3, 17))
         r0 = eng.add_request(LONG, 6)
         eng.run_to_completion(max_ticks=200)
@@ -239,14 +231,15 @@ class TestPagedSpecPrefixCache:
 
 class TestPagedSpecChunked:
     def test_chunked_fill_under_speculative_decode(self):
-        """A long prompt chunk-fills over 4 rounds while another request
-        decodes SPECULATIVELY next door — the filler's parked clock must
-        keep the K+1-wide stale writes in trash; both outputs lossless."""
+        """A long prompt chunk-fills over several rounds (a token budget
+        of 10 leaves it 4 rows a tick beside two slots' K+1 verify rows)
+        while another request decodes SPECULATIVELY next door; both
+        outputs lossless."""
         model, params, draft, dparams = _models()
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=64,
-            draft_k=2, prompt_buckets=[4, 16], block_size=4,
-            prefill_chunk=4)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=64, block_size=4,
+            prompt_buckets=[4, 16], draft_model=draft, draft_params=dparams,
+            draft_k=2, token_budget=10)
         r0 = eng.add_request([40, 2], 20)      # bucket 4: decodes all test
         LONG = list(range(3, 19))              # bucket 16, pad 0: 4 segs
         r1 = eng.add_request(LONG, 8)
@@ -255,37 +248,37 @@ class TestPagedSpecChunked:
         assert got[r1] == _solo(model, params, LONG, 8)
 
     def test_chunked_plus_prefix_plus_speculation(self):
-        """All three compose: a warm prefix hit whose suffix fits one
-        chunk bypasses chunked admission entirely, stays lossless, and
-        keeps the acceptance schedule."""
+        """All three compose: under a 10-row token budget a warm prefix
+        hit whose suffix fits one chunk admits in one tick, stays
+        lossless, and keeps the acceptance schedule."""
         model, params, draft, dparams = _models()
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=64,
-            draft_k=2, prompt_buckets=[16], block_size=4,
-            prefill_chunk=4, enable_prefix_cache=True)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=64, block_size=4,
+            prompt_buckets=[16], draft_model=draft, draft_params=dparams,
+            draft_k=2, token_budget=10, enable_prefix_cache=True)
         LONG = list(range(3, 17))
         r0 = eng.add_request(LONG, 8)
         g0 = eng.run_to_completion(max_ticks=300)
-        cold = eng.rounds
+        cold = eng.spec_rounds
         r1 = eng.add_request(LONG, 8)
         g1 = eng.run_to_completion(max_ticks=300)
         want = _solo(model, params, LONG, 8)
         assert g0[r0] == want and g1[r1] == want
         assert eng.prefix_hits == 1
-        assert eng.rounds == 2 * cold
+        assert eng.spec_rounds == 2 * cold
 
 
 class TestCancel:
-    """Engine.cancel(rid) on the composed speculative+paged engine
-    (ISSUE 9): the shared-table allocator releases BOTH pools' blocks
-    through one cancel, and the remaining request stays bit-lossless."""
+    """Engine.cancel(rid) with a draft attached (ISSUE 9): the
+    shared-table allocator releases BOTH pools' blocks through one
+    cancel, and the remaining request stays bit-lossless."""
 
     def test_cancel_releases_shared_tables(self):
         model, params, draft, dparams = _models()
-        from paddle_tpu.serving import PagedSpeculativeBatchingEngine
-        eng = PagedSpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=64,
-            draft_k=2, prompt_buckets=[8], block_size=4)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=64, block_size=4,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=2)
         sig = []
         r0 = eng.add_request([5, 17, 3], 20,
                              on_token=lambda r, t, d: sig.append((t, d)))
@@ -302,12 +295,13 @@ class TestCancel:
         assert m["blocks_allocated"] == m["blocks_released"]
 
     def test_cancel_contiguous_speculative(self):
-        """The plain (contiguous) speculative engine cancels clean too —
-        base-class slot release, no allocator involved."""
+        """At the coarsest block size (gcd(max_len, bucket)) a cancel
+        mid-speculation releases clean too."""
         model, params, draft, dparams = _models()
-        eng = SpeculativeBatchingEngine(
-            model, params, draft, dparams, max_slots=2, max_len=64,
-            draft_k=2, prompt_buckets=[8])
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=64, block_size=8,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=2)
         r0 = eng.add_request([5, 17, 3], 20)
         r1 = eng.add_request([61], 8)
         eng.step()

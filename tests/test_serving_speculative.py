@@ -1,8 +1,10 @@
-"""Speculative continuous batching (SpeculativeBatchingEngine): draft
-proposals + one verify chunk per round, per-slot acceptance — outputs must
-be BIT-LOSSLESS vs the plain engine (greedy acceptance takes the longest
-argmax-matching prefix, the models/_decode.py speculative contract), while
-a good draft cuts the round count."""
+"""Speculative continuous batching (RaggedPagedContinuousBatchingEngine with
+draft_model= / draft_params= / draft_k=): draft proposals + one verify chunk
+per round, per-slot acceptance — outputs must be BIT-LOSSLESS vs the plain
+engine (greedy acceptance takes the longest argmax-matching prefix, the
+models/_decode.py speculative contract), while a good draft cuts the round
+count.  The block size is gcd(max_len, bucket) throughout: the coarsest
+paging each geometry allows (tests/test_serving_spec_paged.py pages finer)."""
 
 import numpy as np
 import pytest
@@ -13,7 +15,7 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu.models.gpt import GPTConfig, GPTModel
 from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                SpeculativeBatchingEngine)
+                                RaggedPagedContinuousBatchingEngine)
 
 
 @pytest.fixture(scope="module")
@@ -47,9 +49,10 @@ class TestSpeculativeEngine:
         prids = [plain.add_request(p, n) for p, n in zip(PROMPTS, BUDGETS)]
         want = plain.run_to_completion(max_ticks=300)
 
-        spec = SpeculativeBatchingEngine(target, tparams, draft, dparams,
-                                         max_slots=2, max_len=48,
-                                         draft_k=3, prompt_buckets=[8])
+        spec = RaggedPagedContinuousBatchingEngine(
+            target, tparams, max_slots=2, max_len=48, block_size=8,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=3)
         srids = [spec.add_request(p, n) for p, n in zip(PROMPTS, BUDGETS)]
         got = spec.run_to_completion(max_ticks=300)
         for pr, sr in zip(prids, srids):
@@ -62,14 +65,15 @@ class TestSpeculativeEngine:
         round-3 draft-cache-hole bug class)."""
         target, tparams, _, _ = models
         K, N = 3, 13
-        spec = SpeculativeBatchingEngine(target, tparams, target, tparams,
-                                         max_slots=1, max_len=48,
-                                         draft_k=K, prompt_buckets=[8])
+        spec = RaggedPagedContinuousBatchingEngine(
+            target, tparams, max_slots=1, max_len=48, block_size=8,
+            prompt_buckets=[8], draft_model=target, draft_params=tparams,
+            draft_k=K)
         rid = spec.add_request(PROMPTS[0], N)
         got = spec.run_to_completion(max_ticks=100)
         assert len(got[rid]) == N
-        assert spec.rounds == -(-(N - 1) // (K + 1)), \
-            (spec.rounds, N, K)
+        assert spec.spec_rounds == -(-(N - 1) // (K + 1)), \
+            (spec.spec_rounds, N, K)
 
     def test_eos_retires_and_slot_reuse_stays_lossless(self, models):
         """EOS mid-round discards the accepted tail; the freed slot's next
@@ -82,10 +86,10 @@ class TestSpeculativeEngine:
         eos = full[4]
         cut = full.index(eos) + 1
 
-        spec = SpeculativeBatchingEngine(target, tparams, draft, dparams,
-                                         max_slots=1, max_len=48,
-                                         draft_k=3, prompt_buckets=[8],
-                                         eos_token_id=int(eos))
+        spec = RaggedPagedContinuousBatchingEngine(
+            target, tparams, max_slots=1, max_len=48, block_size=8,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=3, eos_token_id=int(eos))
         r0 = spec.add_request(PROMPTS[0], 10)
         r1 = spec.add_request(PROMPTS[3], 4)
         got = spec.run_to_completion(max_ticks=200)
@@ -98,9 +102,10 @@ class TestSpeculativeEngine:
         """A request admitted while another is mid-speculation must not
         perturb it (slot isolation under variable per-row advance)."""
         target, tparams, draft, dparams = models
-        spec = SpeculativeBatchingEngine(target, tparams, draft, dparams,
-                                         max_slots=2, max_len=48,
-                                         draft_k=3, prompt_buckets=[8])
+        spec = RaggedPagedContinuousBatchingEngine(
+            target, tparams, max_slots=2, max_len=48, block_size=8,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=3)
         r0 = spec.add_request(PROMPTS[0], 12)
         for _ in range(2):
             spec.step()
@@ -113,9 +118,10 @@ class TestSpeculativeEngine:
 
     def test_budget_includes_overproposal_slack(self, models):
         target, tparams, draft, dparams = models
-        spec = SpeculativeBatchingEngine(target, tparams, draft, dparams,
-                                         max_slots=1, max_len=20,
-                                         draft_k=4, prompt_buckets=[8])
+        spec = RaggedPagedContinuousBatchingEngine(
+            target, tparams, max_slots=1, max_len=20, block_size=4,
+            prompt_buckets=[8], draft_model=draft, draft_params=dparams,
+            draft_k=4)
         with pytest.raises(ValueError, match="exceeds max_len"):
             spec.add_request([1, 2, 3], 10)   # 8 + 10 + 3 > 20
         spec.add_request([1, 2, 3], 9)        # 8 + 9 + 3 == 20: fits
@@ -131,18 +137,18 @@ class TestSpeculativeEngine:
             compute_dtype="float32"))
         bv = {n: p._data for n, p in bad_vocab.named_parameters()}
         with pytest.raises(ValueError, match="vocab"):
-            SpeculativeBatchingEngine(target, tparams, bad_vocab, bv,
-                                      max_slots=1, max_len=32,
-                                      prompt_buckets=[8])
+            RaggedPagedContinuousBatchingEngine(
+                target, tparams, max_slots=1, max_len=32, block_size=8,
+                prompt_buckets=[8], draft_model=bad_vocab, draft_params=bv)
         short_pos = GPTModel(GPTConfig(
             vocab_size=97, hidden_size=16, num_layers=1,
             num_attention_heads=4, max_position_embeddings=16,
             compute_dtype="float32"))
         sp = {n: p._data for n, p in short_pos.named_parameters()}
         with pytest.raises(ValueError, match="DRAFT"):
-            SpeculativeBatchingEngine(target, tparams, short_pos, sp,
-                                      max_slots=1, max_len=32,
-                                      prompt_buckets=[8])
+            RaggedPagedContinuousBatchingEngine(
+                target, tparams, max_slots=1, max_len=32, block_size=8,
+                prompt_buckets=[8], draft_model=short_pos, draft_params=sp)
 
 
 @pytest.mark.slow
@@ -155,10 +161,10 @@ def test_speculative_fuzz_matches_solo(models, seed):
     rng = np.random.RandomState(100 + seed)
     K = int(rng.choice([1, 2, 4]))
     eos = int(rng.randint(0, 97)) if rng.rand() < 0.5 else None
-    spec = SpeculativeBatchingEngine(
-        target, tparams, draft, dparams, max_slots=int(rng.randint(1, 4)),
-        max_len=48, draft_k=K, prompt_buckets=[8],
-        eos_token_id=eos)
+    spec = RaggedPagedContinuousBatchingEngine(
+        target, tparams, max_slots=int(rng.randint(1, 4)), max_len=48,
+        block_size=8, prompt_buckets=[8], draft_model=draft,
+        draft_params=dparams, draft_k=K, eos_token_id=eos)
     reqs = []
     for _ in range(int(rng.randint(3, 7))):
         p = [int(t) for t in rng.randint(1, 97, rng.randint(1, 9))]
@@ -188,9 +194,9 @@ def test_speculative_engine_int8_target(models):
     target = GPTModel(cfg)
     tparams = {n: p._data for n, p in target.named_parameters()}
     _, _, draft, dparams = models
-    spec = SpeculativeBatchingEngine(target, tparams, draft, dparams,
-                                     max_slots=2, max_len=48, draft_k=3,
-                                     prompt_buckets=[8])
+    spec = RaggedPagedContinuousBatchingEngine(
+        target, tparams, max_slots=2, max_len=48, block_size=8,
+        prompt_buckets=[8], draft_model=draft, draft_params=dparams, draft_k=3)
     rids = [spec.add_request(p, n) for p, n in zip(PROMPTS[:3], (8, 5, 7))]
     got = spec.run_to_completion(max_ticks=200)
     assert spec.caches[0][0].dtype == jnp.int8
@@ -213,9 +219,9 @@ def test_cross_family_moe_target_gpt_draft(models):
     target = ErnieMoeModel(cfg)
     tparams = {n: p._data for n, p in target.named_parameters()}
     _, _, draft, dparams = models   # GPT 1-layer draft, same vocab
-    spec = SpeculativeBatchingEngine(target, tparams, draft, dparams,
-                                     max_slots=2, max_len=48, draft_k=3,
-                                     prompt_buckets=[8])
+    spec = RaggedPagedContinuousBatchingEngine(
+        target, tparams, max_slots=2, max_len=48, block_size=8,
+        prompt_buckets=[8], draft_model=draft, draft_params=dparams, draft_k=3)
     rids = [spec.add_request(p, n) for p, n in zip(PROMPTS[:3], (7, 5, 6))]
     got = spec.run_to_completion(max_ticks=200)
     for rid, p, n in zip(rids, PROMPTS[:3], (7, 5, 6)):

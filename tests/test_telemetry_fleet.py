@@ -764,7 +764,7 @@ class TestAutoscalerFleetSignal:
 
 
 # ---------------------------------------------------------------------------
-# offline regression detection + bench_diff fleet fields
+# offline regression detection
 # ---------------------------------------------------------------------------
 
 class TestReplayRegressions:
@@ -794,39 +794,6 @@ class TestReplayRegressions:
         snap = replay_regressions(
             [], [Objective.floor("f", "tokens_per_s", 1.0)])
         assert snap["replayed_records"] == 0
-
-
-class TestBenchDiffFleetFields:
-    def _rec(self, **fleet):
-        return {"metric": "gpt_gateway_ttft_ms_p99", "value": 28.0,
-                "unit": "ms", "backend": "cpu", "fleet": fleet}
-
-    def test_fleet_block_expands_to_direction_aware_rows(self):
-        bd = _load_tool("bench_diff")
-        rows = bd.expand_telemetry([self._rec(
-            goodput_global=0.6, fleet_ttft_p99=0.02, straggler_skew=1.5,
-            targets=3)])
-        by = {r["metric"]: r for r in rows}
-        gp = by["gpt_gateway_ttft_ms_p99.fleet.goodput_global"]
-        assert gp["direction"] == "higher" and gp["unit"] == "frac"
-        assert gp["backend"] == "cpu"
-        ttft = by["gpt_gateway_ttft_ms_p99.fleet.fleet_ttft_p99"]
-        assert ttft["direction"] == "lower"
-        # target counts are scenario context, never judged
-        assert "gpt_gateway_ttft_ms_p99.fleet.targets" not in by
-
-    def test_fleet_regression_is_flagged(self):
-        bd = _load_tool("bench_diff")
-        old = bd.expand_telemetry([self._rec(fleet_ttft_p99=0.02,
-                                             goodput_global=0.6)])
-        new = bd.expand_telemetry([self._rec(fleet_ttft_p99=0.05,
-                                             goodput_global=0.3)])
-        rows, n_reg, n_cmp = bd.compare(old, new, threshold=0.1)
-        flagged = {r["metric"] for r in rows
-                   if str(r["status"]).startswith("REGRESSION")}
-        assert "gpt_gateway_ttft_ms_p99.fleet.fleet_ttft_p99" in flagged
-        assert "gpt_gateway_ttft_ms_p99.fleet.goodput_global" in flagged
-        assert n_reg >= 2 and n_cmp >= 3
 
 
 # ---------------------------------------------------------------------------

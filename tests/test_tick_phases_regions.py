@@ -144,14 +144,14 @@ def test_verify_chunks_are_rows_of_k_plus_one(served):
 @pytest.mark.parametrize("scenario", SCENARIO_IDS)
 def test_request_events_carry_the_tick_that_emitted_them(served, scenario):
     eng, tr, prompts, got = served[scenario]
-    spans = {e["tick"]: (e["ts"] - e["dur_s"], e["ts"])
-             for e in tr.events("tick")}
+    # the tracer's own clock at both ends of the round: no tolerance
+    spans = {e["tick"]: (e["ts_open"], e["ts"]) for e in tr.events("tick")}
     inside = [e for e in tr.events("request") if e["what"] != "queued"]
     assert inside and all("tick" not in e for e in tr.events("request")
                           if e["what"] == "queued")
     for e in inside:
         lo, hi = spans[e["tick"]]
-        assert lo - 1e-4 <= e["ts"] <= hi + 1e-4
+        assert lo <= e["ts"] <= hi
     # a request's timeline joins the rounds that served it by number
     for rid in range(len(prompts)):
         rounds = {e["tick"] for e in inside

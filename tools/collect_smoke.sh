@@ -53,9 +53,7 @@ then
     exit 1
 fi
 # grad-comm surface: the policy layer must import clean, the int8 local
-# round trip must run, the byte model must clear the 3.5x contract, and
-# the gpt_grad_comm bench config must be registered with a working
-# --help path
+# round trip must run, and the byte model must clear the 3.5x contract
 if ! JAX_PLATFORMS=cpu python - >/dev/null 2>&1 <<'GCEOF'
 import jax.numpy as jnp
 from paddle_tpu.distributed.grad_comm import (
@@ -67,15 +65,9 @@ out, e = p.apply_local(tree, None)
 assert e is not None and out["w"].shape == (8, 64)
 wb = wire_bytes(tree, p)
 assert wb["pre_bytes"] / wb["post_bytes"] >= 3.5, wb
-import bench
-assert "gpt_grad_comm" in bench.CONFIGS
 GCEOF
 then
-    echo "COLLECT SMOKE FAILED: grad_comm policy layer / bench config"
-    exit 1
-fi
-if ! python bench.py --help >/dev/null 2>&1; then
-    echo "COLLECT SMOKE FAILED: bench.py --help"
+    echo "COLLECT SMOKE FAILED: grad_comm policy layer"
     exit 1
 fi
 # AOT surface: jit.aot must import clean, a tiny warmup→serve round trip
@@ -102,8 +94,6 @@ eng.add_request([1, 2, 3], 2)
 out = eng.run_to_completion(max_ticks=50)
 assert eng._compile_misses == before, "warmup missed a program family"
 assert all(len(v) == 2 for v in out.values()), out
-import bench
-assert "gpt_serving_warmup" in bench.CONFIGS
 AOTEOF
 then
     echo "COLLECT SMOKE FAILED: jit.aot import / warmup round trip"
@@ -151,10 +141,6 @@ then
     echo "COLLECT SMOKE FAILED: goodput ledger / ops server round trip"
     exit 1
 fi
-if ! python tools/bench_diff.py --help >/dev/null 2>&1; then
-    echo "COLLECT SMOKE FAILED: tools/bench_diff.py --help"
-    exit 1
-fi
 # serving gateway surface: the module must import clean, a tiny
 # two-replica submit→stream→drain round trip must finish with zero drops
 # (streamed tokens intact, drained replica stopped), and the gateway CLI
@@ -191,8 +177,6 @@ assert [t for t, d in streams[r1.gid]] == r1.tokens
 assert streams[r1.gid][-1][1] is True
 assert sorted(got) == sorted([r1.gid, r2.gid])
 assert gw.replica("a").engine.blocks_in_use == 0
-import bench
-assert "gpt_gateway" in bench.CONFIGS
 GWEOF
 then
     echo "COLLECT SMOKE FAILED: serving gateway round trip"

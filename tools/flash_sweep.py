@@ -1,6 +1,6 @@
-"""Flash-attention block-size sweep on the gpt2s bench config (a builder's
-round-2 profile put the flash backward at ~11 ms/step; block size is the
-main lever).  Never run on the chip since.
+"""Flash-attention block-size sweep on chip_smoke.py's gpt2-small train step,
+16 x 1024 tokens (a builder's round-2 profile put the flash backward at ~11 ms/step;
+block size is the main lever).  Never run on the chip since.
 
 Each block size runs in a FRESH child process because
 ``PADDLE_TPU_FLASH_BLOCK`` is read at trace time and jit caches the kernel.
@@ -34,11 +34,42 @@ def child(block):
     return json.loads(lines[-1]) if lines else {"error": proc.stderr[-300:]}
 
 
+ITERS = 30
+
+
 def measure():
-    import bench
-    out = bench.bench_gpt2s(on_tpu=True)
-    out["flash_block"] = os.environ.get("PADDLE_TPU_FLASH_BLOCK", "auto")
-    print(json.dumps(out), flush=True)
+    """One child: chip_smoke's gpt2-small train step (its geometry, seed and
+    learning rate), compiled once, then ITERS steps on one batch with the
+    clock stopped after block_until_ready on the last loss (each step
+    consumes the state the one before produced)."""
+    import time
+
+    import jax
+    import numpy as np
+
+    import chip_smoke
+    from paddle_tpu.core.device import local_devices
+
+    device = local_devices("tpu")[0]        # raises where there is no chip
+    size = chip_smoke.REAL
+    step, state = chip_smoke.build_step(size, 0)
+    args = chip_smoke.step_args(size, 0)
+    state, loss = step(state, *args)        # compiles
+    jax.block_until_ready(loss)
+    t0 = time.perf_counter()
+    for _ in range(ITERS):
+        state, loss = step(state, *args)
+    jax.block_until_ready(loss)
+    dt = time.perf_counter() - t0
+    loss = float(loss)
+    if not np.isfinite(loss):
+        raise FloatingPointError(f"non-finite loss {loss}")
+    B, L = size["train_batch"]
+    print(json.dumps({
+        "value": round(B * L * ITERS / dt, 1), "unit": "tokens/s",
+        "loss": round(loss, 4), "device": device.device_kind,
+        "flash_block": os.environ.get("PADDLE_TPU_FLASH_BLOCK", "auto")}),
+        flush=True)
 
 
 def main():
@@ -55,8 +86,7 @@ def main():
     if results:
         best = max(results, key=lambda r: r["value"])
         print(json.dumps({"best_block": best["flash_block"],
-                          "tokens_per_sec": best["value"],
-                          "mfu": best.get("mfu")}), flush=True)
+                          "tokens_per_sec": best["value"]}), flush=True)
 
 
 if __name__ == "__main__":
