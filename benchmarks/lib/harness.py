@@ -3,6 +3,7 @@ the checks, and the place where readers find what was observed."""
 
 import contextlib
 import json
+import math
 import os
 import time
 
@@ -64,16 +65,27 @@ class Context:
 
     def check(self, name, value, limit, at_least=None):
         """One number compared, beside its limit; printed in every run."""
-        if at_least is not None:
-            ok = value >= at_least
-            self.note(f"check {name}: {value!r} at least {at_least!r} "
-                      f"{'ok' if ok else 'FAILED'}")
-        else:
-            ok = value == value and value <= limit
-            self.note(f"check {name}: {value!r} limit {limit!r} "
-                      f"{'ok' if ok else 'FAILED'}")
-        self.checks.append((name, value, limit, ok))
+        kind, bound = (("limit", limit) if at_least is None
+                       else ("at_least", at_least))
+        ok = bool(value <= bound if at_least is None else value >= bound)
+        self.note(f"check {name}: {value!r} {kind.replace('_', ' ')} "
+                  f"{bound!r} {'ok' if ok else 'FAILED'}")
+        self.checks.append({"name": name, "value": value, kind: bound,
+                            "ok": ok})
         return ok
+
+    def checked(self):
+        """Every check of the run in call order, as the result line
+        carries them: ``{name: {"value", "limit" or "at_least", "ok"}}``.
+        A value that is not finite (a ``nan`` gap) is ``null`` there and
+        not ok: ``NaN`` is not JSON and would cost the reader the line."""
+        out = {}
+        for c in self.checks:
+            c = dict(c)
+            if not math.isfinite(c["value"]):
+                c.update(value=None, ok=False)
+            out[c.pop("name")] = c
+        return out
 
     # ------------------------------------------------------------ spans --
     @contextlib.contextmanager

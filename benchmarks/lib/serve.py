@@ -47,21 +47,14 @@ def warm_up(eng, engine_cfg, vocab):
     eng.pop_finished()
 
 
-def run(ctx):
-    from paddle_tpu.telemetry import Tracer
-
-    cfg, traffic = ctx.config, ctx.traffic
-    ecfg = traffic["engine"]
-    params = weights.make_gpt_params(cfg, ctx.seed, "bfloat16")
-    tracer = Tracer(capacity=1 << 22)
-    eng = program.build_engine(cfg, ecfg, params, tracer)
-    with ctx.span("warm_up"):
-        warm_up(eng, ecfg, cfg["vocab_size"])
-    ctx.note(f"engine warmed: {eng.metrics()['compile_misses']} programs, "
-             f"{time.monotonic() - ctx.t_start:.1f}s since start")
-
+def offer(ctx, eng, vocab):
+    """Offer the cell's schedule to ``eng`` through the ramp and the
+    window: the one loop of both serving drivers.  Returns every scheduled
+    request, those due inside the window, and (ramp start, window open,
+    window close, end of the loop) on ``time.monotonic``."""
+    traffic = ctx.traffic
     sched = schedule.build_schedule(traffic, ctx.seconds)
-    prompts = schedule.prompt_tokens(sched, ctx.seed, cfg["vocab_size"])
+    prompts = schedule.prompt_tokens(sched, ctx.seed, vocab)
     ctx.note(f"schedule digest {schedule.digest(sched)} "
              f"requests {len(sched)} seed {ctx.seed}")
     ramp, drain = traffic.get("ramp_s", 0.0), traffic.get("drain_s", 0.0)
@@ -78,13 +71,29 @@ def run(ctx):
         r.tokens.append(int(token))
         r.times.append(now())
 
+    def inject(r):
+        r.injected = now()
+        r.rid = eng.add_request(r.prompt, r.out_len, on_token=on_token)
+        by_rid[r.rid] = r
+
+    # a backlog is due before the ramp starts, and is in the engine's queue
+    # before the ramp's clock does: handing over a long one takes the host
+    # a good part of a second, and the ramp is the engine's to run in
+    live = [Live(s.due_s, p, s.output_len) for s, p in zip(sched, prompts)]
+    nxt = 0
+    while nxt < len(live) and live[nxt].due <= -ramp:
+        inject(live[nxt])
+        nxt += 1
     t_begin = now()
+    if nxt:
+        ctx.note(f"backlog queued: {nxt} requests in "
+                 f"{t_begin - live[0].injected:.3f}s")
     w_open, w_close = t_begin + ramp, t_begin + ramp + ctx.seconds
-    live = [Live(w_open + s.due_s, p, s.output_len)
-            for s, p in zip(sched, prompts)]
+    for r in live:
+        r.due += w_open
     in_window = [r for r in live if w_open <= r.due < w_close]
     misses0 = eng.metrics()["compile_misses"]
-    nxt, opened, trace_at = 0, False, w_close - ctx.trace_s
+    opened, trace_at = False, w_close - ctx.trace_s
     while True:
         t = now()
         if not opened and t >= w_open:
@@ -95,11 +104,7 @@ def run(ctx):
         if nxt < len(live) and live[nxt].due <= t:
             with ctx.span("add_requests"):
                 while nxt < len(live) and live[nxt].due <= t:
-                    r = live[nxt]
-                    r.injected = now()
-                    r.rid = eng.add_request(r.prompt, r.out_len,
-                                            on_token=on_token)
-                    by_rid[r.rid] = r
+                    inject(live[nxt])
                     nxt += 1
         if t >= w_close:
             if ctx.tracing:
@@ -119,25 +124,50 @@ def run(ctx):
                   sum(1 for r in live if len(r.tokens) < r.out_len), None,
                   at_least=1)
     ctx.close_window(compiles=eng.metrics()["compile_misses"] - misses0)
+    return live, in_window, (t_begin, w_open, w_close, t_end)
+
+
+def whole_ticks(tracer, t_begin, w_open, w_close):
+    """(the ticks since the ramp began, those the rate counts, the seconds
+    they took).  Whole ticks only: from the end of the tick in flight
+    when the window opened to the end of the one in flight when it
+    closed."""
+    ticks = [dict(e, end=tracer.t0 + e["ts"],
+                  start=tracer.t0 + e["ts"] - e["dur_s"])
+             for e in tracer.events("tick") if e.get("budget_used")
+             and tracer.t0 + e["ts"] - e["dur_s"] >= t_begin]
+    ends = [k["end"] for k in ticks]
+    a = min([e for e in ends if e >= w_open], default=None)
+    b = min([e for e in ends if e >= w_close], default=max(ends, default=0))
+    counted = [k for k in ticks if a is not None and a < k["end"] <= b]
+    return ticks, counted, (b - a) if counted else 0.0
+
+
+def run(ctx):
+    from paddle_tpu.telemetry import Tracer
+
+    cfg, traffic = ctx.config, ctx.traffic
+    ecfg = traffic["engine"]
+    params = weights.make_gpt_params(cfg, ctx.seed, "bfloat16")
+    tracer = Tracer(capacity=1 << 22)
+    eng = program.build_engine(cfg, ecfg, params, tracer)
+    with ctx.span("warm_up"):
+        warm_up(eng, ecfg, cfg["vocab_size"])
+    ctx.note(f"engine warmed: {eng.metrics()['compile_misses']} programs, "
+             f"{time.monotonic() - ctx.t_start:.1f}s since start")
+
+    live, in_window, (t_begin, w_open, w_close, t_end) = offer(
+        ctx, eng, cfg["vocab_size"])
 
     # ------------------------------------------------------ end to end --
     ttft = [(r.times[0] - r.due) * 1e3 if r.tokens else math.inf
             for r in in_window]
     gaps = [(b - a) * 1e3 for r in live
             for a, b in zip(r.times, r.times[1:]) if w_open <= b < w_close]
-    ticks = [dict(e, end=tracer.t0 + e["ts"],
-                  start=tracer.t0 + e["ts"] - e["dur_s"])
-             for e in tracer.events("tick") if e.get("budget_used")
-             and tracer.t0 + e["ts"] - e["dur_s"] >= t_begin]
-    # whole ticks only: from the end of the tick in flight when the
-    # window opened to the end of the one in flight when it closed
-    ends = [k["end"] for k in ticks]
-    a = min([e for e in ends if e >= w_open], default=None)
-    b = min([e for e in ends if e >= w_close], default=max(ends, default=0))
-    counted = [k for k in ticks if a is not None and a < k["end"] <= b]
+    ticks, counted, span_s = whole_ticks(tracer, t_begin, w_open, w_close)
     e2e = {"ttft_p90_ms": stats.percentile(ttft, 90),
            "itl_p95_ms": stats.percentile(gaps, 95),
-           "serve_tok_s": (sum(k["budget_used"] for k in counted) / (b - a)
+           "serve_tok_s": (sum(k["budget_used"] for k in counted) / span_s
                            if counted else None)}
     failed = sum(1 for x in ttft if math.isinf(x))
     inside = sum(1 for r in in_window if r.tokens
@@ -148,7 +178,7 @@ def run(ctx):
              f"{stats.percentile(gaps, 50)} share_inside_1s_200ms "
              f"{inside / max(len(in_window), 1):.4f} gaps {len(gaps)} "
              f"ticks_counted {len(counted)} span_s "
-             f"{(b - a) if counted else 0:.3f} end_after_close_s "
+             f"{span_s:.3f} end_after_close_s "
              f"{t_end - w_close:.3f}")
 
     # -------------------------------------------------- what readers read --
@@ -172,10 +202,11 @@ def run(ctx):
         "pool_blocks": ecfg["num_blocks"], "preemptions": eng.preemptions,
         "ragged_steps": m["ragged_steps"], "mixed_steps": m["mixed_steps"],
         "events_dropped": tracer.events_dropped})
+    note_rounds(ctx, counted)
     if ctx.trace and ctx.trace_window:
-        obs["ragged_ticks"] = traced_rows(
-            ticks, live, lines, tracer.t0, ecfg["block_size"],
-            ctx.trace_window)
+        # the buckets are the multiples of the block (program.build_engine)
+        pads = {r.rid: -len(r.prompt) % ecfg["block_size"] for r in live}
+        obs["ragged_ticks"] = traced_packs(ticks, ctx.trace_window, pads)
     ctx.read_memory()
 
     # --------------------------------------------------------- correct --
@@ -195,57 +226,48 @@ def run(ctx):
     return {"end_to_end": e2e, "attempted": attempted, "failed": failed}
 
 
-def traced_rows(ticks, live, lines, tracer_t0, block, window):
-    """For each tick wholly inside the traced window, the (rows, keys) of
-    every sequence in its pack, rebuilt from what the engine reports: a
-    decode row from each token a request received in the tick, prefill
-    rows by handing the tick's ``prefill_tokens`` to the admitted requests
-    oldest first (the engine's documented order).  None if the counts do
-    not add up (a preemption or a dry pool reordered the rows)."""
-    t0, t1 = window
-    reqs = []
-    for r in live:
-        tl = lines.get(r.rid)
-        if r.rid is None or tl is None or tl.admitted_at is None:
-            continue
-        P = -(-len(r.prompt) // block) * block
-        reqs.append({"adm": tracer_t0 + tl.admitted_at, "P": P,
-                     "pad": P - len(r.prompt), "filled": 0,
-                     "times": r.times, "replays": r.replays})
-    if any(q["replays"] for q in reqs):
-        return None
-    reqs.sort(key=lambda q: q["adm"])
-    out, first = [], 0
-    for k in sorted(ticks, key=lambda k: k["end"]):
-        left = k.get("prefill_tokens", 0)
-        rows = []
-        for q in reqs[first:]:
-            if q["adm"] > k["end"] or left <= 0:
-                break
-            if q["filled"] >= q["P"]:
-                continue
-            m = min(q["P"] - q["filled"], left)
-            lo, hi = q["filled"], q["filled"] + m
-            real = hi - max(lo, q["pad"])
-            if real > 0:
-                rows.append((real, hi - q["pad"]))
-            q["filled"], left = hi, left - m
-        while first < len(reqs) and reqs[first]["filled"] >= reqs[first]["P"]:
-            first += 1
-        if left:
-            return None
-        if not (t0 <= k["start"] and k["end"] <= t1):
-            continue
-        n_dec = 0
-        for q in reqs:
-            for j, t in enumerate(q["times"]):
-                if j and k["start"] < t <= k["end"]:
-                    rows.append((1, q["P"] - q["pad"] + j))
-                    n_dec += 1
-        if n_dec != k.get("decode_rows", 0):
-            return None
-        out.append(rows)
+def packed_rows(tick, pads):
+    """[(real rows, keys the last of them attends)] per sequence of one
+    ``tick`` event, as the engine recorded the pack (``rows``: ``[rid,
+    rows, kv_end]``, the decode rows first).  The bucket's left-pad rows
+    attend nothing and are taken out: a first chunk's rows include them,
+    and a decode row's ``kv_end`` counts them as positions (``pads``:
+    request id -> left-pad rows of its bucket; none where it is not
+    known)."""
+    out = []
+    for i, (rid, n, kv) in enumerate(tick["rows"]):
+        if i < tick.get("decode_rows", 0):
+            kv -= pads.get(rid, 0)
+        if kv > 0:
+            out.append((min(n, kv), kv))
     return out
+
+
+def traced_packs(ticks, window, pads):
+    """The pack of every tick that lies wholly inside the traced window:
+    what the kernel's traced calls worked on."""
+    t0, t1 = window
+    return [packed_rows(k, pads) for k in ticks
+            if t0 <= k["start"] and k["end"] <= t1]
+
+
+def note_rounds(ctx, counted):
+    """The window's counted rounds, those that carry a prefill chunk and
+    those of decode rows only: how many, their rows, seconds, p50 and
+    max, and the seconds by phase.  Printed, no metric."""
+    for kind, some in (("with a chunk", [k for k in counted
+                                          if k.get("prefill_tokens")]),
+                       ("decode only", [k for k in counted
+                                        if not k.get("prefill_tokens")])):
+        ms = sorted(k["dur_s"] * 1e3 for k in some)
+        phases = {p: sum(k["phases"].get(p, 0.0) for k in some)
+                  for p in (some[0]["phases"] if some else ())}
+        ctx.note(f"counted rounds {kind}: {len(some)}, rows "
+                 f"{sum(k['budget_used'] for k in some)}, "
+                 f"{sum(ms) / 1e3:.3f}s, ms p50 "
+                 f"{stats.percentile(ms, 50)} max {ms[-1] if ms else None}; "
+                 "seconds by phase " + ", ".join(
+                     f"{p} {v:.3f}" for p, v in phases.items()))
 
 
 def check_served(ctx, cfg, params, done):

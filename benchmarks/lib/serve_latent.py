@@ -1,16 +1,14 @@
-"""The serving loop for a model that is not GPT: one chip's share of a
+"""The serving driver for a model that is not GPT: one chip's share of a
 ``pangu_ultra_moe`` configuration through the same ragged paged engine.
 
-``lib/serve.py``, ``lib/program.py``, ``lib/weights.py`` and
-``lib/reference_gpt.py`` are written for GPT's keys and GPT's two cache
-leaves, and a ``model_config`` change may edit none of them.  So this file
-repeats ``serve.run``'s loop — the same window, ramp, whole-tick
-``serve_tok_s`` and checks, to the letter — and has its own: the engine's
-construction (through the public entry points), the warm-up for a table
-whose widest bucket is not a power of two, each tick's pack taken from the
-``tick`` event's ``rows`` (not rebuilt, as ``serve.traced_rows`` does), the
-expert counters, and ``correct`` against ``reference_pangu_moe``.  ROADMAP
-has the item that folds both loops into one.
+``lib/program.py``, ``lib/weights.py`` and ``lib/reference_gpt.py`` are
+written for GPT's keys and GPT's two cache leaves.  The loop, the window,
+the ramp, the whole-tick ``serve_tok_s`` and the pack taken from the
+``tick`` event's ``rows`` are ``lib/serve.py``'s (``offer``,
+``whole_ticks``, ``packed_rows``, ``note_rounds``); this file has its own:
+the engine's construction (through the public entry points), the warm-up
+for a table whose widest bucket is not a power of two, the expert
+counters, and ``correct`` against ``reference_pangu_moe``.
 """
 
 import gc
@@ -19,9 +17,7 @@ import time
 
 import numpy as np
 
-from . import (harness, program, reference_pangu_moe, schedule, serve,
-               stats, weights_pangu)
-from .serve import Live
+from . import harness, program, reference_pangu_moe, serve, weights_pangu
 
 
 def pangu_config(cfg, **extra):
@@ -109,85 +105,20 @@ def run(ctx):
              f"{time.monotonic() - ctx.t_start:.1f}s since start; "
              f"{weights_pangu.param_count(cfg)} parameters")
 
-    sched = schedule.build_schedule(traffic, ctx.seconds)
-    prompts = schedule.prompt_tokens(sched, ctx.seed, cfg["vocab_size"])
-    ctx.note(f"schedule digest {schedule.digest(sched)} "
-             f"requests {len(sched)} seed {ctx.seed}")
-    ramp, drain = traffic.get("ramp_s", 0.0), traffic.get("drain_s", 0.0)
-
-    now = time.monotonic
-    by_rid = {}
-
-    def on_token(rid, token, done):
-        r = by_rid[rid]
-        if token is None:           # preempted: the stream starts over
-            r.tokens, r.times = [], []
-            r.replays += 1
-            return
-        r.tokens.append(int(token))
-        r.times.append(now())
-
-    t_begin = now()
-    w_open, w_close = t_begin + ramp, t_begin + ramp + ctx.seconds
-    live = [Live(w_open + s.due_s, p, s.output_len)
-            for s, p in zip(sched, prompts)]
-    in_window = [r for r in live if w_open <= r.due < w_close]
-    misses0 = eng.metrics()["compile_misses"]
-    nxt, opened, trace_at = 0, False, w_close - ctx.trace_s
-    while True:
-        t = now()
-        if not opened and t >= w_open:
-            opened = True
-            ctx.open_window(t)
-        if ctx.trace and not ctx.tracing and t >= trace_at and t < w_close:
-            ctx.start_trace()
-        if nxt < len(live) and live[nxt].due <= t:
-            with ctx.span("add_requests"):
-                while nxt < len(live) and live[nxt].due <= t:
-                    r = live[nxt]
-                    r.injected = now()
-                    r.rid = eng.add_request(r.prompt, r.out_len,
-                                            on_token=on_token)
-                    by_rid[r.rid] = r
-                    nxt += 1
-        if t >= w_close:
-            if ctx.tracing:
-                ctx.stop_trace()
-            waiting = [r for r in in_window if not r.tokens]
-            if not waiting or t >= w_close + drain:
-                break
-        if eng.pending():
-            with ctx.span("engine_step"):
-                eng.step()
-        else:
-            pause = (live[nxt].due - now()) if nxt < len(live) else 0.001
-            time.sleep(min(max(pause, 0.0), 0.001))
-    t_end = now()
-    if traffic["arrival"] == "backlog":     # it must never drain
-        ctx.check("backlog_requests_left_at_close",
-                  sum(1 for r in live if len(r.tokens) < r.out_len), None,
-                  at_least=1)
-    ctx.close_window(compiles=eng.metrics()["compile_misses"] - misses0)
+    live, in_window, (t_begin, w_open, w_close, t_end) = serve.offer(
+        ctx, eng, cfg["vocab_size"])
 
     # ------------------------------------------------------ end to end --
     ttft = [(r.times[0] - r.due) * 1e3 if r.tokens else math.inf
             for r in in_window]
-    ticks = [dict(e, end=tracer.t0 + e["ts"],
-                  start=tracer.t0 + e["ts"] - e["dur_s"])
-             for e in tracer.events("tick") if e.get("budget_used")
-             and tracer.t0 + e["ts"] - e["dur_s"] >= t_begin]
-    # whole ticks only: from the end of the tick in flight when the
-    # window opened to the end of the one in flight when it closed
-    ends = [k["end"] for k in ticks]
-    a = min([e for e in ends if e >= w_open], default=None)
-    b = min([e for e in ends if e >= w_close], default=max(ends, default=0))
-    counted = [k for k in ticks if a is not None and a < k["end"] <= b]
-    e2e = {"serve_tok_s": (sum(k["budget_used"] for k in counted) / (b - a)
+    ticks, counted, span_s = serve.whole_ticks(tracer, t_begin, w_open,
+                                               w_close)
+    e2e = {"serve_tok_s": (sum(k["budget_used"] for k in counted) / span_s
                            if counted else None)}
     failed = sum(1 for x in ttft if math.isinf(x))
     ctx.note(f"requests due in window {len(in_window)} unserved {failed} "
              f"ticks_counted {len(counted)} span_s "
-             f"{(b - a) if counted else 0:.3f} end_after_close_s "
+             f"{span_s:.3f} end_after_close_s "
              f"{t_end - w_close:.3f}")
 
     # -------------------------------------------------- what readers read --
@@ -223,19 +154,7 @@ def run(ctx):
              f"expert of a tick "
              f"{max((k.get('expert_rows_max', 0) for k in in_win), default=0)}"
              f"; at the engine's start {tracer.events('cache')}")
-    for kind, some in (("with a chunk", [k for k in counted
-                                          if k.get("prefill_tokens")]),
-                       ("decode only", [k for k in counted
-                                        if not k.get("prefill_tokens")])):
-        ms = sorted(k["dur_s"] * 1e3 for k in some)
-        phases = {p: sum(k["phases"].get(p, 0.0) for k in some)
-                  for p in (some[0]["phases"] if some else ())}
-        ctx.note(f"counted rounds {kind}: {len(some)}, rows "
-                 f"{sum(k['budget_used'] for k in some)}, "
-                 f"{sum(ms) / 1e3:.3f}s, ms p50 "
-                 f"{stats.percentile(ms, 50)} max {ms[-1] if ms else None}; "
-                 "seconds by phase " + ", ".join(
-                     f"{p} {v:.3f}" for p, v in phases.items()))
+    serve.note_rounds(ctx, counted)
     if ctx.trace:
         obs["latent_ticks"] = {k["tick"]: packed_rows(k) for k in ticks}
     ctx.read_memory()
@@ -256,10 +175,11 @@ def run(ctx):
 
 
 def packed_rows(tick):
-    """[(real rows, keys the last of them attends)] per sequence of one
-    ``tick`` event, as the engine recorded the pack: a first chunk's rows
-    include the bucket's left-pad rows, which attend nothing."""
-    return [(min(n, kv), kv) for _, n, kv in tick["rows"] if kv > 0]
+    """``serve.packed_rows`` as this cell's roofline has counted since PR
+    28: a first chunk's left-pad rows taken out, a decode row's keys as
+    the engine states them (its bucket's pad positions, under 16 of 4 k
+    and more, among them)."""
+    return serve.packed_rows(tick, {})
 
 
 def check_served(ctx, cfg, params, done):
@@ -288,7 +208,10 @@ def check_served(ctx, cfg, params, done):
               ctx.limits["served_logit_gap"])
     ctx.check("route_near_tie_share", got["near"] / got["tokens"],
               ctx.limits["route_near_tie_share"])
-    ctx.note(f"served_mean_gap {got['mean']!r} widest_at_a_near_tie "
+    # a widest gap at a margin just over the epsilon is a route that the
+    # program's rounding flipped, not a wrong token (PERF.md, PR 32)
+    ctx.note(f"served_mean_gap {got['mean']!r} widest_gap_at_route_margin "
+             f"{got['widest_margin']!r} widest_at_a_near_tie "
              f"{got['widest_near']!r} smallest_margin {got['margin_min']!r} "
              f"(printed, not compared)")
     if ctx.control:
@@ -339,13 +262,14 @@ def served_gap(cfg, params, requests, eps, lower=None, pad_to=1024):
         gaps = jnp.where(served, logits.max(-1) - got, 0.0)
         near = served & (margin < eps)
         far = jnp.where(near, 0.0, gaps)
-        return (far.max(), far.sum(), near.sum(),
+        return (far.max(), margin[jnp.argmax(far)], far.sum(), near.sum(),
                 jnp.where(near, gaps, 0.0).max(),
                 jnp.where(served, margin, jnp.inf).min(),
                 jnp.where(served, moved, jnp.nan))
 
     fn = jax.jit(one)
     widest = summed = near = widest_near = 0.0
+    widest_margin = None
     total, margin_min, moved = 0, math.inf, []
     for r in requests:
         served = list(r.tokens)
@@ -358,8 +282,11 @@ def served_gap(cfg, params, requests, eps, lower=None, pad_to=1024):
         toks[lo:lo + len(served)] = served
         ids = np.asarray(ids + [0] * (L - len(ids)), np.int32)
         t0 = time.monotonic()
-        g, gsum, n, gn, mm, mv = fn(params, jnp.asarray(ids), start,
-                                    jnp.asarray(toks), lo, lo + len(served))
+        g, gm, gsum, n, gn, mm, mv = fn(
+            params, jnp.asarray(ids), start, jnp.asarray(toks), lo,
+            lo + len(served))
+        if float(g) > widest or widest_margin is None:
+            widest_margin = float(gm)
         widest, summed = max(widest, float(g)), summed + float(gsum)
         print(f"[bench] reference over {L} positions "
               f"({len(served)} served): {time.monotonic() - t0:.1f}s",
@@ -372,6 +299,6 @@ def served_gap(cfg, params, requests, eps, lower=None, pad_to=1024):
     moved = moved[~np.isnan(moved)]
     return {"widest": widest, "mean": summed / max(total - near, 1),
             "tokens": total, "near": near, "widest_near": widest_near,
-            "margin_min": margin_min,
+            "widest_margin": widest_margin, "margin_min": margin_min,
             "margin_moved": [float(np.percentile(moved, q))
                              for q in (50, 99, 100)]}

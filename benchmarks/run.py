@@ -11,7 +11,8 @@ its driver (``benchmarks/drivers/<driver>.py``); each per-layer metric is
 later cell, mix, metric or reader is new files and no edit here.
 
 The last line of standard output is the result: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``.  Without a
+``failed``, ``metrics``, ``device``, traced ``breakdown``, and ``checks``
+(every number compared, beside its limit).  Without a
 TPU of enough chips the run exits 1 and prints no result; ``--rehearse``
 runs tiny shapes on the CPU to prove the control flow and prints no
 result line either.
@@ -129,8 +130,31 @@ def main(argv=None):
                           at_least=1)
                 continue
             metrics[spec["name"]] = {"value": value, "unit": spec["unit"]}
+    line = result_line(ctx, result, metrics, device, reduction)
+    for name, value in sorted(e2e.items()):
+        print(f"[bench] {name} {value}", flush=True)
+    # what was compared, once more where a refused run's record keeps it:
+    # the end of standard error, and `checks` at the end of the line
+    for name, c in line["checks"].items():
+        print(f"[bench] check {name}: {json.dumps(c, default=float)}",
+              file=sys.stderr, flush=True)
+    if args.rehearse:
+        print("[bench] rehearsal: " + json.dumps(
+            {"correct": line["correct"], "metrics": sorted(metrics),
+             "checks": line["checks"]}, default=float), flush=True)
+        return 0
+    print(json.dumps(line, default=float), flush=True)
+    return 0
+
+
+def result_line(ctx, result, metrics, device, reduction=None):
+    """The run's last line.  ``correct`` is the conjunction of the run's
+    checks, and ``checks``, last on the line, is each of them by the name
+    ``ctx.check`` printed: its value, its ``limit`` (or ``at_least``) and
+    whether it held, so that a refused run's record says which one."""
+    checks = ctx.checked()
     device["memory_peak_bytes"] = ctx.memory_peak
-    line = {"correct": all(ok for *_, ok in ctx.checks),
+    line = {"correct": all(c["ok"] for c in checks.values()),
             "attempted": result["attempted"], "failed": result["failed"],
             "metrics": metrics, "device": device}
     if reduction is not None:
@@ -138,15 +162,8 @@ def main(argv=None):
         device["window_s"] = reduction.window_s
         line["breakdown"] = {"device_ops": reduction.top_ops(10),
                              "idle_gaps": reduction.idle_gaps(10)}
-    for name, value in sorted(e2e.items()):
-        print(f"[bench] {name} {value}", flush=True)
-    if args.rehearse:
-        print("[bench] rehearsal: " + json.dumps(
-            {"correct": line["correct"], "metrics": sorted(metrics),
-             "checks": [[n, v] for n, v, *_ in ctx.checks]}), flush=True)
-        return 0
-    print(json.dumps(line), flush=True)
-    return 0
+    line["checks"] = checks
+    return line
 
 
 if __name__ == "__main__":

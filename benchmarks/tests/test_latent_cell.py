@@ -33,10 +33,10 @@ def rehearse(capsys):
 def test_a_sound_rehearsal_is_correct(capsys):
     result, out = rehearse(capsys)
     assert result["correct"] is True, out
-    assert {n for n, _ in result["checks"]} == {
+    assert list(result["checks"]) == [
         "backlog_requests_left_at_close", "served_logit_gap",
         "route_near_tie_share", "compiles_in_window",
-        "tracer_events_dropped"}
+        "tracer_events_dropped"]
     assert "expert pairs routed in the window" in out
 
 

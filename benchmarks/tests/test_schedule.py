@@ -5,7 +5,25 @@ import pytest
 
 from benchmarks.lib import harness, schedule, stats
 
-SERVING = ["chat-open", "docs-backlog"]
+SERVING = ["chat-open", "docs-backlog", "longdocs-backlog"]
+# a backlog cell: its traffic file, the backlog_tokens it had until PR 32,
+# the requests that gave (all that a window reaches at the accepted
+# rates), their digest, the configuration's vocabulary, and the rows/s it
+# takes to drain the backlog inside the ramp and the window.  docs:
+# 2,500,792 tokens over 4 + 45 s = 51.0 k rows/s, over what the chip's
+# peaks allow the deployment (of 80 rounds 40 carry a 512-row chunk,
+# 1.35 TFLOP of products + ~1 ms of attention = 7.8 ms at 197 TFLOP/s,
+# and 40 carry 14 decode rows and read 2.6 GB of weights, 3.2 ms at 819
+# GB/s: 21,040 rows in 0.44 s, 48 k rows/s; with the KV those rounds read
+# counted too, 32 k).  longdocs: 2,408,466 over 8 + 45 s = 45.4 k rows/s
+# against ~19 k if every 2,048-row tick ran at the peaks (35 ms of
+# products + 74 ms of absorbed attention).  PERF.md section 4.
+BACKLOGS = {
+    "docs-backlog": dict(old_tokens=100_000, n=64, head="d70d17c84f40a383",
+                         vocab=50257, rate=50_000),
+    "longdocs-backlog": dict(old_tokens=600_000, n=58,
+                             head="671250684be69318", vocab=19200,
+                             rate=45_000)}
 
 
 def traffic(name):
@@ -76,6 +94,35 @@ def test_backlog_is_due_before_the_ramp_and_never_drains():
         longest = json.load(f)["run_seconds"]
     served = tr["recorded_serve_tok_s"] * (tr["ramp_s"] + longest)
     assert tokens >= 1.2 * served
+
+
+@pytest.mark.parametrize("name", sorted(BACKLOGS))
+def test_a_longer_backlog_starts_with_the_shorter_one(name):
+    """The requests a window reaches are the ones it reached before the
+    backlog was lengthened, to the token, for any --seed."""
+    tr, was = traffic(name), BACKLOGS[name]
+    n, vocab = was["n"], was["vocab"]
+    long = schedule.build_schedule(tr, 45.0)
+    short = schedule.build_schedule(
+        dict(tr, backlog_tokens=was["old_tokens"]), 45.0)
+    assert len(short) == n < len(long) and long[:n] == short
+    assert schedule.digest(long[:n]) == was["head"]
+    for seed in (7, 2 ** 31 + 11):
+        assert schedule.prompt_tokens(long, seed, vocab)[:n] == \
+            schedule.prompt_tokens(short, seed, vocab)
+
+
+@pytest.mark.parametrize("name", sorted(BACKLOGS))
+def test_no_engine_the_chip_allows_drains_a_backlog(name):
+    """A backlog cell that drains measures an idle engine, and its run is
+    refused as not correct: an edit to a backlog, a ramp or the window
+    that lets a fast engine drain it fails here and not on the chip."""
+    tr = traffic(name)
+    with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
+        window = json.load(f)["run_seconds"]
+    sched = schedule.build_schedule(tr, window)
+    tokens = sum(s.prompt_len + s.output_len for s in sched)
+    assert tokens / (tr["ramp_s"] + window) >= BACKLOGS[name]["rate"]
 
 
 def test_percentile_interpolates_and_counts_failures_as_infinite():
