@@ -19,8 +19,8 @@ row_pos / pads, same trash block 0), with two differences that matter.
 
 The keys have no head axis, so heads are rows of the MXU's left operand:
 the scores are ``(M, R) x (R, keys)`` + ``(M, Dr) x (Dr, keys)`` and the
-output ``(M, keys) x (keys, R)``, where the per-head kernel multiplies row
-by row on the VPU.  And ``M`` is more than one pack row's heads wherever
+output ``(M, keys) x (keys, R)``, where the per-head kernel's ``M`` is a
+run's rows alone.  And ``M`` is more than one pack row's heads wherever
 the pack allows it: a grid step takes ``rows_per_step`` consecutive pack
 rows, and if they are one sequence at consecutive kv positions (the
 inside of a prefill chunk) they share every key block and go through the

@@ -61,6 +61,7 @@ from .telemetry import (PHASE_ADMIT, PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC,
 from .models._decode import (PagedKV, apply_repetition_penalty,
                              build_pools, greedy_verify, seed_presence,
                              suppress_eos, suppress_eos_rows)
+from .ops.ragged_paged_attention import grouped_rows
 
 __all__ = ["PagedContinuousBatchingEngine",
            "RaggedPagedContinuousBatchingEngine"]
@@ -1554,7 +1555,8 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         chunk ``[rid, m, last real position + 1]`` (``m`` counts the
         bucket's left-pad rows, which the program runs too) — so the rows
         sum to ``budget_used`` whatever a preemption or a dry pool did to
-        the order."""
+        the order; ``grouped_rows`` counts those that lie in runs long
+        enough for the "kv" kernel's MXU path."""
         K = self.K
         rows = []
         for slot in dec_slots:
@@ -1570,6 +1572,11 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             prefill_tokens=int(sum(fill_adv.values())),
             budget_used=sum(r[1] for r in rows),
             token_budget=self.token_budget, rows=rows)
+        if self.cache_spec.layout == "kv":
+            # how many of them ops/ragged_paged_attention.py takes through
+            # the MXU as one operand with their neighbours
+            self._tick_note["grouped_rows"] = grouped_rows(
+                rows, self.token_budget)
 
     def _advance_fills(self, fill_adv, first_tok):
         """After a step: move every filler on by its chunk; a prompt that

@@ -19,6 +19,7 @@ import paddle_tpu as paddle
 from paddle_tpu import telemetry
 from paddle_tpu.jit.bucketing import select_bucket
 from paddle_tpu.models.gpt import GPTConfig, GPTModel
+from paddle_tpu.ops.ragged_paged_attention import MIN_RUN, grouped_rows
 from paddle_tpu.serving import (ContinuousBatchingEngine,
                                 RaggedPagedContinuousBatchingEngine)
 from paddle_tpu.telemetry import PHASES, Tracer
@@ -28,8 +29,9 @@ REGIONS = ("embed", "layers", "attn", "mlp", "kv_write", "head", "optimizer",
 PROMPTS = [[5, 17, 3], [40, 2], [9, 9, 9, 9, 9, 1], [61], [8, 30, 12, 4],
            [77, 13, 2, 5, 6, 7, 8]]
 BUDGETS = [10, 4, 7, 12, 3, 8]
-# engine geometry and requests per scenario; the last two are the cases the
-# benchmark's rebuilt rows (serve.traced_rows) give up on
+# engine geometry and requests per scenario; a preemption and a dry pool are
+# the cases a pack rebuilt from request events gives up on (the benchmark
+# reads the tick's own ``rows``: serve.packed_rows)
 SCENARIOS = {
     "plain": (dict(), PROMPTS, BUDGETS),
     "preemption": (dict(max_slots=2, num_blocks=8, prompt_buckets=[8],
@@ -99,6 +101,11 @@ def test_tick_carries_number_phases_and_rows(served, scenario):
         if rows:    # a round that ran the program went through all five
             assert set(e["phases"]) == set(PHASES)
             assert len({rid for rid, _, _ in rows}) == len(rows)
+            # the kernel's MXU runs: a prefill chunk of MIN_RUN rows or
+            # more, never a decode row or a verify chunk
+            assert e["grouped_rows"] == sum(
+                n for _, n, _ in rows if n >= MIN_RUN) \
+                == grouped_rows(rows, eng.token_budget)
         for rid, n, kv_end in rows:
             assert n >= 1 and 0 <= kv_end <= eng.max_len
             rows_of.setdefault(rid, []).append((n, kv_end))
@@ -106,6 +113,17 @@ def test_tick_carries_number_phases_and_rows(served, scenario):
             - e.get("prefill_tokens", 0)        # the verify chunks' K rows
         assert extra % eng.K == 0 and (scenario == "spec" or extra == 0)
     assert set(rows_of) == set(range(len(prompts)))     # all were packed
+
+
+def test_pack_is_recorded_only_with_a_tracer(model_and_params, served):
+    model, params = model_and_params
+    eng = _ragged(model, params)
+    eng.add_request(PROMPTS[2], 3)
+    eng.run_to_completion(max_ticks=50)
+    assert "grouped_rows" not in eng._tick_note \
+        and "rows" not in eng._tick_note
+    assert any(e["grouped_rows"] for e in served["plain"][1].events("tick")
+               if e.get("rows"))
 
 
 def test_rows_survive_a_forced_preemption(served):
