@@ -34,7 +34,9 @@ class CacheSpec(NamedTuple):
 
     ``pools``: a tuple of entries in the order ``decode_ragged`` takes and
     returns them, each a pytree of ``CacheLeaf`` (an int8 K plane is a
-    ``(values, scales)`` pair of leaves).  ``layout``: "kv" for one K and
+    ``(values, scales)`` pair of leaves; a latent stack with a lightning
+    indexer a ``(latent row, indexer key)`` pair, two leaves on the one
+    block table).  ``layout``: "kv" for one K and
     one V entry per head and layer (what the tiered KV store and the
     bucketed paged programs are written for); any other name is the
     model's own.  ``tick_stats``: names of the int32 counters
@@ -229,6 +231,56 @@ def ragged_latent_attention(q_abs, q_r, pool, table, row_seq, row_pos,
     return ragged_latent_attention_ref(q_abs, q_r, pool, table, row_seq,
                                        row_pos, pad_lens, scale=scale,
                                        layer=layer)
+
+
+def ragged_index_select(q_idx, w_idx, pool, table, row_seq, row_pos,
+                        pad_lens, *, k, layer=None):
+    """A lightning indexer's choice for a flattened ragged pack over ONE
+    layer's pool of indexer keys (NB+1, bs, D) — or a stack's (L, NB+1,
+    bs, D) and ``layer``: the index scores (T, C * bs) float32 of q_idx
+    (T, nh, D) with head weights w_idx (T, nh) (region ``indexer``,
+    ops/ragged_index_scores.py), and the threshold (T, >= 2) int32 that
+    stands for each row's ``min(k, context)`` largest, exactly (region
+    ``select``, ops/index_select.py).  Dispatched like
+    ``ragged_attention``."""
+    from ..ops.index_select import (select_threshold_ref,
+                                    select_threshold_rows)
+    from ..ops.ragged_index_scores import (ragged_index_scores_ref,
+                                           ragged_index_scores_rows)
+    use, interp = _pallas_dispatch()
+    with jax.named_scope("indexer"):
+        if use:
+            scores = ragged_index_scores_rows(
+                q_idx, w_idx, pool, table, row_seq, row_pos, layer=layer,
+                interpret=interp)
+        else:
+            scores = ragged_index_scores_ref(
+                q_idx, w_idx, pool, table, row_seq, row_pos, layer=layer)
+    if use:
+        thr = select_threshold_rows(scores, row_seq, row_pos, pad_lens, k=k,
+                                    interpret=interp)
+    else:
+        thr = select_threshold_ref(scores, row_seq, row_pos, pad_lens, k=k)
+    return scores, thr
+
+
+def ragged_sparse_latent_attention(q_abs, q_r, pool, scores, thr, table,
+                                   row_seq, row_pos, pad_lens=None, *,
+                                   scale, layer=None):
+    """``ragged_latent_attention`` over the kv positions that ``(scores,
+    thr)`` of ``ragged_index_select`` name, and no others
+    (ops/ragged_sparse_latent_attention.py)."""
+    from ..ops.ragged_sparse_latent_attention import (
+        ragged_sparse_latent_attention_ref,
+        ragged_sparse_latent_attention_rows)
+    use, interp = _pallas_dispatch()
+    if use:
+        return ragged_sparse_latent_attention_rows(
+            q_abs, q_r, pool, scores, thr, table, row_seq, row_pos,
+            pad_lens, scale=scale, layer=layer, interpret=interp)
+    return ragged_sparse_latent_attention_ref(
+        q_abs, q_r, pool, scores, thr, table, row_seq, row_pos, pad_lens,
+        scale=scale, layer=layer)
 
 
 def ragged_write(pool, chunk, table, row_seq, row_pos, layer=None):

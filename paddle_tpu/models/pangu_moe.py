@@ -25,10 +25,30 @@ With ``experts_held = range(n_routed_experts)`` it is the whole layer.
 
 The multi-token-prediction module of the published model is not built: it
 does not enter the main model's logits.
+
+DeepSeek-V3.2-Exp (``model_type: deepseek_v32``) is the same class under
+its own published keys, each a switch the Pangu file lacks:
+``sandwich_norm`` false (no norm on a sublayer's output: two norms a
+layer); ``rope_scaling`` of type "yarn" (the rotary frequencies blended
+per frequency, and the softmax scale times ``m**2``); ``n_group`` /
+``topk_group`` / ``topk_method`` "noaux_tc" (group-limited routing with a
+selection bias, ``ops/moe.route_sigmoid_topk``); ``index_topk`` /
+``index_n_heads`` / ``index_head_dim`` (a lightning indexer in every
+layer: its own projections of the MLA's ``c_q`` and normed input, a
+LayerNorm on its one shared key, rotary on the first
+``qk_rope_head_dim`` of its columns, signed head weights; DeepSeek-V3.2-Exp
+report, DSA).  With an indexer a stack caches TWO leaves on one table —
+the latent row and the indexer's key, ``index_head_dim`` numbers: one
+128-lane tile — and a row attends its ``min(index_topk, context)``
+highest-scored positions, exactly (``_decode.ragged_index_select``,
+``ragged_sparse_latent_attention``); a program whose table holds no more
+than ``index_topk`` positions attends everything through the accepted
+kernel and only writes the indexer's keys.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -38,7 +58,8 @@ from ..core.tensor import Parameter
 from ..nn.layer.base import Layer
 from ..ops.moe import gated_mlp, held_experts_ffn, route_sigmoid_topk
 from ._decode import (CacheLeaf, CacheSpec, CausalDecoderMixin, build_pools,
-                      ragged_latent_attention, ragged_write)
+                      ragged_index_select, ragged_latent_attention,
+                      ragged_sparse_latent_attention, ragged_write)
 
 _MLA = ("ln1_w", "q_a_w", "q_a_norm_w", "q_b_w", "kv_a_w", "kv_a_norm_w",
         "kv_b_w", "o_w", "ln2_w", "ln3_w", "ln4_w")
@@ -47,7 +68,41 @@ _STACKS = {
     "moe": _MLA + ("router_w", "e_gate_w", "e_up_w", "e_down_w",
                    "s_gate_w", "s_up_w", "s_down_w"),
 }
+_SANDWICH = ("ln2_w", "ln4_w")      # only under ``sandwich_norm``
+_INDEXER = ("idx_q_b_w", "idx_k_w", "idx_k_norm_w", "idx_k_norm_b",
+            "idx_w_w")              # only with ``index_topk``
+_ROUTER_BIAS = "router_bias"        # only with ``topk_method: noaux_tc``
 TICK_STATS = ("expert_rows", "expert_rows_max", "expert_pairs")
+INDEX_STATS = ("index_candidates", "index_selected")
+
+
+def yarn_inv_freq(D, theta, factor, original_max_position_embeddings,
+                  beta_fast=32, beta_slow=1, **_):
+    """The ``D / 2`` rotary frequencies under YaRN (arXiv:2309.00071, as
+    DeepSeek's ``precompute_freqs_cis`` writes it): frequency ``j`` is the
+    blend ``f_j / factor * r_j + f_j * (1 - r_j)`` of the interpolated and
+    the plain ``f_j = theta ** (-2j / D)``, ``r`` the linear ramp from 0
+    at the correction dim of ``beta_fast`` rotations over the original
+    context (rounded down) to 1 at that of ``beta_slow`` (rounded up)."""
+    def correction_dim(rotations):
+        return D * math.log(original_max_position_embeddings
+                            / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), D - 1)
+    if low == high:
+        high += 0.001
+    f = [theta ** (-2.0 * j / D) for j in range(D // 2)]
+    ramp = [min(max((j - low) / (high - low), 0.0), 1.0)
+            for j in range(D // 2)]
+    return [fj / factor * r + fj * (1.0 - r) for fj, r in zip(f, ramp)]
+
+
+def yarn_mscale(factor, mscale_all_dim=1.0, **_):
+    """``m = 0.1 * mscale_all_dim * ln(factor) + 1``: the softmax scale is
+    multiplied by ``m**2``."""
+    return 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 \
+        else 1.0
 
 
 class PanguMoeConfig:
@@ -62,7 +117,11 @@ class PanguMoeConfig:
                  norm_topk_prob=True, rms_norm_eps=1e-5,
                  rope_theta=25600000.0, max_position_embeddings=131072,
                  initializer_range=0.02, compute_dtype="bfloat16",
-                 experts_held: Optional[range] = None):
+                 experts_held: Optional[range] = None,
+                 sandwich_norm=True, rope_scaling: Optional[dict] = None,
+                 n_group=1, topk_group=1, topk_method=None,
+                 index_topk: Optional[int] = None, index_n_heads=64,
+                 index_head_dim=128):
         self.vocab_size = vocab_size
         self.hidden_size = hidden_size
         self.num_hidden_layers = num_hidden_layers
@@ -95,6 +154,26 @@ class PanguMoeConfig:
         if not 0 <= first_k_dense_replace <= num_hidden_layers:
             raise ValueError("first_k_dense_replace outside the stack")
         self.experts_held = held
+        self.sandwich_norm = bool(sandwich_norm)
+        if rope_scaling is not None and \
+                rope_scaling.get("type", "yarn") != "yarn":
+            raise ValueError(f"rope_scaling {rope_scaling.get('type')!r}: "
+                             f"only yarn is written")
+        self.rope_scaling = rope_scaling
+        if n_routed_experts % n_group or not 1 <= topk_group <= n_group:
+            raise ValueError("n_group must divide n_routed_experts and "
+                             "topk_group lie in [1, n_group]")
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
+        if topk_method not in (None, "noaux_tc"):
+            raise ValueError(f"topk_method {topk_method!r}: only noaux_tc "
+                             f"(a selection bias) is written")
+        self.topk_method = topk_method
+        self.index_topk = None if index_topk is None else int(index_topk)
+        self.index_n_heads = int(index_n_heads)
+        self.index_head_dim = int(index_head_dim)
+        if self.index_topk is not None \
+                and not qk_rope_head_dim <= index_head_dim:
+            raise ValueError("the indexer's rotary columns exceed its head")
 
     @property
     def num_layers(self):
@@ -119,25 +198,39 @@ class PanguMoeConfig:
         it in and out of every kernel call)."""
         return -(-self.latent_width // 128) * 128
 
+    def stack_names(self, stack):
+        """The parameter names of one stack ("dense", "moe") under this
+        configuration's switches, without the stack's prefix."""
+        names = _STACKS[stack]
+        if not self.sandwich_norm:
+            names = tuple(n for n in names if n not in _SANDWICH)
+        if self.index_topk is not None:
+            names += _INDEXER
+        if stack == "moe" and self.topk_method == "noaux_tc":
+            names += (_ROUTER_BIAS,)
+        return names
+
 
 class PanguMoeModel(CausalDecoderMixin, Layer):
-    """Two stacks of sandwich-norm MLA blocks; parameters stacked over the
-    layers of their stack (``dense_*`` / ``moe_*``)."""
+    """Two stacks of MLA blocks; parameters stacked over the layers of
+    their stack (``dense_*`` / ``moe_*``)."""
 
     def __init__(self, config: PanguMoeConfig):
         super().__init__()
         self.config = c = config
         from ..nn.initializer import Normal
         for name, (shape, init) in self.param_table(c).items():
-            data = jnp.ones(shape, jnp.float32) if init == "ones" \
+            data = jnp.full(shape, float(init == "ones"), jnp.float32) \
+                if isinstance(init, str) \
                 else Normal(0.0, init)(list(shape), "float32")
             self.add_parameter(name, Parameter(data, name=name))
 
     @staticmethod
     def param_table(c: PanguMoeConfig):
-        """name -> (shape, standard deviation | "ones"): the program's
-        parameter dictionary (``initializer_range`` normal weights, norms
-        at one; no bias anywhere)."""
+        """name -> (shape, standard deviation | "ones" | "zeros"): the
+        program's parameter dictionary (``initializer_range`` normal
+        weights, norm scales at one, the indexer's LayerNorm bias and the
+        router's selection bias at zero)."""
         H, nh = c.hidden_size, c.num_attention_heads
         qk = c.qk_nope_head_dim + c.qk_rope_head_dim
         std = c.initializer_range
@@ -166,30 +259,46 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                     "s_gate_w": ((H, Fs), std), "s_up_w": ((H, Fs), std),
                     "s_down_w": ((Fs, H), std)},
         }
+        Di, nhi = c.index_head_dim, c.index_n_heads
+        mla.update({        # the indexer; its LayerNorm has scale and bias
+            "idx_q_b_w": ((c.q_lora_rank, nhi * Di), std),
+            "idx_k_w": ((H, Di), std), "idx_k_norm_w": ((Di,), "ones"),
+            "idx_k_norm_b": ((Di,), "zeros"), "idx_w_w": ((H, nhi), std)})
+        # the selection bias is a trained buffer: a checkpoint brings it
+        own["moe"][_ROUTER_BIAS] = ((c.n_routed_experts,), "zeros")
         layers = {"dense": c.first_k_dense_replace,
                   "moe": c.num_expert_layers}
         table = {"wte": ((c.vocab_size, H), std),
                  "lm_head": ((H, c.vocab_size), std),
                  "norm_f_w": ((H,), "ones")}
         for stack, n in layers.items():
-            for name, (shape, init) in {**mla, **own[stack]}.items():
+            every = {**mla, **own[stack]}
+            for name in c.stack_names(stack):
+                shape, init = every[name]
                 table[f"{stack}_{name}"] = ((n,) + shape, init)
         return table
 
-    @staticmethod
-    def stacked_param_names(stack: Optional[str] = None):
+    def stacked_param_names(self, stack: Optional[str] = None):
         """Parameters with a leading layer axis: of one stack ("dense",
         "moe"), or of both."""
-        stacks = _STACKS if stack is None else {stack: _STACKS[stack]}
-        return [f"{s}_{n}" for s, names in stacks.items() for n in names]
+        return [f"{s}_{n}" for s in ((stack,) if stack else _STACKS)
+                for n in self.config.stack_names(s)]
 
     def cache_spec(self) -> CacheSpec:
         c = self.config
         dt = str(jnp.dtype(c.compute_dtype))
+        stacks = (c.first_k_dense_replace, c.num_expert_layers)
+        if c.index_topk is None:
+            return CacheSpec(
+                pools=tuple(CacheLeaf(n, (c.latent_row,), dt)
+                            for n in stacks),
+                layout="latent", tick_stats=TICK_STATS)
+        # a stack's entry is a pair: the latent row and the indexer's key
         return CacheSpec(
-            pools=(CacheLeaf(c.first_k_dense_replace, (c.latent_row,), dt),
-                   CacheLeaf(c.num_expert_layers, (c.latent_row,), dt)),
-            layout="latent", tick_stats=TICK_STATS)
+            pools=tuple((CacheLeaf(n, (c.latent_row,), dt),
+                         CacheLeaf(n, (c.index_head_dim,), dt))
+                        for n in stacks),
+            layout="latent", tick_stats=TICK_STATS + INDEX_STATS)
 
     # ------------------------------------------------------ pure functions
 
@@ -204,8 +313,13 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         at positions ``pos`` (broadcast against x's leading axes but the
         last two: x is (..., heads, D) and pos (...,))."""
         D = x.shape[-1]
-        inv = self.config.rope_theta ** (
-            -jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+        c = self.config
+        if c.rope_scaling is None:
+            inv = c.rope_theta ** (
+                -jnp.arange(0, D, 2, dtype=jnp.float32) / D)
+        else:
+            inv = jnp.asarray(yarn_inv_freq(D, c.rope_theta,
+                                            **c.rope_scaling), jnp.float32)
         ang = pos.astype(jnp.float32)[..., None, None] * inv   # (..,1,D/2)
         cos, sin = jnp.cos(ang), jnp.sin(ang)
         x32 = x.astype(jnp.float32)
@@ -214,12 +328,15 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                                -1).astype(x.dtype)
 
     def _stack(self, params, stack):
-        return {n: params[f"{stack}_{n}"] for n in _STACKS[stack]}
+        return {n: params[f"{stack}_{n}"]
+                for n in self.config.stack_names(stack)}
 
     def _mla_in(self, sl, x, pos):
         """N1 and the MLA projections of x (..., H) at logical positions
         ``pos`` (...,): q_nope (..., nh, nope), q_r (..., nh, rope) after
-        rotation, and the row to cache (..., latent_row): c_kv, k_r, zeros."""
+        rotation, and the row to cache (..., latent_row): c_kv, k_r, zeros.
+        With an indexer, what it projects follows: (q_idx, w_idx, k_idx)
+        of ``_index_in``."""
         c = self.config
         dt = x.dtype
         nh, R = c.num_attention_heads, c.kv_lora_rank
@@ -232,8 +349,36 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         c_kv = self._rms(kv[..., :R], sl["kv_a_norm_w"])
         k_r = self._rope(kv[..., None, R:], pos)[..., 0, :]
         pad = jnp.zeros(x.shape[:-1] + (c.latent_row - c.latent_width,), dt)
-        return q_nope, self._rope(q_r, pos), \
-            jnp.concatenate([c_kv, k_r, pad], -1)
+        out = (q_nope, self._rope(q_r, pos),
+               jnp.concatenate([c_kv, k_r, pad], -1))
+        if c.index_topk is not None:
+            with jax.named_scope("indexer"):
+                out += self._index_in(sl, a, c_q, pos)
+        return out
+
+    def _index_in(self, sl, a, c_q, pos):
+        """The lightning indexer's side of a row: q_idx (..., nhi, Di)
+        from the MLA's ``c_q``; w_idx (..., nhi) float32, the signed head
+        weights ``a W_w / sqrt(nhi * Di)``; k_idx (..., Di), ``LayerNorm(a
+        W_k)``, the row of the indexer's cache.  Rotary on the first
+        ``qk_rope_head_dim`` columns of q_idx and k_idx."""
+        c = self.config
+        dt = a.dtype
+        nhi, Di, Dr = c.index_n_heads, c.index_head_dim, c.qk_rope_head_dim
+        q = (c_q @ sl["idx_q_b_w"].astype(dt)).reshape(
+            a.shape[:-1] + (nhi, Di))
+        q = jnp.concatenate([self._rope(q[..., :Dr], pos), q[..., Dr:]], -1)
+        k32 = (a @ sl["idx_k_w"].astype(dt)).astype(jnp.float32)
+        mu = jnp.mean(k32, -1, keepdims=True)
+        k32 = (k32 - mu) * jax.lax.rsqrt(
+            jnp.mean((k32 - mu) ** 2, -1, keepdims=True) + 1e-6)
+        k = (k32 * sl["idx_k_norm_w"].astype(jnp.float32)
+             + sl["idx_k_norm_b"].astype(jnp.float32)).astype(dt)
+        k = jnp.concatenate(
+            [self._rope(k[..., None, :Dr], pos)[..., 0, :], k[..., Dr:]], -1)
+        w = (a @ sl["idx_w_w"].astype(dt)).astype(jnp.float32) \
+            * float(nhi * Di) ** -0.5
+        return q, w, k
 
     def _kv_b(self, sl, dt):
         """W_kvb as (R, nh, nope) for keys and (R, nh, v) for values."""
@@ -246,27 +391,36 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
     @property
     def _scale(self):
         c = self.config
-        return float(c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+        scale = float(c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+        if c.rope_scaling is not None:
+            scale *= yarn_mscale(**c.rope_scaling) ** 2
+        return scale
 
     def _mla_out(self, sl, x, o):
-        """Heads concatenated, W_o, N2, residual: o (..., nh, v)."""
-        o = o.reshape(o.shape[:-2] + (-1,))
-        return x + self._rms(o @ sl["o_w"].astype(x.dtype), sl["ln2_w"])
+        """Heads concatenated, W_o, N2 (under ``sandwich_norm``),
+        residual: o (..., nh, v)."""
+        o = o.reshape(o.shape[:-2] + (-1,)) @ sl["o_w"].astype(x.dtype)
+        return x + (self._rms(o, sl["ln2_w"]) if self.config.sandwich_norm
+                    else o)
 
     def _ffn(self, sl, x, expert: bool, valid=None):
         """N3, F, N4, residual on x (T, H); (x, rows a held expert
         computed (Eh,) or None)."""
         c = self.config
+        n4 = (lambda f: self._rms(f, sl["ln4_w"])) if c.sandwich_norm \
+            else (lambda f: f)
         with jax.named_scope("mlp"):
             m = self._rms(x, sl["ln3_w"])
             if not expert:
-                return x + self._rms(gated_mlp(
-                    m, sl["gate_w"], sl["up_w"], sl["down_w"]),
-                    sl["ln4_w"]), None
+                return x + n4(gated_mlp(
+                    m, sl["gate_w"], sl["up_w"], sl["down_w"])), None
             with jax.named_scope("router"):
+                grouped = {} if c.n_group == 1 and c.topk_method is None \
+                    else dict(bias=sl.get(_ROUTER_BIAS), n_group=c.n_group,
+                              topk_group=c.topk_group)
                 idx, w = route_sigmoid_topk(
                     m, sl["router_w"], c.num_experts_per_tok,
-                    c.routed_scaling_factor, c.norm_topk_prob)
+                    c.routed_scaling_factor, c.norm_topk_prob, **grouped)
             routed, rows = held_experts_ffn(
                 m, idx, w, sl["e_gate_w"], sl["e_up_w"], sl["e_down_w"],
                 c.experts_held.start, valid)
@@ -274,7 +428,7 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                 shared = gated_mlp(m, sl["s_gate_w"], sl["s_up_w"],
                                    sl["s_down_w"])
             f = (routed + shared.astype(jnp.float32)).astype(x.dtype)
-            return x + self._rms(f, sl["ln4_w"]), rows
+            return x + n4(f), rows
 
     def decode_logits(self, params, h):
         """Final norm and the untied head: float32 logits."""
@@ -321,15 +475,21 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         its stack's latent pools (L, NB+1, bs, W): write each row's
         latent, then attend (absorbed) — both in place in the stack, which
         the scan carries whole (sliced per layer, the scan would hold the
-        pools twice and copy a layer in and out every iteration)."""
+        pools twice and copy a layer in and out every iteration).  With an
+        indexer ``pool`` is the stack's pair (latent rows, indexer keys):
+        both are written, and where the table holds more positions than
+        ``index_topk`` a row attends the positions the indexer selects.
+        Returns (x, pool, rows a held expert computed, the selection
+        ``(scores, thr)`` or None)."""
+        c = self.config
         seq = jnp.clip(row_seq, 0, pad_lens.shape[0] - 1)
         pos = jnp.maximum(row_pos - pad_lens[seq], 0)
         w_k, w_v = self._kv_b(sl, x.dtype)
 
         def project(x, pos):
-            q_nope, q_r, latent = self._mla_in(sl, x, pos)
+            q_nope, q_r, latent, *index = self._mla_in(sl, x, pos)
             return (jnp.einsum("thd,rhd->thr", q_nope, w_k), q_r,
-                    latent), ()
+                    latent, *index), ()
 
         def finish(x, o_lat, valid):
             with jax.named_scope("attn"):
@@ -338,25 +498,67 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
             x, rows = self._ffn(sl, x, expert, valid=valid)
             return (x,), rows
 
+        chosen = None
         with jax.named_scope("attn"):
-            (q_abs, q_r, latent), _ = self._rowwise(few, project, x, pos)
-            pool = ragged_write(pool, latent, table, row_seq, row_pos,
-                                layer=layer)
-            o_lat = ragged_latent_attention(
-                q_abs, q_r, pool, table, row_seq, row_pos, pad_lens,
-                scale=self._scale, layer=layer)
+            (q_abs, q_r, latent, *index), _ = self._rowwise(
+                few, project, x, pos)
+            if not index:
+                pool = ragged_write(pool, latent, table, row_seq, row_pos,
+                                    layer=layer)
+                lat_pool = pool
+            else:
+                q_idx, w_idx, k_idx = index
+                lat_pool, idx_pool = pool
+                lat_pool = ragged_write(lat_pool, latent, table, row_seq,
+                                        row_pos, layer=layer)
+                idx_pool = ragged_write(idx_pool, k_idx, table, row_seq,
+                                        row_pos, layer=layer)
+                pool = (lat_pool, idx_pool)
+                if table.shape[1] * idx_pool.shape[2] > c.index_topk:
+                    chosen = ragged_index_select(
+                        q_idx, w_idx, idx_pool, table, row_seq, row_pos,
+                        pad_lens, k=c.index_topk, layer=layer)
+            if chosen is None:
+                o_lat = ragged_latent_attention(
+                    q_abs, q_r, lat_pool, table, row_seq, row_pos, pad_lens,
+                    scale=self._scale, layer=layer)
+            else:
+                o_lat = ragged_sparse_latent_attention(
+                    q_abs, q_r, lat_pool, *chosen, table, row_seq, row_pos,
+                    pad_lens, scale=self._scale, layer=layer)
         (x,), rows = self._rowwise(few, finish, x, o_lat, row_pos >= 0)
-        return x, pool, rows
+        return x, pool, rows, chosen
+
+    @staticmethod
+    def _selected(chosen, table, pool, lo, hi, at):
+        """The mask a block's attention applied to pack rows ``at``: the
+        indexer's choice, or every position in [lo, hi] where the program
+        is too narrow for a choice."""
+        from ..ops.index_select import selected
+        if chosen is not None:
+            return selected(chosen[0][at], chosen[1][at], lo, hi)
+        width = table.shape[1] * jax.tree.leaves(pool)[0].shape[2]
+        col = jnp.arange(width)[None, :]
+        return (col >= lo[:, None]) & (col <= hi[:, None])
 
     def decode_ragged(self, params, h, pools, table, row_seq, row_pos,
-                      pad_lens):
+                      pad_lens, selection_of=None):
         """Both stacks for one mixed ragged tick: h (1, T, H); ``pools``
-        the two latent pools of ``cache_spec()``, stacked over their
-        stack's layers.  Returns (h, pools, stats): ``stats`` int32 (3,)
-        in the order of ``TICK_STATS`` — pairs the held experts computed
+        the entries of ``cache_spec()``, one a stack, stacked over its
+        layers.  Returns (h, pools, stats): ``stats`` int32 in the order
+        of the spec's ``tick_stats`` — pairs the held experts computed
         (summed over the expert layers), the fullest single expert of any
         layer, and the pairs routed in all (real rows x top-k x expert
-        layers)."""
+        layers); with an indexer also the kv positions its rows scored
+        (``index_candidates``: context summed over real rows and layers)
+        and those they attended (``index_selected``: ``min(index_topk,
+        context)`` likewise).
+
+        ``selection_of=(first, n)`` (static) makes the indexer's choice
+        for pack rows [first, first + n) a fourth output: bool (layers,
+        n, C * bs), the mask the attention applied, dense stack first —
+        what a comparison with a reference reads; the tick itself never
+        asks."""
         c = self.config
         x = h[0]
         # a round of decode rows only has at most one real row a slot
@@ -367,24 +569,41 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         slots = pad_lens.shape[0]
         few = (slots, jnp.all(row_pos[slots:] < 0)) \
             if x.shape[0] > 2 * slots else None
-        out_pools, rows = [], None
+        seq = jnp.clip(row_seq, 0, slots - 1)
+        out_pools, rows, masks = [], None, []
         with jax.named_scope("layers"):
             for stack, pool in zip(("dense", "moe"), pools):
                 def body(carry, xs, expert=stack == "moe"):
                     sl, i = xs
-                    y, p, r = self._block_ragged(
+                    y, p, r, chosen = self._block_ragged(
                         sl, carry[0], carry[1], i, table, row_seq, row_pos,
                         pad_lens, expert, few)
+                    if selection_of is not None:
+                        at = slice(selection_of[0], sum(selection_of))
+                        r = (r, self._selected(
+                            chosen, table, p, pad_lens[seq][at],
+                            row_pos[at], at))
                     return (y, p), r
                 (x, pool), r = jax.lax.scan(
                     body, (x, pool), (self._stack(params, stack),
-                                      jnp.arange(pool.shape[0])))
+                                      jnp.arange(jax.tree.leaves(pool)[0]
+                                                 .shape[0])))
+                if selection_of is not None:
+                    r, mask = r
+                    masks.append(mask)
                 out_pools.append(pool)
                 rows = r if stack == "moe" else rows        # (Le, Eh)
-        pairs = jnp.sum(row_pos >= 0) * (c.num_experts_per_tok
-                                         * c.num_expert_layers)
-        stats = jnp.stack([jnp.sum(rows), jnp.max(rows, initial=0),
-                           pairs]).astype(jnp.int32)
+        real = row_pos >= 0
+        pairs = jnp.sum(real) * (c.num_experts_per_tok * c.num_expert_layers)
+        stats = [jnp.sum(rows), jnp.max(rows, initial=0), pairs]
+        if c.index_topk is not None:
+            ctx = jnp.where(real, row_pos - pad_lens[seq] + 1, 0)
+            stats += [jnp.sum(ctx) * c.num_hidden_layers,
+                      jnp.sum(jnp.minimum(ctx, c.index_topk))
+                      * c.num_hidden_layers]
+        stats = jnp.stack(stats).astype(jnp.int32)
+        if selection_of is not None:
+            return x[None], tuple(out_pools), stats, jnp.concatenate(masks)
         return x[None], tuple(out_pools), stats
 
     # ------------------------------------- dense cache: prefill / generate
@@ -400,9 +619,27 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
     def _embed_one(self, params, tok, t, pad_lens=None):
         return self._embed_ragged(params, tok[:, None], None, None, None)[0]
 
-    def _attend_dense(self, sl, x, cache, q_nope, q_r, t0, pad_lens):
+    def _select_dense(self, q_idx, w_idx, cache, t0, pad_lens):
+        """The indexer's choice over a dense cache of its keys (B, Lmax,
+        Di) for rows (B, k) at slots [t0, t0 + k): bool (B, k, Lmax)."""
+        from ..ops.index_select import select_threshold_ref, selected
+        B, k = q_idx.shape[:2]
+        with jax.named_scope("indexer"):
+            sc = jnp.einsum("bqhd,bkd->bqhk", q_idx, cache,
+                            preferred_element_type=jnp.float32)
+            sc = jnp.sum(jnp.maximum(sc, 0.0) * w_idx[..., None], axis=2)
+        sc = sc.reshape(B * k, -1)
+        seq = jnp.repeat(jnp.arange(B), k)
+        hi = jnp.tile(t0 + jnp.arange(k), B)
+        thr = select_threshold_ref(sc, seq, hi, pad_lens,
+                                   k=self.config.index_topk)
+        return selected(sc, thr, pad_lens[seq], hi).reshape(B, k, -1)
+
+    def _attend_dense(self, sl, x, cache, q_nope, q_r, t0, pad_lens,
+                      chosen=None):
         """Absorbed attention of x's rows (B, k, ...) at cache slots
-        [t0, t0 + k) over a dense latent cache (B, Lmax, R + rope)."""
+        [t0, t0 + k) over a dense latent cache (B, Lmax, R + rope);
+        ``chosen`` (B, k, Lmax) bool narrows each row's keys."""
         c = self.config
         R, W = c.kv_lora_rank, c.latent_width
         w_k, w_v = self._kv_b(sl, x.dtype)
@@ -414,6 +651,8 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         k = jnp.arange(cache.shape[1])
         mask = k[None, None, :] <= (t0 + jnp.arange(x.shape[1]))[None, :, None]
         mask = mask & (k[None, None, :] >= pad_lens[:, None, None])
+        if chosen is not None:
+            mask = mask & chosen
         sc = jnp.where(mask[:, None], sc * self._scale, -1e30)
         p = jax.nn.softmax(sc, -1).astype(x.dtype)
         o_lat = jnp.einsum("bhqk,bkr->bqhr", p, cache[..., :R])
@@ -432,11 +671,23 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                 def body(carry, xs, expert=stack == "moe"):
                     sl, ch = xs
                     with jax.named_scope("attn"):
-                        q_nope, q_r, latent = self._mla_in(sl, carry, pos)
-                        ch = jax.lax.dynamic_update_slice_in_dim(
-                            ch, latent.astype(ch.dtype), t0, axis=1)
+                        q_nope, q_r, latent, *index = self._mla_in(
+                            sl, carry, pos)
+                        put = lambda c, v: \
+                            jax.lax.dynamic_update_slice_in_dim(
+                                c, v.astype(c.dtype), t0, axis=1)
+                        if not index:
+                            lat = ch = put(ch, latent)
+                            chosen = None
+                        else:
+                            q_idx, w_idx, k_idx = index
+                            lat, keys = put(ch[0], latent), put(ch[1], k_idx)
+                            ch = (lat, keys)
+                            chosen = self._select_dense(
+                                q_idx, w_idx, keys, t0, pad_lens)
                         y = self._mla_out(sl, carry, self._attend_dense(
-                            sl, carry, ch, q_nope, q_r, t0, pad_lens))
+                            sl, carry, lat, q_nope, q_r, t0, pad_lens,
+                            chosen))
                     y, _ = self._ffn(sl, y.reshape(B * k, H), expert)
                     return y.reshape(B, k, H), ch
                 x, cache = jax.lax.scan(

@@ -267,16 +267,38 @@ def moe_ffn_gather(x, gate_w, w1, b1, w2, b2, k: int = 2,
 # ---------------------------------------------------------------------------
 
 def route_sigmoid_topk(x, gate_w, k: int, scaling: float = 1.0,
-                       normalize: bool = True):
+                       normalize: bool = True, *, bias=None,
+                       n_group: int = 1, topk_group: int = 1):
     """Scores ``sigmoid(x W_g)`` in float32 over ALL routed experts, the
     ``k`` largest, their weights ``s / (sum s + 1e-20) * scaling``
     (``normalize=False``: ``s * scaling``).  x (T, H); gate_w (H, E).
-    Returns (idx (T, k) int32, w (T, k) float32).  No group limit, no
-    selection bias, no capacity: routing never drops a token."""
+    Returns (idx (T, k) int32, w (T, k) float32).  No capacity: routing
+    never drops a token.
+
+    With ``bias`` (E,) and ``n_group`` > 1 the choice is DeepSeek-V3's
+    (arXiv:2412.19437 §2.1.2, ``topk_method: noaux_tc``): the experts are
+    chosen by ``c = s + bias``; a group of ``E / n_group`` consecutive
+    experts scores the sum of its two largest ``c``; the ``topk_group``
+    best groups stay and the ``k`` largest ``c`` among their experts are
+    chosen.  The weights are made from ``s``, never from ``c``.  With
+    neither, this is the plain function, to the bit."""
     s = jax.nn.sigmoid(jnp.matmul(
         x.astype(jnp.float32), gate_w.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    top, idx = jax.lax.top_k(s, k)
+    if bias is None and n_group == 1:
+        top, idx = jax.lax.top_k(s, k)
+    else:
+        c = s if bias is None else s + bias.astype(jnp.float32)
+        if n_group > 1:
+            T, E = c.shape
+            by_group = c.reshape(T, n_group, E // n_group)
+            score = jax.lax.top_k(by_group, 2)[0].sum(-1)       # (T, G)
+            kept = jax.lax.top_k(score, topk_group)[1]          # (T, g)
+            keep = (kept[:, :, None] == jnp.arange(n_group)).any(1)
+            c = jnp.where(keep[:, :, None], by_group,
+                          -jnp.inf).reshape(T, E)
+        idx = jax.lax.top_k(c, k)[1]
+        top = jnp.take_along_axis(s, idx, axis=-1)
     if normalize:
         top = top / (top.sum(-1, keepdims=True) + 1e-20)
     return idx.astype(jnp.int32), top * scaling

@@ -59,7 +59,10 @@ ROWS_PER_STEP = 8           # pack rows per grid step
 
 def _latent_kernel(table_ref, seq_ref, pos_ref, pad_ref, layer_ref, qa_ref,
                    qr_ref, pool_ref, o_ref, buf, sem, acc_ref, m_ref, l_ref,
-                   *, bs, kb, rows, scale, rank, rope):
+                   *, bs, kb, rows, scale, rank, rope, select=None):
+    """``select(lo, n, g)``, where given, is a further (n * nh, keys) mask
+    of inner step ``g``'s keys for pack rows [r0 + lo, r0 + lo + n): the
+    sparse sibling's (ops/ragged_sparse_latent_attention.py)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -124,6 +127,8 @@ def _latent_kernel(table_ref, seq_ref, pos_ref, pad_ref, layer_ref, qa_ref,
             sc = (dot(q_abs, c_kv, dims) + dot(q_r, k_r, dims)) * scale
             key_pos = g * keys + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
             valid = (key_pos <= row_pos) & (key_pos >= pad)
+            if select is not None:
+                valid &= select(lo, n, g)
             sc = jnp.where(valid, sc, _NEG_INF)
             m_prev = m_ref[0:M]
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))

@@ -142,11 +142,11 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             raise ValueError("num_blocks must be >= 1")
         super().__init__(model, params, max_slots, max_len, **kw)
         if self.tracer is not None:     # static, so said once, not a tick
+            leaves = [leaf.nbytes for leaf in jax.tree.leaves(self.caches)]
             self.tracer.emit(
                 "cache", engine=type(self).__name__,
-                layout=self.cache_spec.layout,
-                pool_bytes=sum(leaf.nbytes
-                               for leaf in jax.tree.leaves(self.caches)))
+                layout=self.cache_spec.layout, pool_bytes=sum(leaves),
+                leaf_bytes=leaves)      # in the spec's order
         bad = [b for b in self.buckets if b % self.bs]
         if bad:
             raise ValueError(f"block_size ({self.bs}) must divide every "

@@ -333,6 +333,88 @@ def test_ragged_latent_compiles(v5e, cols):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
+@pytest.mark.parametrize("cols", [256, 3104], ids=["C256", "C3104"])
+def test_sparse_attention_kernels_compile(v5e, cols):
+    """DeepSeek sparse attention's three steps at the published widths and
+    the long-context cell's shape — a 64-head x 128 indexer over its own
+    paged key pool (128 numbers a token: one lane tile), the exact
+    top-2048 threshold search over up to 49,664 scores a row, absorbed
+    attention over the selected of a 640-wide latent pool — 2,048 rows, 6
+    slots, a layer of a five-layer stack addressed in place.  Neither
+    pool is copied, and each kernel is called under its own name inside
+    its region."""
+    from paddle_tpu.models._decode import (ragged_index_select,
+                                           ragged_sparse_latent_attention)
+    T, nh, slots = 2048, 128, 6
+    blocks = slots * 3104 + 1
+    latent = on_one(v5e, (5, blocks, BLOCK, 640), jnp.bfloat16)
+    keys = on_one(v5e, (5, blocks, BLOCK, 128), jnp.bfloat16)
+    per_row = on_one(v5e, (T,), jnp.int32)
+
+    def attend(qa, qr, qi, wi, latent, keys, table, seq, pos, pad, layer):
+        chosen = ragged_index_select(qi, wi, keys, table, seq, pos, pad,
+                                     k=2048, layer=layer)
+        return ragged_sparse_latent_attention(
+            qa, qr, latent, *chosen, table, seq, pos, pad,
+            scale=192 ** -0.5 * 1.87385, layer=layer)
+
+    compiled = compile_for(
+        attend, on_one(v5e, (T, nh, 512), jnp.bfloat16),
+        on_one(v5e, (T, nh, 64), jnp.bfloat16),
+        on_one(v5e, (T, 64, 128), jnp.bfloat16),
+        on_one(v5e, (T, 64), jnp.float32), latent, keys,
+        on_one(v5e, (slots, cols), jnp.int32), per_row, per_row,
+        on_one(v5e, (slots,), jnp.int32), on_one(v5e, (), jnp.int32))
+    names = kernel_op_names(compiled.as_text())
+    assert len(names) == 3
+    for name, region in zip(names, ("indexer/ragged_index_scores", "select",
+                                    "ragged_sparse_latent_attention")):
+        assert region in name, names
+    # scores (T, cols * 16) float32 and the head weights' column are the
+    # temporaries; neither pool (1.9 GB and 0.4 GB) is among them
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < T * cols * BLOCK * 4 + (192 << 20)
+
+
+def test_sparse_serving_tick_compiles_at_the_cells_shape(v5e):
+    """The whole tick of ``dsv32-serve-longctx`` — the configuration file,
+    the traffic file's engine, the program's own tick builder — for a
+    described v5e: it fits (arguments + temporaries under 15.0 GB), holds
+    each pool once, and calls the two sparse kernels and the threshold
+    search in both stacks."""
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmarks.lib import harness, serve_sparse, weights_dsv32
+    cfg = harness.load_json("configs", "deepseek-v3.2-exp-ep16.json")
+    eng = harness.load_json("traffic", "longctx-backlog.json")["engine"]
+    params = {n: on_one(v5e, shape, jnp.bfloat16)
+              for n, (shape, _) in weights_dsv32.param_table(cfg).items()}
+    engine = serve_sparse.build_engine(cfg, dict(eng, num_blocks=1), {},
+                                       None)
+    engine.NB = eng["num_blocks"]
+    C = eng["max_len"] // eng["block_size"]
+    args = jax.eval_shape(lambda: engine._ragged_scratch_args(C))
+    args = jax.tree.map(
+        lambda a: on_one(v5e, a.shape, a.dtype) if hasattr(a, "shape")
+        else a, (params,) + tuple(args[1:]))
+    # at the precision the chip runs with: the suite asks for "highest"
+    # (conftest.py, for its numpy oracles), and the compiler's own kernel
+    # for the experts' grouped product refuses a float32 product of
+    # bfloat16 operands
+    with jax.default_matmul_precision("default"):
+        compiled = engine._build_ragged_step(
+            eng["token_budget"], C).lower(*args).compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9, ma
+    pools = 5 * (eng["num_blocks"] + 1) * 16 * (640 + 128) * 2
+    assert ma.alias_size_in_bytes >= pools          # donated, held once
+    names = kernel_op_names(compiled.as_text())
+    for stem in ("ragged_index_scores", "select/",
+                 "ragged_sparse_latent_attention"):
+        assert sum(stem in n for n in names) == 2, (stem, names)
+
+
 def kernel_op_names(text):
     """The ``op_name`` of every Pallas kernel call in compiled HLO text."""
     import re

@@ -341,6 +341,46 @@ def test_prefix_lookup_works_on_a_latent_cache():
     assert eng.run_to_completion()[b] == first and eng.prefix_hits >= 1
 
 
+# sha256 of the Pangu tick's lowering on the tree before PR 36 (commit
+# 3a775a4) under this suite's conftest, by (dtype, kernels interpreted,
+# table width)
+PARENT_TICK = {
+    ("float32", False, 4):
+        "ba1604cb7cbf0f7592c00e60d3a0359292c223d5115250b315d324aeabf4206d",
+    ("float32", False, 8):
+        "db8b15583655acb7ad61299d31b67b52d970fafe78977cd5e5dd2865c03a4e98",
+    ("float32", True, 4):
+        "0be93017b9b2637fb7a244262a8b0f56075492f15014d3782bd0c698671040b7",
+    ("float32", True, 8):
+        "249f54d04750f387ff2a8428aeb8888219026062a103e5f9bf9f197f159afb76",
+    ("bfloat16", False, 4):
+        "4de688e25ab67243afc91bd8cd21de02d5ea7ba86c5954633da47e229fa06d3e",
+    ("bfloat16", False, 8):
+        "5ba59012067f30a35bc168a1b47d3a2e9aff6d687a9360cb0e39bf1639fbb021",
+    ("bfloat16", True, 4):
+        "be2fdcfecb4ad443d464aa7a5f1fcadece92b6fb3f325854ab8464bd5340a360",
+    ("bfloat16", True, 8):
+        "3894731d64851300ab5f4d208f2e72f50afc33c691230b9de85018d5a057fa0b",
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("interpret", [True, False], indirect=True,
+                         ids=["kernel", "xla"])
+@pytest.mark.parametrize("cols", [4, 8])
+def test_the_pangu_tick_lowers_as_at_the_parent(dtype, interpret, cols):
+    """What DeepSeek-V3.2-Exp added to the model class, the router and the
+    latent kernel is reached only through keys the Pangu configuration
+    does not have: its tick lowers to the parent's program to the byte."""
+    import hashlib
+    model, params = build(dtype)
+    eng = engine(model, params)
+    text = eng._build_ragged_step(16, cols).lower(
+        *eng._ragged_scratch_args(cols)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() \
+        == PARENT_TICK[dtype, interpret, cols]
+
+
 def _mlir_type(shape, dtype):
     name = {"float32": "f32", "int8": "i8"}[str(dtype)]
     return "tensor<" + "x".join(map(str, shape)) + "x" + name + ">"
