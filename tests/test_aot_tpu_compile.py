@@ -33,10 +33,19 @@ from jax.sharding import SingleDeviceSharding
 KERNEL = "tpu_custom_call"
 
 # (batch, length, heads, head_dim): gpt2-small's 64 and the 128 of the larger
-# presets, at the training length and at the long-context cell's
+# presets, at the training length and at the long-context cell's; the first
+# and the last are the two training cells' own (gpt2s-train whole,
+# c1p3b-train-x4 a chip's two rows)
 FLASH_SHAPES = [(16, 1024, 12, 64), (4, 1024, 16, 128),
-                (1, 8192, 12, 64), (1, 8192, 8, 128)]
-FLASH_IDS = ["D64-L1024", "D128-L1024", "D64-L8192", "D128-L8192"]
+                (1, 8192, 12, 64), (1, 8192, 8, 128), (2, 2048, 16, 128)]
+FLASH_IDS = ["D64-L1024", "D128-L1024", "D64-L8192", "D128-L8192",
+             "D128-L2048"]
+CELLS = [FLASH_SHAPES[0], FLASH_SHAPES[4]]
+CELL_IDS = ["gpt2s-train", "c1p3b-train-x4"]
+# the temporaries of one layer's forward + backward at the parent of PR 37
+# (three kernels, float32 operands), compiled for the same described chip
+# (at x4's shape the compiler reports none, for the parent and since)
+PARENT_TEMP_BYTES = {FLASH_SHAPES[0]: 201_391_104, FLASH_SHAPES[4]: 0}
 
 # the serving cell's pool geometry: 8 slots x 512 positions in 16-token
 # blocks, a 256-row token budget
@@ -96,11 +105,53 @@ def test_flash_forward_compiles(v5e, shape):
 
 @pytest.mark.parametrize("shape", FLASH_SHAPES, ids=FLASH_IDS)
 def test_flash_backward_compiles(v5e, shape):
+    from paddle_tpu.ops.attention import flash_plan
     q = on_one(v5e, shape, jnp.bfloat16)
     compiled = compile_for(jax.grad(flash_loss(), argnums=(0, 1, 2)),
                            q, q, q)
-    # forward, dQ, dK/dV
-    assert compiled.as_text().count(KERNEL) == 3
+    # forward and the fused backward; or forward, dQ, dK/dV where a head's
+    # float32 dQ does not fit the plan's VMEM budget (8,192 rows)
+    fused = flash_plan(shape[1], shape[3], True, jnp.bfloat16).form == "fused"
+    assert fused == (shape[1] != 8192)
+    assert compiled.as_text().count(KERNEL) == (2 if fused else 3)
+
+
+@pytest.mark.parametrize("shape", CELLS, ids=CELL_IDS)
+def test_flash_backward_is_one_kernel_at_the_cells_shapes(v5e, shape):
+    """Both training cells' shapes: ONE backward kernel (five products),
+    named, and the program holds no more temporaries than the parent's
+    three kernels did — the gradients take their operands' buffers."""
+    q = on_one(v5e, shape, jnp.bfloat16)
+    compiled = compile_for(jax.grad(flash_loss(), argnums=(0, 1, 2)),
+                           q, q, q)
+    names = kernel_op_names(compiled.as_text())
+    assert sorted(n.split("/")[-2] for n in names) == [
+        "flash_attention_bwd", "flash_attention_fwd"]
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= PARENT_TEMP_BYTES[shape]
+
+
+@pytest.mark.parametrize("dtype,causal,masked,dropout_p", [
+    ("float32", True, False, 0.0),      # float32 products stay float32
+    ("bfloat16", False, True, 0.1),     # BERT: padding mask, dropout
+    ("bfloat16", True, True, 0.1)],
+    ids=["float32-causal", "bert-mask-dropout", "causal-mask-dropout"])
+def test_flash_variants_compile(v5e, dtype, causal, masked, dropout_p):
+    from paddle_tpu.ops.attention import flash_attention
+    shape = (4, 1024, 12, 64)
+    q = on_one(v5e, shape, jnp.dtype(dtype))
+    kmask = on_one(v5e, shape[:2], jnp.float32)
+    seed = on_one(v5e, (), jnp.uint32)
+
+    def loss(q, k, v, kmask, seed):
+        return flash_attention(
+            q, k, v, causal=causal, key_mask=kmask if masked else None,
+            dropout_p=dropout_p, dropout_seed=seed if dropout_p else None,
+        ).astype(jnp.float32).sum()
+
+    compiled = compile_for(jax.grad(loss, argnums=(0, 1, 2)),
+                           q, q, q, kmask, seed)
+    assert compiled.as_text().count(KERNEL) == 2
 
 
 def test_flash_under_dp2_mp2_mesh_compiles(v5e):
@@ -112,7 +163,7 @@ def test_flash_under_dp2_mp2_mesh_compiles(v5e):
         sharding=NamedSharding(mesh, P("data", None, "model", None)))
     compiled = compile_for(jax.grad(flash_loss(mesh), argnums=(0, 1, 2)),
                            q, q, q)
-    assert compiled.as_text().count(KERNEL) == 3
+    assert compiled.as_text().count(KERNEL) == 2    # forward, backward
     with pytest.raises(Exception, match="shard_map"):
         compile_for(jax.grad(flash_loss(None), argnums=(0, 1, 2)), q, q, q)
 
@@ -175,7 +226,7 @@ def test_zero3_step_gathers_one_layer_for_v5e_2x2(v5e):
                for d in r["dims"] if r["computation"].startswith(
                    "all-reduce-scatter")}
     assert {(H, 3 * H), (H, I)} <= reduced, reduced
-    assert text.count(KERNEL) == 4      # flash forward (twice: remat), dQ, dK/dV
+    assert text.count(KERNEL) == 3      # flash forward (twice: remat), backward
 
 
 # the docs cell's own tick (benchmarks/traffic/docs-backlog.json): a
@@ -425,15 +476,26 @@ def kernel_op_names(text):
 
 def test_flash_kernels_are_called_under_their_own_name(v5e):
     """A trace finds a kernel by the region and the name of its call, not
-    by the file it lives in: forward, dQ and dK/dV say ``flash_attention``,
-    the backward ones on the transposed path."""
+    by the file it lives in: forward and backward say ``flash_attention``,
+    the backward on the transposed path."""
     q = on_one(v5e, FLASH_SHAPES[1], jnp.bfloat16)
     names = kernel_op_names(compile_for(
         jax.grad(flash_loss(), argnums=(0, 1, 2)), q, q, q).as_text())
-    assert len(names) == 3
+    assert len(names) == 2
     # the region reaches the call inside the wrapper that transformed it:
     # jvp(flash_attention)/..., transpose(jvp(flash_attention))/...
     assert all("(flash_attention)" in n for n in names)
+    assert sorted(n.split("/")[-2] for n in names) == [
+        "flash_attention_bwd", "flash_attention_fwd"]
+    assert sum("transpose(" in n for n in names) == 1
+
+
+def test_flash_split_kernels_keep_their_names(v5e):
+    """Where the plan keeps two backward kernels (a head's dQ over the
+    VMEM budget) they are the dQ and dK/dV calls a trace knew before."""
+    q = on_one(v5e, FLASH_SHAPES[3], jnp.bfloat16)
+    names = kernel_op_names(compile_for(
+        jax.grad(flash_loss(), argnums=(0, 1, 2)), q, q, q).as_text())
     assert sorted(n.split("/")[-2] for n in names) == [
         "flash_attention_dkv", "flash_attention_dq", "flash_attention_fwd"]
     assert sum("transpose(" in n for n in names) == 2

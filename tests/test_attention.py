@@ -2,10 +2,15 @@
 
 OpTest-style oracle comparisons (reference op_test.py:277 methodology):
 forward and analytic gradients of the Pallas kernels vs the dense XLA
-reference at fp32, plus dropout determinism and an O(L) memory assertion
-(no (L, L) intermediate in the backward jaxpr — the round-1 backward vjp'd
-through dense attention and materialized it).
+reference, over both forms of the backward (one fused kernel of five
+products; dQ and dK/dV apart), head sizes 64 and 128, float32 and bfloat16
+inputs, plus dropout determinism (the keep mask position for position), the
+plan function, and an O(L) memory assertion (no (L, L) intermediate in the
+backward jaxpr — the round-1 backward vjp'd through dense attention and
+materialized it).
 """
+
+import os
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +18,14 @@ import numpy as np
 import pytest
 
 import paddle_tpu.ops.attention as A
+from paddle_tpu.ops.attention import FlashPlan
+
+FORMS = ["fused", "split"]
+HEAD_DIMS = [64, 128]
+DTYPES = ["float32", "bfloat16"]
+# what a comparison with the float32 dense oracle may differ by, against
+# the oracle's largest entry: (forward, gradients)
+TOL = {"float32": (2e-5, 5e-5), "bfloat16": (1.5e-2, 2.5e-2)}
 
 
 def _rand_qkv(B=2, L=256, H=2, D=64, seed=0, dtype=jnp.float32):
@@ -22,72 +35,142 @@ def _rand_qkv(B=2, L=256, H=2, D=64, seed=0, dtype=jnp.float32):
     return mk(), mk(), mk()
 
 
-def _flash(q, k, v, causal=False, key_mask=None, dropout_p=0.0, seed=0):
-    B, L = q.shape[0], q.shape[1]
-    km = (jnp.zeros((B, L), jnp.float32) if key_mask is None
-          else key_mask.astype(jnp.float32))
+def _flash(q, k, v, causal=False, key_mask=None, dropout_p=0.0, seed=0,
+           form="fused", block=128, sub=128):
+    km = None if key_mask is None else key_mask.astype(jnp.float32)
     sd = jnp.full((1,), seed, jnp.uint32)
     scale = 1.0 / np.sqrt(q.shape[-1])
-    return A._flash_attention(q, k, v, km, sd, causal, scale, dropout_p, 128)
+    return A._flash_attention(q, k, v, km, sd, causal, scale, dropout_p,
+                              FlashPlan(form, block, block, sub))
+
+
+def _dense32(q, k, v, **kw):
+    """The oracle on the same inputs, in float32."""
+    return A.dense_attention(*(x.astype(jnp.float32) for x in (q, k, v)),
+                             **kw)
+
+
+def _key_mask(B, L, seed):
+    valid = np.random.RandomState(seed).rand(B, L) > 0.3
+    valid[:, 0] = True  # every row keeps at least one key
+    return jnp.asarray(np.where(valid, 0.0, -1e30).astype(np.float32))
+
+
+def _close(got, want, tol, what=""):
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    worst = np.abs(got - want).max() / np.abs(want).max()
+    assert worst <= tol, f"{what} off by {worst:.3e} of the largest entry"
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in eqn.params.values():
+            if hasattr(sub, "jaxpr"):
+                yield from _eqns(sub.jaxpr)
+
+
+def _assert_linear_buffers(jaxpr, L, limit):
+    for eqn in _eqns(jaxpr.jaxpr):
+        for var in eqn.outvars:
+            sz = int(np.prod(var.aval.shape)) if var.aval.shape else 1
+            assert sz < L * L, \
+                f"quadratic buffer {var.aval.shape} from {eqn.primitive}"
+            assert sz <= limit, \
+                f"oversized buffer {var.aval.shape} from {eqn.primitive}"
+
+
+def _grads(f, q, k, v):
+    return jax.grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32) ** 2),
+                    argnums=(0, 1, 2))(q, k, v)
 
 
 class TestFlashForward:
+    @pytest.mark.parametrize("dtype", DTYPES)
+    @pytest.mark.parametrize("D", HEAD_DIMS)
     @pytest.mark.parametrize("causal", [False, True])
-    def test_matches_dense(self, causal):
-        q, k, v = _rand_qkv()
+    def test_matches_dense(self, causal, D, dtype):
+        q, k, v = _rand_qkv(D=D, dtype=dtype)
         out = _flash(q, k, v, causal=causal)
-        ref = A.dense_attention(q, k, v, causal=causal)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
+        assert out.dtype == q.dtype
+        _close(out, _dense32(q, k, v, causal=causal), TOL[dtype][0])
 
-    def test_key_padding_mask_matches_dense(self):
-        q, k, v = _rand_qkv()
-        B, L = q.shape[0], q.shape[1]
-        r = np.random.RandomState(1)
-        valid = r.rand(B, L) > 0.3
-        valid[:, 0] = True  # every row keeps at least one key
-        km = jnp.asarray(np.where(valid, 0.0, -1e30).astype(np.float32))
+    @pytest.mark.parametrize("dtype", DTYPES)
+    @pytest.mark.parametrize("D", HEAD_DIMS)
+    def test_key_padding_mask_matches_dense(self, D, dtype):
+        q, k, v = _rand_qkv(D=D, dtype=dtype)
+        km = _key_mask(q.shape[0], q.shape[1], 1)
         out = _flash(q, k, v, key_mask=km)
-        ref = A.dense_attention(q, k, v, mask=km[:, None, None, :])
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
+        _close(out, _dense32(q, k, v, mask=km[:, None, None, :]),
+               TOL[dtype][0])
+
+    @pytest.mark.parametrize("block,sub", [(256, 128), (256, 256), (512, 128)])
+    def test_a_block_walked_in_groups_matches_dense(self, block, sub):
+        """Blocks larger than the walk's group: the online softmax runs
+        over the blocks under the diagonal, and a diagonal block's groups
+        each stop at their own edge."""
+        q, k, v = _rand_qkv(B=1, L=512)
+        km = _key_mask(1, 512, 5)
+        out = _flash(q, k, v, causal=True, key_mask=km, block=block, sub=sub)
+        _close(out, _dense32(q, k, v, causal=True,
+                             mask=km[:, None, None, :]), TOL["float32"][0])
 
 
 class TestFlashBackward:
+    @pytest.mark.parametrize("dtype", DTYPES)
+    @pytest.mark.parametrize("D", HEAD_DIMS)
+    @pytest.mark.parametrize("form", FORMS)
     @pytest.mark.parametrize("causal", [False, True])
-    def test_grads_match_dense(self, causal):
-        q, k, v = _rand_qkv(L=256)
-
-        def loss_flash(q, k, v):
-            return jnp.sum(_flash(q, k, v, causal=causal) ** 2)
-
-        def loss_dense(q, k, v):
-            return jnp.sum(A.dense_attention(q, k, v, causal=causal) ** 2)
-
-        g_f = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g_d = jax.grad(loss_dense, argnums=(0, 1, 2))(q, k, v)
+    def test_grads_match_dense(self, causal, form, D, dtype):
+        q, k, v = _rand_qkv(L=256, D=D, dtype=dtype)
+        g_f = _grads(lambda *a: _flash(*a, causal=causal, form=form), q, k, v)
+        g_d = _grads(lambda *a: _dense32(*a, causal=causal), q, k, v)
         for a, b, name in zip(g_f, g_d, "qkv"):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-5, rtol=5e-5,
-                                       err_msg=f"d{name} mismatch")
+            assert a.dtype == q.dtype
+            _close(a, b, TOL[dtype][1], f"d{name}")
 
-    def test_grads_match_dense_with_mask(self):
-        q, k, v = _rand_qkv()
-        B, L = q.shape[0], q.shape[1]
-        r = np.random.RandomState(2)
-        valid = r.rand(B, L) > 0.3
-        valid[:, 0] = True
-        km = jnp.asarray(np.where(valid, 0.0, -1e30).astype(np.float32))
-
-        g_f = jax.grad(lambda q, k, v: jnp.sum(
-            _flash(q, k, v, key_mask=km) ** 2), argnums=(0, 1, 2))(q, k, v)
-        g_d = jax.grad(lambda q, k, v: jnp.sum(
-            A.dense_attention(q, k, v, mask=km[:, None, None, :]) ** 2),
-            argnums=(0, 1, 2))(q, k, v)
+    @pytest.mark.parametrize("dtype", DTYPES)
+    @pytest.mark.parametrize("D", HEAD_DIMS)
+    @pytest.mark.parametrize("form", FORMS)
+    def test_grads_match_dense_with_mask(self, form, D, dtype):
+        q, k, v = _rand_qkv(D=D, dtype=dtype)
+        km = _key_mask(q.shape[0], q.shape[1], 2)
+        g_f = _grads(lambda *a: _flash(*a, key_mask=km, form=form), q, k, v)
+        g_d = _grads(lambda *a: _dense32(*a, mask=km[:, None, None, :]),
+                     q, k, v)
         for a, b, name in zip(g_f, g_d, "qkv"):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       atol=5e-5, rtol=5e-5,
-                                       err_msg=f"d{name} mismatch")
+            _close(a, b, TOL[dtype][1], f"d{name}")
+
+    @pytest.mark.parametrize("form", FORMS)
+    @pytest.mark.parametrize("block,sub", [(256, 128), (256, 256), (512, 128)])
+    def test_grads_of_a_block_walked_in_groups(self, block, sub, form):
+        """dQ of a group sums over the pieces it meets, dK / dV of a piece
+        over the groups that meet it, the fused form's dQ over the kv
+        blocks of the whole head."""
+        q, k, v = _rand_qkv(B=1, L=512)
+        km = _key_mask(1, 512, 6)
+        g_f = _grads(lambda *a: _flash(*a, causal=True, key_mask=km,
+                                       form=form, block=block, sub=sub),
+                     q, k, v)
+        g_d = _grads(lambda *a: _dense32(*a, causal=True,
+                                         mask=km[:, None, None, :]), q, k, v)
+        for a, b, name in zip(g_f, g_d, "qkv"):
+            _close(a, b, TOL["float32"][1], f"d{name}")
+
+    @pytest.mark.parametrize("form,names", [
+        ("fused", ["flash_attention_bwd", "flash_attention_fwd"]),
+        ("split", ["flash_attention_dkv", "flash_attention_dq",
+                   "flash_attention_fwd"])])
+    def test_the_forms_kernels_by_name(self, form, names):
+        """The fused backward is ONE Pallas call."""
+        q = jax.ShapeDtypeStruct((1, 256, 2, 64), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda *a: jnp.sum(_flash(*a, causal=True, form=form)
+                               .astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, q, q)
+        assert sorted(eqn.params["name"] for eqn in _eqns(jaxpr.jaxpr)
+                      if eqn.primitive.name == "pallas_call") == names
 
     def test_no_quadratic_buffer_in_backward(self):
         """The VERDICT-cited regression: round-1 backward materialized the
@@ -100,46 +183,45 @@ class TestFlashBackward:
             return jnp.sum(_flash(q, k, v, causal=True))
 
         jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
-        limit = L * D * 16  # biggest legitimate buffer family, with slack
-
-        def walk(jx):
-            for eqn in jx.eqns:
-                for var in eqn.outvars:
-                    sz = int(np.prod(var.aval.shape)) if var.aval.shape else 1
-                    assert sz < L * L, \
-                        f"quadratic buffer {var.aval.shape} from {eqn.primitive}"
-                    assert sz <= limit, \
-                        f"oversized buffer {var.aval.shape} from {eqn.primitive}"
-                for sub in eqn.params.values():
-                    if hasattr(sub, "jaxpr"):
-                        walk(sub.jaxpr)
-        walk(jaxpr.jaxpr)
+        # the biggest legitimate buffer family, with slack
+        _assert_linear_buffers(jaxpr, L, L * D * 16)
 
     def test_l32k_linear_memory(self):
         """L=32768 long-context bound (VERDICT r2 #8): the full fwd+bwd jaxpr
         stays O(L) — no aval anywhere near L*L, and the total live-buffer
-        bound fits a single chip's HBM at bf16."""
+        bound fits a single chip's HBM at bf16.  The plan's own form there:
+        32 k rows of float32 dQ do not fit the budget, so the backward is
+        the pair of kernels."""
         B, L, H, D = 1, 32768, 8, 64
         q = jax.ShapeDtypeStruct((B, L, H, D), jnp.bfloat16)
+        plan = A.flash_plan(L, D, True, jnp.bfloat16)
+        assert plan.form == "split"
 
         def loss(q, k, v):
-            return jnp.sum(_flash(q, k, v, causal=True).astype(jnp.float32))
+            return jnp.sum(_flash(q, k, v, causal=True, form=plan.form,
+                                  block=plan.block_q, sub=plan.sub)
+                           .astype(jnp.float32))
 
         jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q)
-        limit = L * D * H * 16
+        _assert_linear_buffers(jaxpr, L, L * D * H * 16)
 
-        def walk(jx):
-            for eqn in jx.eqns:
-                for var in eqn.outvars:
-                    sz = int(np.prod(var.aval.shape)) if var.aval.shape else 1
-                    assert sz < L * L, \
-                        f"quadratic buffer {var.aval.shape} from {eqn.primitive}"
-                    assert sz <= limit, \
-                        f"oversized buffer {var.aval.shape} from {eqn.primitive}"
-                for sub in eqn.params.values():
-                    if hasattr(sub, "jaxpr"):
-                        walk(sub.jaxpr)
-        walk(jaxpr.jaxpr)
+
+def _hashed_dropout_oracle(q, k, v, causal, dropout_p, seed):
+    """Dense attention under the keep mask ``position_hash_keep`` gives
+    each (batch·head, row, col): what every kernel, whatever its blocks or
+    the orientation of its tiles, must reproduce position for position."""
+    B, L, H, D = q.shape
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    s = jnp.einsum("blhd,bmhd->bhlm", q, k) / np.sqrt(D)
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((L, L), bool)), s, -1e30)
+    keep = jnp.stack([
+        A.position_hash_keep(
+            jnp.uint32(seed) + jnp.uint32(bh) * jnp.uint32(0xC2B2AE3D),
+            0, 0, (L, L), dropout_p)
+        for bh in range(B * H)]).reshape(B, H, L, L)
+    p = jnp.where(keep, jax.nn.softmax(s, axis=-1) / (1.0 - dropout_p), 0.0)
+    return jnp.einsum("bhlm,bmhd->blhd", p, v)
 
 
 class TestFlashDropout:
@@ -153,6 +235,23 @@ class TestFlashDropout:
         # E[dropout(P)] = P, so the mean output is near the no-dropout one
         base = _flash(q, k, v)
         assert np.abs(np.asarray(o1).mean() - np.asarray(base).mean()) < 0.05
+
+    @pytest.mark.parametrize("dtype", DTYPES)
+    @pytest.mark.parametrize("form", FORMS)
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_keep_mask_position_for_position(self, causal, form, dtype):
+        """Forward and gradients against dense attention under the hash's
+        own mask over the whole square, query-major: the kernels'
+        key-major tiles keep the same positions as the query-major
+        kernels before them did."""
+        q, k, v = _rand_qkv(B=1, L=256, dtype=dtype)
+        flash = lambda *a: _flash(*a, causal=causal, dropout_p=0.3, seed=5,
+                                  form=form, block=256, sub=128)
+        oracle = lambda *a: _hashed_dropout_oracle(*a, causal, 0.3, 5)
+        _close(flash(q, k, v), oracle(q, k, v), TOL[dtype][0])
+        for a, b, name in zip(_grads(flash, q, k, v),
+                              _grads(oracle, q, k, v), "qkv"):
+            _close(a, b, TOL[dtype][1], f"d{name}")
 
     @pytest.mark.parametrize("argnum,name", [(0, "q"), (1, "k"), (2, "v")])
     def test_vjp_consistent_with_fd(self, argnum, name):
@@ -179,6 +278,101 @@ class TestFlashDropout:
                                    err_msg=f"d{name} FD mismatch")
 
 
+class TestFlashPlan:
+    """The one function that chooses the form and the blocks."""
+
+    @pytest.mark.parametrize("L,D,most", [(1024, 64, 0.625),
+                                          (2048, 128, 0.5625)])
+    def test_the_training_cells_shapes(self, L, D, most):
+        """gpt2s-train (16 x 1024, heads of 64) and c1p3b-train-x4 (2048,
+        heads of 128): the fused backward, the swept blocks (the whole
+        head a grid step, walked in groups of 256), and at most the issue's
+        share of the causal square computed."""
+        plan = A.flash_plan(L, D, True, jnp.bfloat16)
+        assert plan == FlashPlan("fused", L, L, 256)
+        done, square = plan.tiles(L, True)
+        assert done / square <= most
+        assert plan.tiles(L, False) == (square, square)
+
+    def test_a_head_whose_dq_does_not_fit_keeps_two_kernels(self):
+        """The budgets count a buffer as it lies in VMEM, a row padded to
+        the 128 lanes."""
+        assert A.flash_plan(4096, 128, True, jnp.bfloat16).form == "fused"
+        assert A.flash_plan(8192, 64, True, jnp.bfloat16).form == "split"
+        assert A.flash_plan(8192, 128, True, jnp.bfloat16).form == "split"
+        assert A.flash_plan(32768, 64, True, jnp.bfloat16).form == "split"
+        assert 8192 * 128 * 4 > A.DQ_VMEM_BUDGET >= 2048 * 128 * 4
+
+    def test_a_block_fits_its_budget_in_the_inputs_dtype(self):
+        assert A.flash_plan(8192, 128, True, jnp.bfloat16).block_q == 2048
+        assert A.flash_plan(8192, 128, True, jnp.float32).block_q == 1024
+        assert A.flash_plan(512, 64, False, jnp.bfloat16) \
+            == FlashPlan("fused", 512, 512, 256)
+
+    def test_a_length_no_block_tiles_has_no_plan(self):
+        assert A.flash_plan(1000, 64, True, jnp.float32) is None
+        assert A.flash_plan(384, 64, True, jnp.float32) \
+            == FlashPlan("fused", 128, 128, 128)
+
+    @pytest.mark.parametrize("block,sub", [(512, 128), (512, 256), (256, 256),
+                                           (128, 128), (1024, 128)])
+    @pytest.mark.parametrize("causal", [False, True])
+    def test_the_walk_covers_what_attention_needs_once(self, causal, block,
+                                                       sub):
+        """Count the tiles the kernels' own predicate and walk let through:
+        every position a row attends lies in exactly one piece, a piece
+        marked unmasked holds nothing above the diagonal, and the count is
+        the plan's."""
+        L = 1024
+        plan = FlashPlan("fused", block, block, sub)
+        seen = np.zeros((L, L), np.int32)
+        area = 0
+        for qi in range(L // block):
+            for ki in range(L // block):
+                run, diag = plan.runs(qi, ki, causal)
+                if not run:
+                    continue
+                for q0, pieces in plan.walk(diag):
+                    r0 = qi * block + q0
+                    for k0, keys, masked in pieces:
+                        c0 = ki * block + k0
+                        rows = np.arange(r0, r0 + sub)[:, None]
+                        cols = np.arange(c0, c0 + keys)[None, :]
+                        live = (cols <= rows) if masked else \
+                            np.ones((sub, keys), bool)
+                        if causal and not masked:
+                            assert c0 + keys - 1 <= r0
+                        seen[r0:r0 + sub, c0:c0 + keys] += live
+                        area += sub * keys
+        want = np.tril(np.ones((L, L), np.int32)) if causal else 1
+        np.testing.assert_array_equal(seen, want)
+        done, square = plan.tiles(L, causal)
+        assert done * sub * sub == area and square == (L // sub) ** 2
+
+    def test_the_plan_sets_its_gauges_when_a_program_is_traced(
+            self, monkeypatch):
+        from paddle_tpu.utils.stats import get_all_stats
+        monkeypatch.setattr(A, "_use_pallas", lambda: True)
+        q = jax.ShapeDtypeStruct((1, 1024, 2, 64), jnp.bfloat16)
+        jax.make_jaxpr(lambda q: A.flash_attention(q, q, q, causal=True))(q)
+        stats = get_all_stats()
+        assert stats["flash_blocks_computed"] == 10
+        assert stats["flash_blocks_square"] == 16
+        assert stats["flash_backward_fused"] == 1
+
+    def test_nothing_but_the_shape_chooses(self):
+        """No environment variable, flag or argument: the old override is
+        gone from the package and the tools."""
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        for top in ("paddle_tpu", "tools"):
+            for folder, _, files in os.walk(os.path.join(root, top)):
+                for name in files:
+                    if name.endswith(".py"):
+                        with open(os.path.join(folder, name)) as f:
+                            assert "PADDLE_TPU_FLASH_BLOCK" not in f.read(), \
+                                os.path.join(folder, name)
+
+
 class TestSDPARouting:
     def test_bert_padding_mask_uses_flash(self, monkeypatch):
         """(B,1,1,L) additive masks must route to the flash kernel, not the
@@ -200,3 +394,23 @@ class TestSDPARouting:
                                              attn_mask=paddle.to_tensor(mask))
         assert calls.get("flash"), "padding mask fell back to dense"
         assert np.isfinite(np.asarray(out._data)).all()
+
+    def test_the_public_entry_runs_the_kernels_with_mask_and_dropout(
+            self, monkeypatch):
+        """``flash_attention`` itself (what every model calls), through the
+        plan: a key mask and dropout reach the kernels, no mask is a static
+        fact, and a length no block tiles takes the dense path."""
+        monkeypatch.setattr(A, "_use_pallas", lambda: True)
+        q, k, v = _rand_qkv(B=1, L=256)
+        km = _key_mask(1, 256, 3)
+        out = A.flash_attention(q, k, v, causal=True, key_mask=km)
+        _close(out, _dense32(q, k, v, causal=True,
+                             mask=km[:, None, None, :]), TOL["float32"][0])
+        seed = jnp.uint32(5)
+        out = A.flash_attention(q, k, v, causal=True, dropout_p=0.3,
+                                dropout_seed=seed)
+        _close(out, _hashed_dropout_oracle(q, k, v, True, 0.3, 5),
+               TOL["float32"][0])
+        q, k, v = _rand_qkv(B=1, L=200)
+        _close(A.flash_attention(q, k, v, causal=True),
+               _dense32(q, k, v, causal=True), TOL["float32"][0])
