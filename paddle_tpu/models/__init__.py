@@ -5,3 +5,4 @@ from .bert import (BERT_CONFIGS, BertConfig, BertModel, bert_preset,  # noqa: F4
 from .ernie_moe import (ErnieMoeConfig, ErnieMoeModel,  # noqa: F401
                         make_ernie_moe_train_step)
 from .pangu_moe import PanguMoeConfig, PanguMoeModel  # noqa: F401
+from .evabyte import EvaByteConfig, EvaByteModel  # noqa: F401

@@ -19,18 +19,29 @@ import jax.numpy as jnp
 
 class CacheLeaf(NamedTuple):
     """One cache leaf as the model states it: ``layers`` of them stacked,
-    ``tail`` the shape of one position's entry, ``dtype`` its dtype.  A
-    paged engine stores it as ``(layers, NB + 1, block_size) + tail``
-    (block 0 the trash block), a dense cache as ``(layers, B, max_len) +
-    tail``."""
+    ``tail`` the shape of one row's entry, ``dtype`` its dtype, and how a
+    row is addressed (docs/CACHE_SPEC.md):
+
+    - per token by the block table (the default): a paged engine stores it
+      as ``(layers, NB + 1, block_size) + tail`` (block 0 the trash block),
+      a dense cache as ``(layers, B, max_len) + tail``;
+    - ``tokens_per_row`` > 1: by the table still, one row per that many
+      positions (a leaf that pages BY CHUNK): a block of ``block_size``
+      rows names ``block_size * tokens_per_row`` positions;
+    - ``slot_rows`` > 0: the leaf does not page.  ``(layers, slots,
+      slot_rows) + tail``, a sequence's own rows addressed by its slot
+      and ``position mod slot_rows``, allocated and freed with the
+      slot."""
     layers: int
     tail: Tuple[int, ...]
     dtype: str
+    tokens_per_row: int = 1
+    slot_rows: int = 0
 
 
 class CacheSpec(NamedTuple):
-    """What a model caches per token (docs/CACHE_SPEC.md).  The serving
-    engines read this, never the model's class.
+    """What a model caches (docs/CACHE_SPEC.md).  The serving engines read
+    this, never the model's class.
 
     ``pools``: a tuple of entries in the order ``decode_ragged`` takes and
     returns them, each a pytree of ``CacheLeaf`` (an int8 K plane is a
@@ -41,19 +52,92 @@ class CacheSpec(NamedTuple):
     bucketed paged programs are written for); any other name is the
     model's own.  ``tick_stats``: names of the int32 counters
     ``decode_ragged`` returns as a third output, one vector entry each
-    (empty: it returns two outputs)."""
+    (empty: it returns two outputs).  ``row_boundary`` > 0: no pack may
+    hold rows of one sequence on both sides of a multiple of it (counted
+    from the sequence's first real position)."""
     pools: tuple
     layout: str = "kv"
     tick_stats: Tuple[str, ...] = ()
+    row_boundary: int = 0
 
 
-def build_pools(spec: CacheSpec, lead: Tuple[int, ...]):
-    """Zeroed storage for ``spec``: every leaf ``(layers,) + lead + tail``
-    (``lead`` is ``(NB + 1, block_size)`` for a block pool)."""
-    return jax.tree.map(
-        lambda leaf: jnp.zeros((leaf.layers,) + tuple(lead) + leaf.tail,
-                               jnp.dtype(leaf.dtype)),
-        spec.pools, is_leaf=lambda x: isinstance(x, CacheLeaf))
+def spec_leaves(spec: CacheSpec):
+    return jax.tree.leaves(spec.pools,
+                           is_leaf=lambda x: isinstance(x, CacheLeaf))
+
+
+def tokens_per_row(spec: CacheSpec) -> int:
+    """Positions one row of the spec's table-addressed leaves stands for.
+    They share one table and one allocator, so they must agree."""
+    per = {leaf.tokens_per_row for leaf in spec_leaves(spec)
+           if not leaf.slot_rows}
+    if len(per) > 1:
+        raise ValueError(f"leaves on one block table must cover the same "
+                         f"positions a row, got {sorted(per)}")
+    return per.pop() if per else 1
+
+
+def build_pools(spec: CacheSpec, lead: Tuple[int, ...], slots=None):
+    """Zeroed storage for ``spec``: every table-addressed leaf ``(layers,)
+    + lead + tail`` (``lead`` is ``(NB + 1, block_size)`` for a block
+    pool), every leaf that does not page ``(layers, slots, slot_rows) +
+    tail``."""
+    def one(leaf):
+        at = tuple(lead)
+        if leaf.slot_rows:
+            if slots is None:
+                raise ValueError("a leaf that does not page needs `slots`")
+            at = (int(slots), leaf.slot_rows)
+        return jnp.zeros((leaf.layers,) + at + leaf.tail,
+                         jnp.dtype(leaf.dtype))
+    return jax.tree.map(one, spec.pools,
+                        is_leaf=lambda x: isinstance(x, CacheLeaf))
+
+
+def rms_norm(x, w, eps, unit_offset=False):
+    """RMSNorm over the last axis in float32, back in x's dtype:
+    ``x / sqrt(mean(x^2) + eps) * w`` — or ``* (1 + w)`` under
+    ``unit_offset`` (a scale stored as its distance from one)."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    w32 = w.astype(jnp.float32)
+    return (y * (1.0 + w32 if unit_offset else w32)).astype(x.dtype)
+
+
+def rope_rotate_half(x, pos, inv_freq):
+    """Rotate-half rotary positions over the last axis of x (..., heads,
+    D) at positions ``pos`` (...,), ``inv_freq`` the D / 2 frequencies."""
+    D = x.shape[-1]
+    ang = pos.astype(jnp.float32)[..., None, None] * inv_freq  # (..,1,D/2)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :D // 2], x32[..., D // 2:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+
+
+def rowwise(few, fn, *rows):
+    """``fn(*rows) -> (row-wise outputs, anything else)`` over arrays
+    whose leading axis is the pack's rows.  ``few`` is None (never),
+    or ``(n, flag)``: where the traced bool ``flag`` says that every
+    real row lies in the first ``n``, ``fn`` runs over those rows
+    alone and its row-wise outputs are padded back with zeros.  The
+    program's row count is the token budget, and at a budget of 2,048
+    a round of 16 decode rows would pay a whole chunk's products.  The
+    pools never pass through the ``cond`` (it would copy them): writes
+    and the kernel take all the rows and skip the padding themselves."""
+    if few is None:
+        return fn(*rows)
+    n, flag = few
+    T = rows[0].shape[0]
+
+    def first(*rows):
+        out, rest = fn(*(r[:n] for r in rows))
+        return jax.tree.map(
+            lambda o: jnp.pad(o, ((0, T - n),)
+                              + ((0, 0),) * (o.ndim - 1)), out), rest
+
+    return jax.lax.cond(flag, first, fn, *rows)
 
 
 def cached_attention(q, ck, cv, t, pad_lens=None):
@@ -305,6 +389,55 @@ def ragged_write(pool, chunk, table, row_seq, row_pos, layer=None):
         if layer is not None:
             return pool.at[layer, pb, off].set(chunk.astype(pool.dtype))
         return pool.at[pb, off].set(chunk.astype(pool.dtype))
+
+
+def slot_write(pool, chunk, row_seq, row_pos, layer):
+    """Write a flattened ragged chunk (T, ...) into a leaf that does not
+    page, a whole stack's ``(L, slots, rows, ...)``: each row at ``[layer,
+    row_seq, row_pos mod rows]``, in place; a padding row (row_pos < 0)
+    is dropped."""
+    with jax.named_scope("kv_write"):
+        S, R = pool.shape[1:3]
+        seq = jnp.where(row_pos >= 0, jnp.clip(row_seq, 0, S - 1), S)
+        return pool.at[layer, seq, row_pos % R].set(
+            chunk.astype(pool.dtype), mode="drop")
+
+
+def eva_summarize(win_k, win_v, phi, mu, seq, chunk_at, *, chunk, scale,
+                  layer=None):
+    """The summaries ``(k~, v~)``, each (N, nh, hd), of the N chunks
+    ``chunk_at`` (index inside the window) of slots ``seq``, read from
+    the window leaf (ops/eva_summarize.py).  Dispatched like
+    ``ragged_attention``."""
+    from ..ops.eva_summarize import eva_summarize_ref, eva_summarize_rows
+    use, interp = _pallas_dispatch()
+    if use:
+        return eva_summarize_rows(win_k, win_v, phi, mu, seq, chunk_at,
+                                  chunk=chunk, scale=scale, layer=layer,
+                                  interpret=interp)
+    return eva_summarize_ref(win_k, win_v, phi, mu, seq, chunk_at,
+                             chunk=chunk, scale=scale, layer=layer)
+
+
+def ragged_eva_attention(q, window, summaries, table, row_seq, row_pos, *,
+                         chunk, scale, layer=None):
+    """EVA attention for a flattened ragged pack: q (T, nh, hd) over
+    ``window`` = (K, V) of a leaf that does not page (slots, W, nh, hd)
+    and ``summaries`` = (K~, V~) of a leaf paged by chunk (NB+1, bs, nh,
+    hd) — or whole stacks' and ``layer``, read in place; one softmax over
+    a row's window rows [0, pos mod W] and the summaries of every earlier
+    window (ops/ragged_eva_attention.py).  Dispatched like
+    ``ragged_attention``."""
+    from ..ops.ragged_eva_attention import (ragged_eva_attention_ref,
+                                            ragged_eva_attention_rows)
+    use, interp = _pallas_dispatch()
+    if use:
+        return ragged_eva_attention_rows(
+            q, *window, *summaries, table, row_seq, row_pos, chunk=chunk,
+            scale=scale, layer=layer, interpret=interp)
+    return ragged_eva_attention_ref(
+        q, *window, *summaries, table, row_seq, row_pos, chunk=chunk,
+        scale=scale, layer=layer)
 
 
 def write_cache(cache, chunk, t):

@@ -59,7 +59,8 @@ from ..nn.layer.base import Layer
 from ..ops.moe import gated_mlp, held_experts_ffn, route_sigmoid_topk
 from ._decode import (CacheLeaf, CacheSpec, CausalDecoderMixin, build_pools,
                       ragged_index_select, ragged_latent_attention,
-                      ragged_sparse_latent_attention, ragged_write)
+                      ragged_sparse_latent_attention, ragged_write, rms_norm,
+                      rope_rotate_half, rowwise)
 
 _MLA = ("ln1_w", "q_a_w", "q_a_norm_w", "q_b_w", "kv_a_w", "kv_a_norm_w",
         "kv_b_w", "o_w", "ln2_w", "ln3_w", "ln4_w")
@@ -303,10 +304,7 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
     # ------------------------------------------------------ pure functions
 
     def _rms(self, x, w):
-        x32 = x.astype(jnp.float32)
-        y = x32 * jax.lax.rsqrt(
-            jnp.mean(x32 * x32, -1, keepdims=True) + self.config.rms_norm_eps)
-        return (y * w.astype(jnp.float32)).astype(x.dtype)
+        return rms_norm(x, w, self.config.rms_norm_eps)
 
     def _rope(self, x, pos):
         """Rotate-half rotary positions over the last axis of x (..., D)
@@ -320,12 +318,7 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         else:
             inv = jnp.asarray(yarn_inv_freq(D, c.rope_theta,
                                             **c.rope_scaling), jnp.float32)
-        ang = pos.astype(jnp.float32)[..., None, None] * inv   # (..,1,D/2)
-        cos, sin = jnp.cos(ang), jnp.sin(ang)
-        x32 = x.astype(jnp.float32)
-        x1, x2 = x32[..., :D // 2], x32[..., D // 2:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                               -1).astype(x.dtype)
+        return rope_rotate_half(x, pos, inv)
 
     def _stack(self, params, stack):
         return {n: params[f"{stack}_{n}"]
@@ -445,29 +438,7 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
             return jnp.take(params["wte"], toks, axis=0)[None].astype(
                 jnp.dtype(self.config.compute_dtype))
 
-    @staticmethod
-    def _rowwise(few, fn, *rows):
-        """``fn(*rows) -> (row-wise outputs, anything else)`` over arrays
-        whose leading axis is the pack's rows.  ``few`` is None (never),
-        or ``(n, flag)``: where the traced bool ``flag`` says that every
-        real row lies in the first ``n``, ``fn`` runs over those rows
-        alone and its row-wise outputs are padded back with zeros.  The
-        program's row count is the token budget, and at a budget of 2,048
-        a round of 16 decode rows would pay a whole chunk's products.  The
-        pools never pass through the ``cond`` (it would copy them): writes
-        and the kernel take all the rows and skip the padding themselves."""
-        if few is None:
-            return fn(*rows)
-        n, flag = few
-        T = rows[0].shape[0]
-
-        def first(*rows):
-            out, rest = fn(*(r[:n] for r in rows))
-            return jax.tree.map(
-                lambda o: jnp.pad(o, ((0, T - n),)
-                                  + ((0, 0),) * (o.ndim - 1)), out), rest
-
-        return jax.lax.cond(flag, first, fn, *rows)
+    _rowwise = staticmethod(rowwise)    # models/_decode.py
 
     def _block_ragged(self, sl, x, pool, layer, table, row_seq, row_pos,
                       pad_lens, expert, few=None):

@@ -60,7 +60,8 @@ from .telemetry import (PHASE_ADMIT, PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC,
                         PHASE_UNPACK)
 from .models._decode import (PagedKV, apply_repetition_penalty,
                              build_pools, greedy_verify, seed_presence,
-                             suppress_eos, suppress_eos_rows)
+                             spec_leaves, suppress_eos, suppress_eos_rows,
+                             tokens_per_row)
 from .ops.ragged_paged_attention import grouped_rows
 
 __all__ = ["PagedContinuousBatchingEngine",
@@ -129,9 +130,16 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
             self._require_kv_layout("kv_store")
         self.kv_store = kv_store
         self._kv_meta = None           # kv_page_meta() computes it once
-        self.bs = int(block_size)
-        if self.bs < 1:
+        # a block is ``block_size`` ROWS of every leaf on the table and
+        # names ``bs`` POSITIONS: the same number unless the model states
+        # a leaf that pages by chunk (``CacheLeaf.tokens_per_row``) — the
+        # allocator, the table's width and the pack count in positions
+        self.block_rows = int(block_size)
+        if self.block_rows < 1:
             raise ValueError("block_size must be >= 1")
+        self.bs = self.block_rows * tokens_per_row(self.cache_spec)
+        if self.prefix_caching:
+            self._require_token_leaves("enable_prefix_cache")
         if max_len % self.bs:
             raise ValueError(f"block_size ({self.bs}) must divide "
                              f"max_len ({max_len})")
@@ -143,14 +151,21 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         super().__init__(model, params, max_slots, max_len, **kw)
         if self.tracer is not None:     # static, so said once, not a tick
             leaves = [leaf.nbytes for leaf in jax.tree.leaves(self.caches)]
+            how = {}
+            if not self._token_leaves():
+                # how each leaf is addressed, where the spec says so
+                how["leaf_rows"] = [
+                    f"slot/{leaf.slot_rows}" if leaf.slot_rows
+                    else f"table/{leaf.tokens_per_row}"
+                    for leaf in spec_leaves(self.cache_spec)]
             self.tracer.emit(
                 "cache", engine=type(self).__name__,
                 layout=self.cache_spec.layout, pool_bytes=sum(leaves),
-                leaf_bytes=leaves)      # in the spec's order
-        bad = [b for b in self.buckets if b % self.bs]
+                leaf_bytes=leaves, **how)      # in the spec's order
+        bad = [b for b in self.buckets if b % self.block_rows]
         if bad:
-            raise ValueError(f"block_size ({self.bs}) must divide every "
-                             f"prompt bucket; doesn't divide {bad}")
+            raise ValueError(f"block_size ({self.block_rows}) must divide "
+                             f"every prompt bucket; doesn't divide {bad}")
         # block 0 is trash; real ids are 1..NB
         self._free = list(range(self.NB, 0, -1))      # pop() -> 1, 2, …
         self._table = np.zeros((self.S, self.MB), np.int32)
@@ -200,7 +215,8 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         block_size) + tail``.  The paged-speculative composition builds a
         second set for the draft — SAME allocator and tables, different
         pool storage."""
-        return build_pools(spec, (self.NB + 1, self.bs))
+        return build_pools(spec, (self.NB + 1, self.block_rows),
+                           slots=self.S)
 
     def _alloc_caches(self):
         return self._build_pool(self.cache_spec)
@@ -213,6 +229,26 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
                 f"{what} is written for the K/V cache layout; "
                 f"{type(self.model).__name__} caches "
                 f"{self.cache_spec.layout!r} leaves (docs/CACHE_SPEC.md)")
+
+    def _token_leaves(self) -> bool:
+        """Every leaf one row per position on the block table, and no
+        boundary a pack must respect: what the prefix cache and the
+        speculative rollback count in."""
+        spec = self.cache_spec
+        return not spec.row_boundary and all(
+            leaf.tokens_per_row == 1 and not leaf.slot_rows
+            for leaf in spec_leaves(spec))
+
+    def _require_token_leaves(self, what: str):
+        """Prefix sharing and draft verification are keyed on blocks of
+        per-token rows; a model with a leaf that does not page, or one that
+        pages by chunk, is refused by name."""
+        if not self._token_leaves():
+            raise NotImplementedError(
+                f"{what} is written for leaves of one row per token on the "
+                f"block table; {type(self.model).__name__} caches "
+                f"{self.cache_spec.layout!r} leaves, of which one does not "
+                f"page or pages by chunk (docs/CACHE_SPEC.md)")
 
     def _paged_sig_suffix(self):
         from .core.flags import flag
@@ -1232,6 +1268,8 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                     "ragged speculation does not support "
                     "repetition_penalty/min_new_tokens yet")
         super().__init__(model, params, max_slots, max_len, **kw)
+        if draft_model is not None:
+            self._require_token_leaves("draft_model")
         rows_per_slot = (self.K + 1) if draft_model is not None else 1
         tb = (int(token_budget) if token_budget is not None
               else int(max_slots) * rows_per_slot + max(self.buckets))
@@ -1451,6 +1489,13 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                 break
             st = self._filling[slot]
             want = min(st["P"] - st["filled"], T - n)
+            edge = self.cache_spec.row_boundary
+            if edge:
+                # the model's boundary, counted from the first real row:
+                # a pack holds no rows of one sequence from both sides
+                done = max(st["filled"] - st["pad"], 0)
+                want = min(want, st["pad"] + (done // edge + 1) * edge
+                           - st["filled"])
             have = int(self._nblk[slot])
             if have * self.bs < st["filled"] + want:
                 # grant what the pool can actually cover in ONE

@@ -512,3 +512,80 @@ def test_ragged_kernel_is_called_under_its_own_name(v5e):
     assert names and all(
         "/ragged_paged_attention/ragged_paged_attention/" in n
         for n in names)
+
+
+@pytest.mark.parametrize("rows,cols", [(2176, 128), (2176, 8), (6, 128)],
+                         ids=["chunk-C128", "chunk-C8", "decode"])
+def test_eva_kernels_compile_at_real_widths(v5e, rows, cols):
+    """The two kernels of EVA attention at EvaByte's widths (32 heads of
+    128, a 2,048-row window, 16-row chunks, bfloat16): a window leaf that
+    does not page and a summary leaf paged by chunk, whole stacks read in
+    place by layer."""
+    from paddle_tpu.models._decode import (eva_summarize,
+                                           ragged_eva_attention)
+    L, S, W, nh, hd, bs, NB = 16, 6, 2048, 32, 128, 16, 768
+    win = on_one(v5e, (L, S, W, nh, hd), jnp.bfloat16)
+    sums = on_one(v5e, (L, NB + 1, bs, nh, hd), jnp.bfloat16)
+    q = on_one(v5e, (rows, nh, hd), jnp.bfloat16)
+    per_row = on_one(v5e, (rows,), jnp.int32)
+    table = on_one(v5e, (S, cols), jnp.int32)
+    layer = on_one(v5e, (), jnp.int32)
+
+    def attend(q, wk, wv, sk, sv, table, seq, pos, layer):
+        return ragged_eva_attention(q, (wk, wv), (sk, sv), table, seq, pos,
+                                    chunk=16, scale=hd ** -0.5, layer=layer)
+    compiled = compile_for(attend, q, win, win, sums, sums, table, per_row,
+                           per_row, layer)
+    names = kernel_op_names(compiled.as_text())
+    assert names and all(
+        "/ragged_eva_attention/ragged_eva_attention/" in n for n in names)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+    n = rows // 16 + S
+    vec = on_one(v5e, (nh, hd), jnp.bfloat16)
+    closed = on_one(v5e, (n,), jnp.int32)
+
+    def close(wk, wv, phi, mu, seq, at, layer):
+        return eva_summarize(wk, wv, phi, mu, seq, at, chunk=16,
+                             scale=hd ** -0.5, layer=layer)
+    names = kernel_op_names(compile_for(
+        close, win, win, vec, vec, closed, closed, layer).as_text())
+    assert names and all("/eva_summarize/eva_summarize/" in n
+                         for n in names)
+
+
+def test_eva_serving_tick_compiles_at_the_cells_shape(v5e):
+    """The whole tick of ``evabyte-serve-bytedocs`` — the configuration
+    file, the traffic file's engine, the program's own tick builder — for
+    a described v5e: it fits (arguments + temporaries under 15.0 GB),
+    holds both leaves once, and calls each kernel once (one rolled
+    layer)."""
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmarks.lib import harness, serve_eva, weights_evabyte
+    cfg = harness.load_json("configs", "evabyte-6.5b-pp2.json")
+    eng = harness.load_json("traffic", "bytedocs-backlog.json")["engine"]
+    params = {n: on_one(v5e, shape, jnp.bfloat16)
+              for n, (shape, _) in weights_evabyte.param_table(cfg).items()}
+    engine = serve_eva.build_engine(
+        cfg, dict(eng, num_blocks=1, max_slots=1), {}, None)
+    engine.NB, engine.S = eng["num_blocks"], eng["max_slots"]
+    C = eng["max_len"] // serve_eva.block_positions(cfg, eng)
+    assert C == engine.MB == 128
+    args = jax.eval_shape(lambda: engine._ragged_scratch_args(C))
+    args = jax.tree.map(
+        lambda a: on_one(v5e, a.shape, a.dtype) if hasattr(a, "shape")
+        else a, (params,) + tuple(args[1:]))
+    with jax.default_matmul_precision("default"):
+        compiled = engine._build_ragged_step(
+            eng["token_budget"], C).lower(*args).compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9, ma
+    row = 2 * 32 * 128 * 2              # K and V of a row, bfloat16
+    leaves = 16 * row * (eng["max_slots"] * 2048
+                         + (eng["num_blocks"] + 1) * 16)
+    assert ma.alias_size_in_bytes >= leaves         # donated, held once
+    names = kernel_op_names(compiled.as_text())
+    for stem in ("ragged_eva_attention", "eva_summarize"):
+        assert sum(f"/{stem}/{stem}/" in n for n in names) == 1, names
