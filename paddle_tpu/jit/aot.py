@@ -26,8 +26,9 @@ path.  This module is the TPU-native equivalent for a framework whose
    and step builders declare their compile grid (``engine.compile_grid()``
    enumerates the bucket/table-width program families behind
    ``serving_paged.py`` — the ragged engine's grid is one program per
-   (token_budget, table-width) bucket whether or not a draft model is
-   attached: speculation swaps the family, it never widens the grid;
+   (token_budget, table-width) bucket, plus one narrow program for the
+   rounds of decode rows only; with a draft model attached speculation
+   swaps the family and keeps one row count, it never widens the grid;
    training steps AOT-compile via
    :func:`compile_aot`), and the planner precompiles it — optionally on a
    background thread — before traffic.  Progress reports through the

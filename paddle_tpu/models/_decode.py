@@ -125,7 +125,15 @@ def rowwise(few, fn, *rows):
     program's row count is the token budget, and at a budget of 2,048
     a round of 16 decode rows would pay a whole chunk's products.  The
     pools never pass through the ``cond`` (it would copy them): writes
-    and the kernel take all the rows and skip the padding themselves."""
+    and the kernel take all the rows and skip the padding themselves.
+
+    What it costs (PERF.md section 6, PR 39): every array ``fn`` closes
+    over is an operand of the ``conditional``, an operand has to be a
+    buffer, and so inside a rolled layer scan each layer's weights are
+    copied out of their stack in EVERY round, whichever branch runs.
+    The remedy is a program of the few rows' own, chosen by the host
+    (``ragged_narrow_rounds``, the ragged engine's ``narrow_rows``); the
+    latent model is the one caller left, until it moves there."""
     if few is None:
         return fn(*rows)
     n, flag = few
@@ -947,6 +955,12 @@ class CausalDecoderMixin:
                            params["wpe"].shape[0] - 1)
             h = jnp.take(params["wte"], toks, axis=0) + params["wpe"][pos]
             return h[None].astype(dt)
+
+    # the ragged engine builds this tick a second time at a few rows —
+    # its slots, rounded up to 8 — and dispatches a round of decode rows
+    # only to that program: the host knows the pack before it dispatches.
+    # A class whose tick must stay one program at one width says False
+    ragged_narrow_rounds = True
 
     def decode_ragged(self, params, h, pools, table, row_seq, row_pos,
                       pad_lens):

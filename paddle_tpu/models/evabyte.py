@@ -45,7 +45,7 @@ from ..nn.layer.base import Layer
 from ..ops.moe import gated_mlp
 from ._decode import (CacheLeaf, CacheSpec, CausalDecoderMixin, build_pools,
                       eva_summarize, ragged_eva_attention, ragged_write,
-                      rms_norm, rope_rotate_half, rowwise, slot_write)
+                      rms_norm, rope_rotate_half, slot_write)
 
 _BLOCK = ("ln1_w", "qkv_w", "o_w", "adaptive_phi", "adaptive_mu_k",
           "ln2_w", "gate_w", "up_w", "down_w")
@@ -197,8 +197,7 @@ class EvaByteModel(CausalDecoderMixin, Layer):
             return jnp.take(params["wte"], toks, axis=0)[None].astype(
                 jnp.float32)
 
-    def _block_ragged(self, sl, x, pools, layer, table, seq, pos, closed,
-                      few):
+    def _block_ragged(self, sl, x, pools, layer, table, seq, pos, closed):
         """One block for a flattened pack x (T, H) float32 over layer
         ``layer`` of both leaves, in place: write the rows' rotated K and
         V into the window leaf, close every chunk whose last row is here
@@ -212,25 +211,17 @@ class EvaByteModel(CausalDecoderMixin, Layer):
         window, sums = pools
         c_seq, c_at = closed
 
-        def project(x, pos):
-            a = self._rms(x, sl["ln1_w"]).astype(dt)
-            qkv = (a @ sl["qkv_w"].astype(dt)).reshape(-1, 3, nh, hd)
-            at = jnp.maximum(pos, 0)
-            return (self._rope(qkv[:, 0], at), self._rope(qkv[:, 1], at),
-                    qkv[:, 2]), ()
-
-        def finish(x, o):
-            with jax.named_scope("attn"):
-                x = x + (o.reshape(-1, nh * hd)
-                         @ sl["o_w"].astype(dt)).astype(jnp.float32)
-            with jax.named_scope("mlp"):
-                b = self._rms(x, sl["ln2_w"]).astype(dt)
-                x = x + gated_mlp(b, sl["gate_w"], sl["up_w"],
-                                  sl["down_w"]).astype(jnp.float32)
-            return (x,), ()
-
         with jax.named_scope("attn"):
-            (q, k, v), _ = rowwise(few, project, x, pos)
+            a = self._rms(x, sl["ln1_w"]).astype(dt)
+            # cut into thirds BEFORE the heads are split off: reshaped
+            # first, the compiler moves the reshape onto the weight and
+            # copies the layer's ``qkv_w`` out of its stack, transposed,
+            # every round (tests/test_aot_tpu_compile.py holds it)
+            qkv = a @ sl["qkv_w"].astype(dt)
+            q, k, v = (qkv[:, i * nh * hd:(i + 1) * nh * hd]
+                       .reshape(-1, nh, hd) for i in range(3))
+            at = jnp.maximum(pos, 0)
+            q, k = self._rope(q, at), self._rope(k, at)
             window = tuple(slot_write(w, r, seq, pos, layer)
                            for w, r in zip(window, (k, v)))
             in_window = jnp.maximum(c_at, 0) % (c.window_size // c.chunk_size)
@@ -243,7 +234,12 @@ class EvaByteModel(CausalDecoderMixin, Layer):
             o = ragged_eva_attention(
                 q, window, sums, table, seq, pos, chunk=c.chunk_size,
                 scale=self._scale, layer=layer)
-        (x,), _ = rowwise(few, finish, x, o)
+            x = x + (o.reshape(-1, nh * hd)
+                     @ sl["o_w"].astype(dt)).astype(jnp.float32)
+        with jax.named_scope("mlp"):
+            b = self._rms(x, sl["ln2_w"]).astype(dt)
+            x = x + gated_mlp(b, sl["gate_w"], sl["up_w"],
+                              sl["down_w"]).astype(jnp.float32)
         return x, (window, sums)
 
     def decode_ragged(self, params, h, pools, table, row_seq, row_pos,
@@ -273,14 +269,12 @@ class EvaByteModel(CausalDecoderMixin, Layer):
         named = rows < T
         at = jnp.minimum(rows, T - 1)
         closed = (seq[at], jnp.where(named, pos[at] // c.chunk_size, -1))
-        # a round of decode rows only: see ``_decode.rowwise``
-        few = (S, jnp.all(row_pos[S:] < 0)) if T > 2 * S else None
         stacked = {n: params[f"blocks_{n}"] for n in _BLOCK}
 
         def body(carry, xs):
             sl, i = xs
             return self._block_ragged(sl, *carry, i, table, seq, pos,
-                                      closed, few), None
+                                      closed), None
 
         with jax.named_scope("layers"):
             (x, pools), _ = jax.lax.scan(

@@ -1186,11 +1186,20 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
       step.
 
     Compiled-program count: one program per (token_budget, table-width
-    bucket) — at most log2(max_len/block_size) + 1 programs TOTAL,
-    regardless of prompt buckets, prefix depths, or arrival patterns.
-    Because only packed rows are computed, there are no parked clocks and
-    no inactive-row trash gating: every row in the program is a real
-    token.
+    bucket) — at most log2(max_len/block_size) + 1 programs, regardless
+    of prompt buckets, prefix depths, or arrival patterns — and ONE
+    narrow program more: a round that carries decode rows only (no
+    prefill chunk, no verify rows: at most one row a slot) runs the same
+    tick built at ``narrow_rows`` rows — ``max_slots`` rounded up to a
+    multiple of 8 — where the budget is over twice that and the model
+    takes it (``ragged_narrow_rounds``, beside its ``decode_ragged``).
+    The host knows what a pack holds before it dispatches, so the row
+    count is a key of the program, as the table width is, and never a
+    branch inside one.  The narrow program is built at the widest table
+    only: the kernels walk a row's own length, so a decode round costs
+    the same at any width, and a program per width would cost a compile
+    each.  Because only packed rows are computed, there are no parked
+    clocks and no inactive-row trash gating.
 
     The allocator (lazy growth, prefix cache, deferral, youngest-first
     preemption) is inherited unchanged from the paged engine; prompts
@@ -1278,6 +1287,12 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                 f"token_budget ({tb}) must cover every decode slot "
                 f"(max_slots={max_slots})")
         self.token_budget = tb
+        # the row count of the decode-only rounds' program (0: none).  The
+        # fused draft+verify program keeps one width
+        narrow = -(-int(max_slots) // 8) * 8
+        self.narrow_rows = narrow if (
+            draft_model is None and tb > 2 * narrow
+            and getattr(model, "ragged_narrow_rounds", True)) else 0
         # per-slot speculation flag (set at admission from the request's
         # effective spec budget) + the add_request validation seam
         self._spec_slot = np.zeros(self.S, bool)
@@ -1292,6 +1307,12 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
     @property
     def ragged_steps(self) -> int:
         return int(self._stats.value("ragged_steps"))
+
+    @property
+    def narrow_steps(self) -> int:
+        """Steps of decode rows only that ran the ``narrow_rows``-row
+        program."""
+        return int(self._stats.value("narrow_steps"))
 
     @property
     def mixed_steps(self) -> int:
@@ -1542,7 +1563,13 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         draft model the same round runs the fused draft+verify program
         instead — still one compiled program per (token_budget,
         table-width) bucket.  The round is five phases, each bracketed
-        by ``tracer.phase`` when a tracer is attached (telemetry.PHASES)."""
+        by ``tracer.phase`` when a tracer is attached (telemetry.PHASES).
+
+        Which program a round runs is chosen here, from the pack: one
+        with no prefill chunk (and so, without a draft, at most one row a
+        slot, packed first) goes to the narrow program, its operands cut
+        to ``narrow_rows`` rows, at the widest table; every other pack to
+        the (token_budget, C) program."""
         phase = self._phases()
         with phase(PHASE_ADMIT):
             self._admit()
@@ -1552,8 +1579,12 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                 return
             (toks, row_seq, row_pos, C, sample_rows, sample_active,
              dec_slots, fill_adv, spec_row0, spec_active) = pack
+            T = self.token_budget
+            narrow = bool(self.narrow_rows) and not fill_adv
+            if narrow:
+                T, C = self.narrow_rows, self.MB
             if self.tracer is not None:
-                self._note_pack(dec_slots, fill_adv, spec_active)
+                self._note_pack(dec_slots, fill_adv, spec_active, T)
         if self.draft_model is not None:
             return self._run_spec_pack(phase, toks, row_seq, row_pos, C,
                                        sample_rows, dec_slots, fill_adv,
@@ -1562,17 +1593,19 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             emitted0 = np.asarray(
                 [len(self._slot_req[s].generated) if self._active[s] else 0
                  for s in range(self.S)], np.int32)
-            run = self._ragged_prog(C)
+            run = self._ragged_prog(C, T)
             n = len(self.caches)
             out = run(
                 self.params, self.caches,
-                jnp.asarray(toks), jnp.asarray(row_seq),
-                jnp.asarray(row_pos), jnp.asarray(self._table[:, :C]),
+                jnp.asarray(toks[:T]), jnp.asarray(row_seq[:T]),
+                jnp.asarray(row_pos[:T]), jnp.asarray(self._table[:, :C]),
                 jnp.asarray(self._pad), jnp.asarray(sample_rows),
                 jnp.asarray(sample_active), jnp.asarray(emitted0),
                 self._next_key(), self._presence, self._plane_operands())
             self.caches, ntok, self._presence = out[:n], out[n], out[n + 1]
             self._stats.add("ragged_steps")
+            if narrow:
+                self._stats.add("narrow_steps")
         with phase(PHASE_SYNC):
             ntok = np.asarray(ntok)
             names = self.cache_spec.tick_stats
@@ -1593,8 +1626,11 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                     self._retire(slot)
             self._advance_fills(fill_adv, ntok)
 
-    def _note_pack(self, dec_slots, fill_adv, spec_active):
-        """Record the pack on the tick in flight, where it is built:
+    def _note_pack(self, dec_slots, fill_adv, spec_active, rows_run):
+        """Record the pack on the tick in flight, where it is built
+        (``rows_run``: the row count of the program it is dispatched to;
+        ``token_budget`` stays the engine's budget, what a round could
+        have carried):
         ``rows`` is one ``[rid, rows, kv_end]`` per sequence — a decode
         row ``[rid, 1, t + 1]``, a verify chunk its K + 1 rows, a prefill
         chunk ``[rid, m, last real position + 1]`` (``m`` counts the
@@ -1616,12 +1652,11 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             decode_rows=len(dec_slots),
             prefill_tokens=int(sum(fill_adv.values())),
             budget_used=sum(r[1] for r in rows),
-            token_budget=self.token_budget, rows=rows)
+            token_budget=self.token_budget, rows_run=rows_run, rows=rows)
         if self.cache_spec.layout == "kv":
             # how many of them ops/ragged_paged_attention.py takes through
             # the MXU as one operand with their neighbours
-            self._tick_note["grouped_rows"] = grouped_rows(
-                rows, self.token_budget)
+            self._tick_note["grouped_rows"] = grouped_rows(rows, rows_run)
 
     def _advance_fills(self, fill_adv, first_tok):
         """After a step: move every filler on by its chunk; a prompt that
@@ -1638,12 +1673,15 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
 
     # ---------------------------------------------------------- programs --
 
-    def _ragged_prog(self, C: int):
+    def _ragged_prog(self, C: int, T: Optional[int] = None):
         """ONE program per (token_budget, table-width bucket) — the whole
-        mixed admission+decode tick, no per-bucket prefill family."""
+        mixed admission+decode tick, no per-bucket prefill family — and
+        the same tick at ``(narrow_rows, MB)`` for rounds of decode rows
+        only."""
+        T = self.token_budget if T is None else T
         return self._cached_prog(
-            ("ragged_step", self.token_budget, C, self._sig),
-            lambda: self._build_ragged_step(self.token_budget, C))
+            ("ragged_step", T, C, self._sig),
+            lambda: self._build_ragged_step(T, C))
 
     def _build_ragged_step(self, T: int, C: int):
         model = self.model
@@ -1854,11 +1892,13 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
     def _warmup_tasks(self):
         """The ragged engine's whole compile grid is ONE program per
         (token_budget, table-width bucket) — pow2_grid(MB) enumerates it
-        completely, so a warmed engine never compiles on the serving
-        path (compile count 0 for ANY arrival pattern).  With a draft
-        model the grid is the same SIZE: the fused draft+verify program
+        — and, where the engine has one, the narrow program of the
+        decode-only rounds at the widest table, so a warmed engine never
+        compiles on the serving path (compile count 0 for ANY arrival
+        pattern).  With a draft model the fused draft+verify program
         replaces the plain one bucket for bucket (speculation adds zero
-        program families — the draft prefills through the same pack)."""
+        program families — the draft prefills through the same pack) and
+        there is no narrow program."""
         from .jit.aot import WarmupTask
         if self.draft_model is not None:
             tasks = [WarmupTask(f"ragged_spec:{self.token_budget}:{C}",
@@ -1868,18 +1908,24 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             tasks = [WarmupTask(f"ragged_step:{self.token_budget}:{C}",
                                 partial(self._warmup_ragged, C))
                      for C in pow2_grid(self.MB)]
+            if self.narrow_rows:
+                tasks.append(WarmupTask(
+                    f"ragged_step:{self.narrow_rows}:{self.MB}",
+                    partial(self._warmup_ragged, self.MB,
+                            self.narrow_rows)))
         if self.prefix_caching:
             # same reasoning as the paged grid: export-side gathers on
             # store-less prefill-role replicas are part of the grid too
             tasks.append(WarmupTask("kvio", self._warmup_kvio))
         return tasks
 
-    def _ragged_scratch_args(self, C: int):
+    def _ragged_scratch_args(self, C: int, T: Optional[int] = None):
         """Scratch operand tuple for one table-width bucket's ragged
-        program: fresh pools (donated and freed), rows all parked on slot
-        0 / the trash table — values are irrelevant, shapes and dtypes
-        ARE the program signature (the purity test lowers through these)."""
-        T, S = self.token_budget, self.S
+        program (``T`` rows: the budget, or ``narrow_rows``): fresh pools
+        (donated and freed), rows all parked on slot 0 / the trash table
+        — values are irrelevant, shapes and dtypes ARE the program
+        signature (the purity test lowers through these)."""
+        T, S = self.token_budget if T is None else T, self.S
         z = jnp.zeros(S, jnp.int32)
         return (self.params, self._alloc_caches(), jnp.zeros(T, jnp.int32),
                 jnp.zeros(T, jnp.int32),
@@ -1889,9 +1935,9 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                 jnp.zeros(S, bool), z, self._warmup_key(),
                 self._scratch_presence(), self._plane_operands())
 
-    def _warmup_ragged(self, C: int):
-        run = self._ragged_prog(C)
-        jax.block_until_ready(run(*self._ragged_scratch_args(C)))
+    def _warmup_ragged(self, C: int, T: Optional[int] = None):
+        run = self._ragged_prog(C, T)
+        jax.block_until_ready(run(*self._ragged_scratch_args(C, T)))
 
     def _ragged_spec_scratch_args(self, C: int):
         """Scratch operands for one fused draft+verify program (fresh
@@ -1916,6 +1962,7 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
 
     METRICS_SCHEMA = {
         "ragged_steps": ("counter", float),
+        "narrow_steps": ("counter", float),
         "mixed_steps": ("counter", float),
         # present only with a draft model (ragged speculation):
         "spec_rounds": ("counter", int),
@@ -1928,6 +1975,7 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
     def metrics(self):
         m = super().metrics()
         m["ragged_steps"] = float(self.ragged_steps)
+        m["narrow_steps"] = float(self.narrow_steps)
         m["mixed_steps"] = float(self.mixed_steps)
         if self.draft_model is not None:
             dt = max(time.monotonic() - self._started, 1e-9)

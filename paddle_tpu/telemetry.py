@@ -22,7 +22,9 @@ Event kinds (each event is one flat JSON-serializable dict):
              ``blocks_allocated``/``blocks_released``/``preemptions``/
              ``prefix_hits``), plus whatever the engine packed this tick:
              ``decode_rows``, ``prefill_tokens``, ``budget_used``/
-             ``token_budget`` and ``rows`` (ragged: one ``[rid, rows,
+             ``token_budget``, ``rows_run`` (the row count of the
+             program the round ran: the budget, or the ragged engine's
+             ``narrow_rows``) and ``rows`` (ragged: one ``[rid, rows,
              kv_end]`` per sequence in the pack, summing to
              ``budget_used``), and ``programs`` (short labels of the
              compiled programs dispatched, e.g. ``ragged_step:12:4``).

@@ -315,13 +315,40 @@ def test_ragged_paged_compiles(v5e, hd, int8, cell, layers):
         assert compiled.memory_analysis().temp_size_in_bytes < 100e6
 
 
+@pytest.mark.parametrize("rows", [8, 16])
+@pytest.mark.parametrize("hd", [64, 128], ids=["hd64", "hd128"])
+def test_ragged_paged_compiles_at_a_decode_rounds_rows(v5e, hd, rows):
+    """A pack narrower than one 128-row grid step — the engine's program
+    for rounds of decode rows only, its slots rounded up to 8 — is one
+    grid step of that many rows, at gpt2-small's lane-slice form (hd 64,
+    the smoke's 8 slots) as at the docs cell's geometry (hd 128, 14
+    slots, 128 columns, 24 layers read in place)."""
+    from paddle_tpu.models._decode import ragged_attention
+    geometry = {k: DOCS[k] for k in ("slots", "cols", "blocks")} \
+        if hd == 128 else {}
+    nh, pool, table, per_slot = pool_args(v5e, hd, False, **geometry)
+    pool = on_one(v5e, (DOCS["layers"],) + pool.shape, pool.dtype)
+    q = on_one(v5e, (rows, nh, hd), jnp.bfloat16)
+    per_row = on_one(v5e, (rows,), jnp.int32)
+    compiled = compile_for(ragged_attention, q, pool, pool, table, per_row,
+                           per_row, per_slot, on_one(v5e, (), jnp.int32))
+    text = compiled.as_text()
+    assert text.count(KERNEL) == 1
+    if hd == 128:
+        assert not whole_pool_copies(text, pool)
+        assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+
+
+@pytest.mark.parametrize("rows", [BUDGET, 8], ids=["budget", "narrow"])
 @pytest.mark.parametrize("hidden,heads,vocab", [(768, 12, 50304),
                                                 (2048, 16, 8192)],
                          ids=["hd64", "hd128"])
-def test_ragged_serving_step_compiles(v5e, hidden, heads, vocab):
+def test_ragged_serving_step_compiles(v5e, hidden, heads, vocab, rows):
     """The engine's whole tick at gpt2-small width and at Cerebras-GPT
     1.3B's (depth cut to two layers, the second one's vocabulary cut
-    too): embed, scatter into the pools, ragged kernel, sampler."""
+    too): embed, scatter into the pools, ragged kernel, sampler — at the
+    budget's rows and at the 8 rows of the program that 8 slots' rounds
+    of decode rows run (``chip_smoke.py`` serves the first at hd 64)."""
     import paddle_tpu as paddle
     from paddle_tpu.models.gpt import GPTConfig, GPTModel
     from paddle_tpu.serving import RaggedPagedContinuousBatchingEngine
@@ -337,9 +364,10 @@ def test_ragged_serving_step_compiles(v5e, hidden, heads, vocab):
     eng = RaggedPagedContinuousBatchingEngine(
         model, params, max_slots=SLOTS, max_len=BLOCK * COLS,
         block_size=BLOCK, prompt_buckets=[64, 128], token_budget=BUDGET)
+    assert eng.narrow_rows == 8 and eng.MB == COLS
     args = jax.tree.map(lambda x: on_one(v5e, x.shape, x.dtype),
-                        eng._ragged_scratch_args(COLS))
-    compiled = eng._build_ragged_step(BUDGET, COLS).lower(*args).compile()
+                        eng._ragged_scratch_args(COLS, rows))
+    compiled = eng._build_ragged_step(rows, COLS).lower(*args).compile()
     assert KERNEL in compiled.as_text()
     # the tick holds its pools once: both are donated into the outputs,
     # and no second copy of a side (nor a layer of one) is a temporary.
@@ -351,6 +379,39 @@ def test_ragged_serving_step_compiles(v5e, hidden, heads, vocab):
     if hidden // heads == 128:
         assert ma.temp_size_in_bytes < side // 2, (ma.temp_size_in_bytes,
                                                    side)
+
+
+def test_narrow_gpt_tick_compiles_at_the_docs_cells_shape(v5e):
+    """The program ``c1p3b-serve-docs`` runs a round of 14 decode rows
+    through: the configuration file, the traffic file's engine, the
+    tick at 16 rows over the widest table (128 columns), all 24 layers.
+    Both pools are donated and held once; what the round reads is the
+    weights and its rows' keys, so the temporaries are small."""
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmarks.lib import harness, program, weights
+    cfg = harness.load_json("configs", "cerebras-gpt-1.3b.json")
+    eng = harness.load_json("traffic", "docs-backlog.json")["engine"]
+    params = {n: on_one(v5e, shape, jnp.bfloat16)
+              for n, (shape, _) in weights.gpt_param_table(cfg).items()}
+    engine = program.build_engine(cfg, dict(eng, num_blocks=1), {}, None)
+    engine.NB = eng["num_blocks"]
+    assert (engine.narrow_rows, engine.MB) == (16, DOCS["cols"])
+    assert engine.compile_grid()[-1] == "ragged_step:16:128"
+    args = jax.eval_shape(
+        lambda: engine._ragged_scratch_args(engine.MB, engine.narrow_rows))
+    args = jax.tree.map(
+        lambda a: on_one(v5e, a.shape, a.dtype) if hasattr(a, "shape")
+        else a, (params,) + tuple(args[1:]))
+    with jax.default_matmul_precision("default"):
+        compiled = engine._build_ragged_step(
+            engine.narrow_rows, engine.MB).lower(*args).compile()
+    ma = compiled.memory_analysis()
+    pools = 2 * DOCS["layers"] * DOCS["blocks"] * BLOCK * 2048 * 2
+    assert ma.alias_size_in_bytes >= pools
+    assert ma.temp_size_in_bytes < 100e6, ma
+    assert compiled.as_text().count(KERNEL) == 1    # one rolled layer
 
 
 @pytest.mark.parametrize("cols", [1, 8, 256, 1040],
@@ -514,8 +575,10 @@ def test_ragged_kernel_is_called_under_its_own_name(v5e):
         for n in names)
 
 
-@pytest.mark.parametrize("rows,cols", [(2176, 128), (2176, 8), (6, 128)],
-                         ids=["chunk-C128", "chunk-C8", "decode"])
+@pytest.mark.parametrize("rows,cols", [(2176, 128), (2176, 8), (6, 128),
+                                       (8, 128), (16, 128)],
+                         ids=["chunk-C128", "chunk-C8", "decode",
+                              "narrow-8", "narrow-16"])
 def test_eva_kernels_compile_at_real_widths(v5e, rows, cols):
     """The two kernels of EVA attention at EvaByte's widths (32 heads of
     128, a 2,048-row window, 16-row chunks, bfloat16): a window leaf that
@@ -554,38 +617,79 @@ def test_eva_kernels_compile_at_real_widths(v5e, rows, cols):
                          for n in names)
 
 
-def test_eva_serving_tick_compiles_at_the_cells_shape(v5e):
+def materialised(text, shapes):
+    """Instructions of compiled HLO OUTSIDE the fused computations whose
+    result has one of ``shapes`` (leading 1s aside): arrays the program
+    writes to memory, as a layer's weight copied out of its stack is."""
+    import re
+    hits, fused = [], False
+    for line in text.splitlines():
+        if line and not line.startswith(" "):       # a computation opens
+            fused = line.startswith("%fused_computation")
+        m = re.match(r"\s+(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]+)\]", line)
+        if not m or fused or " parameter(" in line:
+            continue
+        dims = tuple(int(d) for d in m.group(2).split(","))
+        while len(dims) > 1 and dims[0] == 1:
+            dims = dims[1:]
+        if dims in shapes:
+            hits.append(line.strip()[:120])
+    return hits
+
+
+@pytest.mark.parametrize("rows", ["budget", "narrow"])
+def test_eva_serving_tick_compiles_at_the_cells_shape(v5e, rows):
     """The whole tick of ``evabyte-serve-bytedocs`` — the configuration
     file, the traffic file's engine, the program's own tick builder — for
-    a described v5e: it fits (arguments + temporaries under 15.0 GB),
-    holds both leaves once, and calls each kernel once (one rolled
-    layer)."""
+    a described v5e, at the budget's 2,176 rows and at the 8 rows of the
+    program that rounds of 6 decode rows run: it fits (arguments +
+    temporaries under 15.0 GB), holds both leaves once, and calls each
+    kernel once (one rolled layer).
+
+    And no layer's weights are copied out of their stack: the program has
+    no ``conditional`` (an array a branch closes over is an operand of
+    it, and an operand is a buffer: PR 28 to PR 38 paid 0.4 GB a layer a
+    round for ``_decode.rowwise``), nothing outside a fused computation
+    has a weight matrix's shape (the QKV product reshaped before it was
+    cut into thirds cost a transposed copy of ``qkv_w`` a layer), and the
+    temporaries stay under 0.30 GB (0.389 with the branch, 0.132 now)."""
+    import re
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     from benchmarks.lib import harness, serve_eva, weights_evabyte
     cfg = harness.load_json("configs", "evabyte-6.5b-pp2.json")
     eng = harness.load_json("traffic", "bytedocs-backlog.json")["engine"]
+    table = weights_evabyte.param_table(cfg)
     params = {n: on_one(v5e, shape, jnp.bfloat16)
-              for n, (shape, _) in weights_evabyte.param_table(cfg).items()}
+              for n, (shape, _) in table.items()}
     engine = serve_eva.build_engine(
         cfg, dict(eng, num_blocks=1, max_slots=1), {}, None)
+    assert engine.narrow_rows == 8      # 1 slot or the cell's 6: 8 rows
     engine.NB, engine.S = eng["num_blocks"], eng["max_slots"]
     C = eng["max_len"] // serve_eva.block_positions(cfg, eng)
     assert C == engine.MB == 128
-    args = jax.eval_shape(lambda: engine._ragged_scratch_args(C))
+    T = eng["token_budget"] if rows == "budget" else engine.narrow_rows
+    assert eng["token_budget"] > 2 * engine.narrow_rows
+    args = jax.eval_shape(lambda: engine._ragged_scratch_args(C, T))
     args = jax.tree.map(
         lambda a: on_one(v5e, a.shape, a.dtype) if hasattr(a, "shape")
         else a, (params,) + tuple(args[1:]))
     with jax.default_matmul_precision("default"):
-        compiled = engine._build_ragged_step(
-            eng["token_budget"], C).lower(*args).compile()
+        compiled = engine._build_ragged_step(T, C).lower(*args).compile()
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9, ma
     row = 2 * 32 * 128 * 2              # K and V of a row, bfloat16
     leaves = 16 * row * (eng["max_slots"] * 2048
                          + (eng["num_blocks"] + 1) * 16)
     assert ma.alias_size_in_bytes >= leaves         # donated, held once
-    names = kernel_op_names(compiled.as_text())
+    text = compiled.as_text()
+    names = kernel_op_names(text)
     for stem in ("ragged_eva_attention", "eva_summarize"):
         assert sum(f"/{stem}/{stem}/" in n for n in names) == 1, names
+    assert not re.search(r"\bconditional\(", text)
+    weights = {shape[1:] for n, (shape, _) in table.items()
+               if n.startswith("blocks_") and np.prod(shape[1:]) * 2 > 1e6}
+    assert len(weights) == 4            # QKV, W_o, gate = up, down
+    assert not materialised(text, weights | {s[::-1] for s in weights})
+    assert ma.temp_size_in_bytes < 0.30e9, ma

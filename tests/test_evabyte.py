@@ -261,6 +261,91 @@ def test_a_tracer_changes_nothing(params, prompts):
         == serve(engine(params, None), prompts[:2], 6)
 
 
+def one_width(eng):
+    """The engine with every round through its budget-wide program: what
+    a model that says ``ragged_narrow_rounds = False`` gets."""
+    eng.narrow_rows = 0
+    return eng
+
+
+@pytest.mark.parametrize("case", ["plain", "kernels", "preempted",
+                                  "long_decode"])
+def test_narrow_rounds_give_the_wide_programs_tokens(params, prompts, case,
+                                                     request):
+    """Rounds of decode rows only run the tick at 8 rows (3 slots, a
+    budget of 35): the same tokens as the budget-wide program alone,
+    through the XLA path and the interpreted kernels, across a window's
+    end and a preemption."""
+    if case == "kernels":
+        request.getfixturevalue("interpret")
+    over = dict(num_blocks=13) if case == "preempted" else {}
+    which = prompts[:2] if case == "kernels" else prompts
+    out_len = {"plain": 12, "kernels": 8, "preempted": 40,
+               "long_decode": 2 * W + 5}[case]
+    tr = Tracer()
+    eng = engine(params, tr, **over)
+    assert eng.narrow_rows == 8
+    narrow = serve(eng, which, out_len)
+    wide_eng = one_width(engine(params, **over))
+    assert narrow == serve(wide_eng, which, out_len)
+    assert 0 < eng.narrow_steps < eng.ragged_steps
+    assert wide_eng.narrow_steps == 0
+    if case == "preempted":
+        assert eng.preemptions >= 1
+    ticks = [k for k in tr.events("tick") if k.get("budget_used")]
+    for k in ticks:
+        assert k["token_budget"] == W + 3
+        assert k["rows_run"] == (W + 3 if k["prefill_tokens"] else 8)
+    # a decode row that closes a chunk closes it in the narrow program too
+    assert any(k["rows_run"] == 8 and k["eva_chunks_closed"] for k in ticks)
+    # exactly one program more than the table widths the wide rounds took
+    keys = sorted(k[1:3] for k in eng.model._serving_programs)
+    assert [k for k in keys if k[0] == 8] == [(8, eng.MB)]
+    assert all(k[0] in (8, W + 3) for k in keys)
+
+
+@pytest.mark.parametrize("rows", [8, 16])
+def test_decode_rows_alone_equal_the_same_rows_in_a_wide_pack(params, rows):
+    """The tick at ``rows`` rows against the tick at a chunk's width over
+    the same three decode rows (one of them closing a chunk, one in its
+    third window): hidden states, both leaves and the counters."""
+    model = serve_eva.meta_model(CFG)
+    S, per_block = 3, 4
+    C = 256 // (per_block * CHUNK)
+    table = 1 + jnp.arange(S * C, dtype=jnp.int32).reshape(S, C)
+    rng = np.random.default_rng(3)
+    pools = jax.tree.map(
+        lambda z: jnp.asarray(rng.standard_normal(z.shape) * 0.3, z.dtype),
+        build_pools(model.cache_spec(), (S * C + 1, per_block), slots=S))
+    at = [2 * W + 6, CHUNK * 5 - 1, 40]
+
+    def tick(T):
+        seq = list(range(S)) + [-1] * (T - S)
+        pos = at + [-1] * (T - S)
+        toks = [11, 12, 13] + [0] * (T - S)
+        return _tick(model, params, pools, table, toks, seq, pos)
+
+    few, wide = tick(rows), tick(W + 3)
+    np.testing.assert_allclose(np.asarray(few[0][:S]),
+                               np.asarray(wide[0][:S]), atol=2e-5)
+    for a, b in zip(jax.tree.leaves(few[1]), jax.tree.leaves(wide[1])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+    np.testing.assert_array_equal(few[2], wide[2])
+    assert few[2][2] == 1                 # the one chunk that closed
+
+
+@pytest.mark.parametrize("rows", [8, W + 3], ids=["narrow", "wide"])
+def test_the_tick_has_no_branch(params, rows):
+    """No ``cond`` at either width: an array a branch closes over is an
+    operand of the conditional, and inside the layer scan that copies
+    every layer's weights out of their stack in every round."""
+    eng = engine(params)
+    text = eng._build_ragged_step(rows, eng.MB).lower(
+        *eng._ragged_scratch_args(eng.MB, rows)).as_text()
+    assert "stablehlo.while" in text
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+
+
 @pytest.mark.parametrize("what", ["prefix_cache", "kv_store", "draft",
                                   "bucketed_engine", "generate"])
 def test_refused_by_name(params, what):

@@ -612,3 +612,242 @@ class TestPoolsCarriedWhole:
         for g, w in zip(got, want):
             assert g.shape == w.shape and g.dtype == w.dtype
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+class OneWidthGPT(GPTModel):
+    """A model that declines the narrow program, as the latent model
+    does: every round through the budget-wide tick."""
+    ragged_narrow_rounds = False
+
+
+def _serve_scenario(model, params, scenario, tracer=None):
+    """One of the scenarios below through a 3-slot engine with a 24-row
+    budget (over twice the 8 rows of the narrow program).  Returns
+    (tokens by request in order of admission, the engine)."""
+    kw = dict(max_slots=3, max_len=64, block_size=4,
+              prompt_buckets=[8, 16], token_budget=24, tracer=tracer)
+    if scenario == "preemption":
+        kw.update(max_slots=2, num_blocks=8, prompt_buckets=[8],
+                  max_len=32)
+    if scenario == "prefix_hit":
+        kw.update(prompt_buckets=[16], enable_prefix_cache=True)
+    if scenario == "planes":
+        kw.update(per_request_sampling=True)
+    eng = RaggedPagedContinuousBatchingEngine(model, params, **kw)
+    out = []
+    if scenario == "decode_stretch":
+        rids = [eng.add_request(p, 14) for p in PROMPTS[:3]]
+    elif scenario == "admit_mid_decode":
+        rids = [eng.add_request(PROMPTS[0], 12)]
+        for _ in range(4):
+            eng.step()                  # prefill, then decode-only rounds
+        rids += [eng.add_request(PROMPTS[5], 6),
+                 eng.add_request(PROMPTS[1], 9)]
+    elif scenario == "preemption":
+        rids = [eng.add_request(PROMPTS[0], 14),
+                eng.add_request(PROMPTS[1], 14)]
+    elif scenario == "finish_frees_slot":
+        rids = [eng.add_request(p, n)
+                for p, n in zip(PROMPTS, [10, 4, 7, 12, 3, 8])]
+    elif scenario == "prefix_hit":
+        sysp = list(range(7, 19))
+        rids = [eng.add_request(sysp + [1], 6)]
+        out.append(eng.run_to_completion(max_ticks=200)[rids[0]])
+        rids = [eng.add_request(sysp + [2], 6)]
+    elif scenario == "planes":
+        rids = [eng.add_request(PROMPTS[0], 8),
+                eng.add_request(PROMPTS[1], 7, repetition_penalty=5.0),
+                eng.add_request(PROMPTS[0], 8, min_new_tokens=4,
+                                eos_token_id=PROMPTS[0][1])]
+    got = eng.run_to_completion(max_ticks=500)
+    return out + [got[r] for r in rids], eng
+
+
+class TestNarrowProgram:
+    """A round of decode rows only runs the tick built at ``narrow_rows``
+    rows (PR 39): the host chooses the program from the pack, and the
+    tokens are what the budget-wide program alone serves."""
+
+    @pytest.fixture(scope="class")
+    def one_width(self, model_and_params):
+        model, params = model_and_params
+        return OneWidthGPT(model.config), params
+
+    @pytest.mark.parametrize("scenario", [
+        "decode_stretch", "admit_mid_decode", "preemption",
+        "finish_frees_slot", "prefix_hit", "planes"])
+    def test_same_tokens_with_and_without(self, scenario, model_and_params,
+                                          one_width):
+        from paddle_tpu.telemetry import Tracer
+        tr = Tracer()
+        narrow, eng = _serve_scenario(*model_and_params, scenario, tr)
+        wide, eng_w = _serve_scenario(*one_width, scenario)
+        assert narrow == wide
+        assert eng.narrow_rows == 8 and eng_w.narrow_rows == 0
+        assert 0 < eng.narrow_steps < eng.ragged_steps
+        assert eng_w.narrow_steps == 0
+        assert eng_w.ragged_steps == eng.ragged_steps
+        if scenario == "preemption":
+            assert eng.preemptions >= 1
+        if scenario == "prefix_hit":
+            assert eng.prefix_hits >= 1
+        # a pack with no chunk went narrow, any pack with one wide; the
+        # event's budget is the engine's whichever program ran
+        ticks = [k for k in tr.events("tick") if k.get("budget_used")]
+        assert len(ticks) == eng.ragged_steps
+        for k in ticks:
+            assert k["token_budget"] == 24
+            assert k["rows_run"] == (24 if k["prefill_tokens"] else 8)
+            assert k["budget_used"] <= k["rows_run"]
+        assert sum(k["rows_run"] == 8 for k in ticks) == eng.narrow_steps
+        m = eng.metrics()
+        assert m["narrow_steps"] == eng.narrow_steps
+        assert m["ragged_steps"] == eng.ragged_steps
+
+    def test_exactly_one_program_more(self, model_and_params, one_width):
+        """The narrow program is ONE, at the widest table, whatever
+        widths the traffic reaches; the grid names it and a warmed engine
+        compiles nothing when a decode-only round comes."""
+        from paddle_tpu.serving_paged import pow2_grid
+        model, params = model_and_params
+        served = {}
+        for m in (model, one_width[0]):
+            m.__dict__.pop("_serving_programs", None)
+            _, eng = _serve_scenario(m, params, "finish_frees_slot")
+            served[type(m).__name__] = (
+                sorted(k[1:3] for k in m._serving_programs), eng)
+        keys_w, eng_w = served["OneWidthGPT"]
+        keys_n, eng = served["GPTModel"]
+        assert all(T == 24 for T, _ in keys_w)
+        # the one narrow program, and of the wide ones no more than before
+        # (a width that only decode-only rounds reached is never built)
+        assert [k for k in keys_n if k[0] != 24] == [(8, eng.MB)]
+        assert set(keys_n) - {(8, eng.MB)} <= set(keys_w)
+        wide_grid = [f"ragged_step:24:{C}" for C in pow2_grid(eng.MB)]
+        assert eng_w.compile_grid() == wide_grid
+        assert eng.compile_grid() == wide_grid + [f"ragged_step:8:{eng.MB}"]
+        model.__dict__.pop("_serving_programs", None)
+        _, fresh = _serve_scenario(model, params, "prefix_hit")
+        fresh.warmup()
+        misses = fresh._compile_misses
+        fresh.add_request(PROMPTS[3], 9)
+        fresh.run_to_completion(max_ticks=100)
+        assert fresh.narrow_steps and fresh._compile_misses == misses
+
+    def test_a_budget_within_twice_the_slots_has_no_narrow_program(
+            self, model_and_params):
+        model, params = model_and_params
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=3, max_len=32, block_size=4,
+            prompt_buckets=[8], token_budget=16)
+        assert eng.narrow_rows == 0
+        eng.add_request(PROMPTS[0], 6)
+        eng.run_to_completion(max_ticks=100)
+        assert eng.narrow_steps == 0 and eng.ragged_steps >= 6
+
+    def test_the_spec_engine_compiles_what_it_did(self, model_and_params):
+        """The fused draft+verify program keeps one width: the grid is one
+        ``ragged_spec`` program a table width and nothing else runs."""
+        from paddle_tpu.serving_paged import pow2_grid
+        model, params = model_and_params
+        paddle.seed(77)
+        draft = GPTModel(GPTConfig(
+            vocab_size=97, hidden_size=32, num_layers=1,
+            num_attention_heads=4, max_position_embeddings=96,
+            compute_dtype="float32"))
+        model.__dict__.pop("_serving_programs", None)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=2, max_len=48, block_size=4,
+            prompt_buckets=[8], token_budget=40, draft_model=draft,
+            draft_params={n: p._data for n, p in draft.named_parameters()},
+            draft_k=3)
+        assert eng.narrow_rows == 0
+        assert eng.compile_grid() == [f"ragged_spec:40:{C}"
+                                      for C in pow2_grid(eng.MB)]
+        rid = eng.add_request(PROMPTS[0], 9)
+        got = eng.run_to_completion(max_ticks=100)
+        assert got[rid] == _solo_greedy(model, params, PROMPTS[0], 9)
+        assert {k[0] for k in model._serving_programs} == {"ragged_spec"}
+        assert eng.narrow_steps == 0
+
+    @pytest.mark.parametrize("rows", [8, 24], ids=["narrow", "wide"])
+    def test_a_model_that_declines_lowers_what_it_did(
+            self, rows, model_and_params, one_width):
+        """What a model says about the narrow program changes which
+        programs its engine builds and not one byte of a program: the
+        tick of ``rows`` rows lowers to the same text from either."""
+        def text(m, params):
+            eng = RaggedPagedContinuousBatchingEngine(
+                m, params, max_slots=3, max_len=64, block_size=4,
+                prompt_buckets=[8, 16], token_budget=24)
+            return eng._build_ragged_step(rows, 4).lower(
+                *eng._ragged_scratch_args(4, rows)).as_text()
+        assert text(*model_and_params) == text(*one_width)
+
+    def test_the_latent_model_declines(self):
+        """``PanguMoeModel`` keeps one width and its in-program branch
+        (PERF.md section 7 item 0): no narrow program in its grid, no
+        round dispatched to one."""
+        from paddle_tpu.models.pangu_moe import (PanguMoeConfig,
+                                                 PanguMoeModel)
+        from paddle_tpu.serving_paged import pow2_grid
+        assert PanguMoeModel.ragged_narrow_rounds is False
+        assert GPTModel.ragged_narrow_rounds is True
+        paddle.seed(0)
+        model = PanguMoeModel(PanguMoeConfig(
+            vocab_size=96, hidden_size=32, num_hidden_layers=2,
+            first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=16,
+            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+            v_head_dim=8, intermediate_size=48, moe_intermediate_size=12,
+            n_routed_experts=4, n_shared_experts=1, num_experts_per_tok=2,
+            max_position_embeddings=128, compute_dtype="float32"))
+        params = {n: p._data for n, p in model.named_parameters()}
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=3, max_len=64, block_size=8,
+            num_blocks=20, token_budget=32,
+            prompt_buckets=list(range(8, 65, 8)))
+        assert eng.narrow_rows == 0
+        assert eng.compile_grid() == [f"ragged_step:32:{C}"
+                                      for C in pow2_grid(eng.MB)]
+        eng.add_request(list(range(1, 12)), 6)
+        eng.run_to_completion(max_ticks=100)
+        assert eng.narrow_steps == 0 and eng.ragged_steps >= 6
+
+    # sha256 of the GPT tick's lowering at the parent of PR 39 (commit
+    # 4946d47), by (interpreted kernel, dtype): 3 slots, 64 positions in
+    # blocks of 8, a 24-row budget, 4 table columns; under the suite's
+    # settings (tests/conftest.py: matmul precision "highest")
+    PARENT_TICK = {
+        (False, "float32"):
+            "f79f44b482f924adb0bb03eef62c17f22959f40850a21bddab01f3f47672877d",
+        (False, "bfloat16"):
+            "b67341de6ae43924fb92e1ff5f366af33268ec5480ea74a37907d656da9418f9",
+        (True, "float32"):
+            "4b7442963aecc77ed20766dd66e60e50a6d4ae69c215bbc1830cb7edc836c89e",
+        (True, "bfloat16"):
+            "4fe15a60b509b22d23ed289e194b6b62114b0344a67cad7554c745ef590056f2",
+    }
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("interp", [False, True], ids=["xla", "kernel"])
+    def test_the_wide_gpt_tick_lowers_as_at_the_parent(self, interp, dtype):
+        """A round with a chunk runs the program it ran: the narrow
+        program is one more key, not a change to the budget-wide tick."""
+        import hashlib
+        paddle.seed(3)
+        model = GPTModel(GPTConfig(
+            vocab_size=97, hidden_size=32, num_layers=2,
+            num_attention_heads=4, max_position_embeddings=96,
+            compute_dtype=dtype))
+        params = {n: p._data for n, p in model.named_parameters()}
+        set_flags({"FLAGS_paged_attn_interpret": interp})
+        try:
+            eng = RaggedPagedContinuousBatchingEngine(
+                model, params, max_slots=3, max_len=64, block_size=8,
+                num_blocks=12, token_budget=24)
+            text = eng._build_ragged_step(24, 4).lower(
+                *eng._ragged_scratch_args(4)).as_text()
+        finally:
+            set_flags({"FLAGS_paged_attn_interpret": False})
+        assert hashlib.sha256(text.encode()).hexdigest() \
+            == self.PARENT_TICK[interp, dtype]
