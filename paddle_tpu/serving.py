@@ -48,8 +48,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from .jit.bucketing import select_bucket
-from .telemetry import (PHASE_ADMIT, PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC,
-                        PHASE_UNPACK, program_label)
+from .telemetry import (PART_CALL, PART_KEY, PART_OPERANDS, PHASE_ADMIT,
+                        PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC, PHASE_UNPACK,
+                        program_label)
 from .utils.stats import StatRegistry, stat_add
 from .utils.stats import prometheus_text as _prometheus_text
 from .models._decode import (apply_repetition_penalty, make_row_sampler,
@@ -60,9 +61,14 @@ from .models._decode import (apply_repetition_penalty, make_row_sampler,
 __all__ = ["ContinuousBatchingEngine", "Request"]
 
 
+def _no_part(name):
+    pass
+
+
 # what _step_impl enters for each phase of a round when no tracer is
-# attached: one shared object that does nothing
-_NO_PHASE = contextlib.nullcontext()
+# attached: one shared object that does nothing, and hands out a part()
+# that does nothing
+_NO_PHASE = contextlib.nullcontext(_no_part)
 
 
 def _no_phase(name):
@@ -1279,15 +1285,24 @@ class ContinuousBatchingEngine:
             emitted0 = np.asarray(
                 [len(r.generated) if r is not None else 0
                  for r in self._slot_req], np.int32)
-        with phase(PHASE_DISPATCH):
-            run = self._decode_prog_all()
-            ck, cv, blk, self._presence = run(
-                self.params, self.caches[0], self.caches[1],
+        with phase(PHASE_DISPATCH) as part:     # three parts partition it
+            part(PART_OPERANDS)
+            operands = (
                 *self._decode_extra_operands(),
                 jnp.asarray(self._tok), jnp.asarray(self._t),
-                jnp.asarray(self._pad), jnp.asarray(active_before),
-                self._next_key(), self._presence, jnp.asarray(emitted0),
-                self._plane_operands())
+                jnp.asarray(self._pad), jnp.asarray(active_before))
+            emitted0 = jnp.asarray(emitted0)
+            planes = self._plane_operands()
+            part(PART_KEY)
+            key = self._next_key()
+            part(PART_CALL)
+            run = self._decode_prog_all()
+            ck, cv, blk, self._presence = run(
+                self.params, self.caches[0], self.caches[1], *operands,
+                key, self._presence, emitted0, planes)
+            # freed here, as the call's own temporaries were: the phase
+            # keeps its extent
+            del operands, key, emitted0, planes
             self.caches = (ck, cv)
         with phase(PHASE_SYNC):
             blk = np.asarray(blk)

@@ -56,7 +56,8 @@ import numpy as np
 from .serving import ContinuousBatchingEngine
 from .jit.bucketing import pow2_bucket, pow2_grid, select_bucket
 from .kv_store import KVPage, chain_hex
-from .telemetry import (PHASE_ADMIT, PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC,
+from .telemetry import (PART_CALL, PART_KEY, PART_OPERANDS, PART_STATS,
+                        PHASE_ADMIT, PHASE_DISPATCH, PHASE_PACK, PHASE_SYNC,
                         PHASE_UNPACK)
 from .models._decode import (PagedKV, apply_repetition_penalty,
                              build_pools, greedy_verify, seed_presence,
@@ -1589,29 +1590,39 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             return self._run_spec_pack(phase, toks, row_seq, row_pos, C,
                                        sample_rows, dec_slots, fill_adv,
                                        spec_row0, spec_active)
-        with phase(PHASE_DISPATCH):
+        with phase(PHASE_DISPATCH) as part:     # three parts partition it
+            part(PART_OPERANDS)
             emitted0 = np.asarray(
                 [len(self._slot_req[s].generated) if self._active[s] else 0
                  for s in range(self.S)], np.int32)
-            run = self._ragged_prog(C, T)
-            n = len(self.caches)
-            out = run(
-                self.params, self.caches,
+            operands = (
                 jnp.asarray(toks[:T]), jnp.asarray(row_seq[:T]),
                 jnp.asarray(row_pos[:T]), jnp.asarray(self._table[:, :C]),
                 jnp.asarray(self._pad), jnp.asarray(sample_rows),
-                jnp.asarray(sample_active), jnp.asarray(emitted0),
-                self._next_key(), self._presence, self._plane_operands())
+                jnp.asarray(sample_active), jnp.asarray(emitted0))
+            planes = self._plane_operands()
+            part(PART_KEY)
+            key = self._next_key()
+            part(PART_CALL)
+            run = self._ragged_prog(C, T)
+            n = len(self.caches)
+            out = run(self.params, self.caches, *operands, key,
+                      self._presence, planes)
+            # freed here, as the call's own temporaries were: the phase
+            # keeps its extent
+            del operands, key, planes
             self.caches, ntok, self._presence = out[:n], out[n], out[n + 1]
             self._stats.add("ragged_steps")
             if narrow:
                 self._stats.add("narrow_steps")
-        with phase(PHASE_SYNC):
+        with phase(PHASE_SYNC) as part:
             ntok = np.asarray(ntok)
             names = self.cache_spec.tick_stats
             if names and self.tracer is not None:
                 # the model's own counters for this tick (one small
-                # vector), read with the tokens; never without a tracer
+                # vector), read after the tokens; never without a tracer,
+                # so what the read costs is tracing's own: PART_STATS
+                part(PART_STATS)
                 self._tick_note.update(
                     zip(names, np.asarray(out[n + 2]).tolist()))
         with phase(PHASE_UNPACK):
@@ -1653,6 +1664,9 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             prefill_tokens=int(sum(fill_adv.values())),
             budget_used=sum(r[1] for r in rows),
             token_budget=self.token_budget, rows_run=rows_run, rows=rows)
+        # the round's kind, on every span from here on: with a chunk
+        # where > 0, decode rows only otherwise
+        self.tracer.span_stats(chunk_rows=self._tick_note["prefill_tokens"])
         if self.cache_spec.layout == "kv":
             # how many of them ops/ragged_paged_attention.py takes through
             # the MXU as one operand with their neighbours
@@ -1745,17 +1759,21 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         round's last three phases (see ``_step_impl``)."""
         K = self.K
         n_spec = int(spec_active.sum())
-        with phase(PHASE_DISPATCH):
-            run = self._ragged_spec_prog(C)
-            n = len(self.caches)
-            *pools, lead, block = run(
-                (self.params, self.draft_params), self.caches,
-                self.draft_caches,
+        with phase(PHASE_DISPATCH) as part:     # greedy: no key, two parts
+            part(PART_OPERANDS)
+            operands = (
                 jnp.asarray(toks), jnp.asarray(row_seq),
                 jnp.asarray(row_pos), jnp.asarray(self._table[:, :C]),
                 jnp.asarray(self._pad), jnp.asarray(sample_rows),
                 jnp.asarray(spec_row0), jnp.asarray(spec_active),
                 jnp.asarray(self._tok), jnp.asarray(self._t))
+            part(PART_CALL)
+            run = self._ragged_spec_prog(C)
+            n = len(self.caches)
+            *pools, lead, block = run(
+                (self.params, self.draft_params), self.caches,
+                self.draft_caches, *operands)
+            del operands    # freed here, as the call's temporaries were
             self.caches = tuple(pools[:n])
             self.draft_caches = tuple(pools[n:])
             self._stats.add("ragged_steps")

@@ -15,7 +15,9 @@ Event kinds (each event is one flat JSON-serializable dict):
              request events emitted inside it carry the same number),
              ``dur_s`` (host wall time), ``phases`` (``{name: seconds}``
              of the ``engine.*`` phases that partition the round, see
-             ``Tracer.phase``), ``queue_depth``, ``active``
+             ``Tracer.phase``), ``parts`` (likewise, of the parts of
+             those phases that the round entered: ``PARTS``),
+             ``queue_depth``, ``active``
              (decoding slots), ``filling`` (prompts mid-prefill), per-tick
              deltas of the engine counters (``tokens_emitted``,
              ``requests_finished``, and for paged engines
@@ -133,7 +135,8 @@ __all__ = ["Tracer", "RequestTimeline", "RequestTraceIndex", "TraceContext",
            "TrainMonitor", "program_label", "chrome_trace_from_jsonl",
            "instrument_train_step", "set_active_monitor", "current_monitor",
            "PHASE_TICK", "PHASE_ADMIT", "PHASE_PACK", "PHASE_DISPATCH",
-           "PHASE_SYNC", "PHASE_UNPACK", "PHASES"]
+           "PHASE_SYNC", "PHASE_UNPACK", "PHASES",
+           "PART_OPERANDS", "PART_KEY", "PART_CALL", "PART_STATS", "PARTS"]
 
 _PCTS = (50.0, 95.0, 99.0)
 
@@ -149,6 +152,18 @@ PHASE_DISPATCH = "engine.dispatch"  # operands to the device, the call
 PHASE_SYNC = "engine.sync"          # the host waits for the sampled tokens
 PHASE_UNPACK = "engine.unpack"      # tokens to requests, callbacks, retire
 PHASES = (PHASE_ADMIT, PHASE_PACK, PHASE_DISPATCH, PHASE_SYNC, PHASE_UNPACK)
+# Parts of a phase, opened by the callable its ``with`` hands out (``with
+# phase(PHASE_DISPATCH) as part: part(PART_OPERANDS) ...``): a part runs
+# from there to the next part or to the phase's end, so the parts of
+# ``engine.dispatch`` follow one another on one clock reading each and
+# partition it.  Their seconds go to the ``tick`` event's ``parts``, not
+# its ``phases``; an engine opens only those whose mechanism it has:
+PART_OPERANDS = "engine.dispatch.operands"  # host arrays to the device
+PART_KEY = "engine.dispatch.key"            # _next_key(): its own program
+PART_CALL = "engine.dispatch.call"          # the program's fetch, the call
+PART_STATS = "engine.sync.stats"    # the model's tick_stats read back: a
+#                                     read made only with a tracer attached
+PARTS = (PART_OPERANDS, PART_KEY, PART_CALL, PART_STATS)
 
 
 class TraceContext:
@@ -305,27 +320,43 @@ def _annotation(name: str, **kw):
 class _Phase:
     """What ``Tracer.phase`` returns; see there."""
 
-    __slots__ = ("_acc", "_name", "_ann", "_t0")
+    __slots__ = ("_note", "_name", "_stats", "_ann", "_t0",
+                 "_part", "_part_ann", "_part_t0")
 
-    def __init__(self, note, name):
-        if note is None:
-            self._acc, self._ann = None, _annotation(name)
-        else:
-            self._acc = note["phases"]
-            self._ann = _annotation(name, tick=note["tick"])
-        self._name = name
+    def __init__(self, note, name, stats):
+        self._note, self._name, self._stats = note, name, stats
+        self._part = None
+        self._ann = _annotation(name, **stats)
 
     def __enter__(self):
         self._ann.__enter__()
         self._t0 = time.perf_counter()
-        return self
+        return self.part
+
+    def part(self, name):
+        """From here to the next part, or to the phase's end, is the part
+        ``name``: its own span, and its seconds in the round's ``parts``."""
+        now = time.perf_counter()
+        if self._part is not None:
+            self._close_part(now)
+        self._part, self._part_t0 = name, now
+        self._part_ann = _annotation(name, **self._stats)
+        self._part_ann.__enter__()
+
+    def _close_part(self, now):
+        self._part_ann.__exit__(None, None, None)
+        if self._note is not None:
+            acc = self._note["parts"]
+            acc[self._part] = acc.get(self._part, 0.0) + now - self._part_t0
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
+        now = time.perf_counter()
+        if self._part is not None:
+            self._close_part(now)
         self._ann.__exit__(*exc)
-        acc = self._acc
-        if acc is not None:
-            acc[self._name] = acc.get(self._name, 0.0) + dt
+        if self._note is not None:
+            acc = self._note["phases"]
+            acc[self._name] = acc.get(self._name, 0.0) + now - self._t0
         return False
 
 
@@ -365,6 +396,7 @@ class Tracer:
         # between open_tick() and tick(), else None
         self._tick_seq = 0
         self._open_tick: Optional[Dict[str, Any]] = None
+        self._span_stats: Dict[str, Any] = {}   # of the round in flight
         self._tick_span = None
         self._warmup_depth = 0            # expected_compiles nesting
         self._prov_resolver = None        # compile provenance (jit/aot.py)
@@ -519,26 +551,41 @@ class Tracer:
         span (a ``jax.profiler.TraceAnnotation``, so in a profiler session
         the round lies on the host plane on the device operations' clock;
         inert otherwise) and return the note that ``tick()`` closes the
-        round with: ``{"tick": seq, "ts_open": now, "phases": {}}``
-        (``ts_open`` on the clock every event's ``ts`` is on, so the round
-        spans ``[ts_open, ts]`` of its ``tick`` event and an event stamped
-        inside it lies inside that).  The engine adds whatever it packed to
-        the same dict; until ``tick()`` every ``phase()`` adds its seconds
-        to it and every request event carries its number.  One engine per
-        tracer: rounds do not nest."""
+        round with: ``{"tick": seq, "ts_open": now, "phases": {}, "parts":
+        {}}`` (``ts_open`` on the clock every event's ``ts`` is on, so the
+        round spans ``[ts_open, ts]`` of its ``tick`` event and an event
+        stamped inside it lies inside that).  The engine adds whatever it
+        packed to the same dict; until ``tick()`` every ``phase()`` adds
+        its seconds to it and every request event carries its number.  One
+        engine per tracer: rounds do not nest."""
         self._tick_seq += 1
         self._open_tick = note = {"tick": self._tick_seq,
-                                  "ts_open": self.now(), "phases": {}}
+                                  "ts_open": self.now(), "phases": {},
+                                  "parts": {}}
+        self._span_stats = {"tick": self._tick_seq}
         self._tick_span = _annotation(PHASE_TICK, tick=self._tick_seq)
         self._tick_span.__enter__()
         return note
+
+    def span_stats(self, **stats):
+        """The engine's word on the round in flight, for the trace: every
+        span opened from here to the round's end carries these stats
+        beside ``tick`` (the ragged engine says ``chunk_rows`` once it has
+        its pack: a round is "with a chunk" where that is > 0, "decode
+        only" otherwise).  Outside a round nothing is kept."""
+        if self._open_tick is not None:
+            self._span_stats.update(stats)
 
     def phase(self, name: str) -> "_Phase":
         """``with tracer.phase(PHASE_PACK): ...`` — one phase of the round
         in flight: a clock pair whose seconds add to the round's
         ``phases[name]``, and a ``TraceAnnotation`` of that name carrying
-        the round's number.  Outside a round it is only the annotation."""
-        return _Phase(self._open_tick, name)
+        the round's number and whatever the engine has said of the round
+        by then (``span_stats``).  ``with ... as part`` hands out what
+        opens the phase's parts (``part(PART_KEY)``: see ``PARTS``): the
+        same stats on a span of the part's name, its seconds in
+        ``parts[name]``.  Outside a round it is only the annotations."""
+        return _Phase(self._open_tick, name, self._span_stats)
 
     def tick(self, engine: str, dur_s: float, **fields):
         """One scheduler round; observes the tick-duration histogram and
@@ -556,6 +603,7 @@ class Tracer:
         if self._tick_span is not None:     # the round open_tick() began
             self._tick_span.__exit__(None, None, None)
             self._tick_span = self._open_tick = None
+            self._span_stats = {}
         self.registry.add("ticks")
         self.registry.observe("tick_seconds", dur_s)
         progs = fields.get("programs")

@@ -22,7 +22,9 @@ from paddle_tpu.models.gpt import GPTConfig, GPTModel
 from paddle_tpu.ops.ragged_paged_attention import MIN_RUN, grouped_rows
 from paddle_tpu.serving import (ContinuousBatchingEngine,
                                 RaggedPagedContinuousBatchingEngine)
-from paddle_tpu.telemetry import PHASES, Tracer
+from paddle_tpu.telemetry import (PART_CALL, PART_KEY, PART_OPERANDS,
+                                  PART_STATS, PARTS, PHASE_DISPATCH,
+                                  PHASE_SYNC, PHASE_UNPACK, PHASES, Tracer)
 
 REGIONS = ("embed", "layers", "attn", "mlp", "kv_write", "head", "optimizer",
            "flash_attention", "ragged_paged_attention")
@@ -43,6 +45,7 @@ SCENARIOS = {
     "spec": (dict(draft=True), PROMPTS[:4], BUDGETS[:4]),
 }
 SCENARIO_IDS = ["plain", "preemption", "dry_pool", "spec"]
+SPANS = {}      # scenario -> [(span name, its stats)] in the order opened
 
 
 @pytest.fixture(scope="module")
@@ -71,15 +74,21 @@ def served(model_and_params):
     """{scenario: (engine, tracer, prompts, outputs)}, each served once."""
     model, params = model_and_params
     out = {}
-    for name in SCENARIO_IDS:
-        kw, prompts, budgets = SCENARIOS[name]
-        tr = Tracer()
-        eng = _ragged(model, params, tracer=tr, **kw)
-        for p, n in zip(prompts, budgets):
-            eng.add_request(p, n)
-        got = eng.run_to_completion(max_ticks=500)
-        assert len(got) == len(prompts)
-        out[name] = (eng, tr, prompts, got)
+    real = telemetry._annotation
+    with pytest.MonkeyPatch.context() as mp:
+        for name in SCENARIO_IDS:
+            kw, prompts, budgets = SCENARIOS[name]
+            opened = SPANS[name] = []
+            mp.setattr(telemetry, "_annotation",
+                       lambda span, _to=opened, **stats:
+                       _to.append((span, stats)) or real(span, **stats))
+            tr = Tracer()
+            eng = _ragged(model, params, tracer=tr, **kw)
+            for p, n in zip(prompts, budgets):
+                eng.add_request(p, n)
+            got = eng.run_to_completion(max_ticks=500)
+            assert len(got) == len(prompts)
+            out[name] = (eng, tr, prompts, got)
     return out
 
 
@@ -159,6 +168,116 @@ def test_verify_chunks_are_rows_of_k_plus_one(served):
     assert sizes <= {1, eng.K + 1} and eng.K + 1 in sizes
 
 
+# ------------------------------------- the parts, and the round's kind --
+
+def _dispatch_parts(scenario):
+    # the fused draft + verify program is greedy and takes no key
+    return (PART_OPERANDS, PART_CALL) if scenario == "spec" \
+        else (PART_OPERANDS, PART_KEY, PART_CALL)
+
+
+def test_parts_are_named_apart_from_the_phases():
+    assert PARTS == (PART_OPERANDS, PART_KEY, PART_CALL, PART_STATS)
+    assert not set(PARTS) & set(PHASES) and len(set(PARTS)) == 4
+    for part in PARTS:      # each lies inside the phase its name begins with
+        assert part.rsplit(".", 1)[0] in (PHASE_DISPATCH, PHASE_SYNC)
+
+
+@pytest.mark.parametrize("scenario", SCENARIO_IDS)
+def test_parts_partition_dispatch_on_the_tick_event(served, scenario):
+    eng, tr, prompts, got = served[scenario]
+    ran = [e for e in tr.events("tick") if e.get("rows")]
+    assert ran
+    shares = []
+    for e in tr.events("tick"):
+        assert set(e["parts"]) <= set(PARTS)
+        assert not set(e["parts"]) & set(e["phases"])
+        assert sum(e["phases"].values()) <= e["dur_s"]  # as it was
+    for e in ran:
+        assert tuple(e["parts"]) == _dispatch_parts(scenario)
+        assert all(v >= 0 for v in e["parts"].values())
+        whole = e["phases"][PHASE_DISPATCH]
+        assert sum(e["parts"].values()) <= whole
+        shares.append(sum(e["parts"].values()) / whole)
+    # a part ends on the clock reading that begins the next, the last on
+    # the phase's own: what is left is the phase's entry to its first part
+    assert sorted(shares)[len(shares) // 2] > 0.98
+
+
+@pytest.mark.parametrize("scenario", SCENARIO_IDS)
+def test_spans_after_the_pack_carry_the_round_kind(served, scenario):
+    eng, tr, prompts, got = served[scenario]
+    by_tick = {}
+    for name, stats in SPANS[scenario]:
+        by_tick.setdefault(stats["tick"], []).append((name, stats))
+    ticks = {e["tick"]: e for e in tr.events("tick")}
+    assert set(by_tick) == set(ticks)
+    narrow = 0
+    for number, spans in by_tick.items():
+        e = ticks[number]
+        names = [name for name, _ in spans]
+        assert names[:3] == [telemetry.PHASE_TICK, telemetry.PHASE_ADMIT,
+                             telemetry.PHASE_PACK]
+        for _, stats in spans[:3]:      # opened before the pack is known
+            assert stats == {"tick": number}
+        if not e.get("rows"):
+            assert len(spans) == 3
+            continue
+        assert names[3:] == [PHASE_DISPATCH, *_dispatch_parts(scenario),
+                             PHASE_SYNC, PHASE_UNPACK]
+        kind = {"tick": number, "chunk_rows": e["prefill_tokens"]}
+        assert all(stats == kind for _, stats in spans[3:])
+        assert e["rows_run"] in (eng.token_budget, eng.narrow_rows)
+        narrow += e["rows_run"] != eng.token_budget
+        if e["rows_run"] == eng.narrow_rows != 0:
+            assert kind["chunk_rows"] == 0      # decode rows only
+    assert narrow == eng.metrics()["narrow_steps"]
+
+
+def test_the_stats_read_is_a_part_only_where_the_model_has_tick_stats(
+        served):
+    """``engine.sync.stats`` brackets the read of the model's counters,
+    which happens only with a tracer: GPT names none and has no such
+    span; the latent model's tick returns its vector and has one."""
+    from benchmarks.lib import weights_pangu
+    from paddle_tpu.models.pangu_moe import (TICK_STATS, PanguMoeConfig,
+                                             PanguMoeModel)
+    assert all(PART_STATS not in e["parts"] for name in SCENARIO_IDS
+               for e in served[name][1].events("tick"))
+    cfg = dict(vocab_size=96, hidden_size=32, num_hidden_layers=2,
+               first_k_dense_replace=1, num_attention_heads=4,
+               q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8,
+               qk_rope_head_dim=4, v_head_dim=8, intermediate_size=48,
+               moe_intermediate_size=12, n_shared_experts=1,
+               num_experts_per_tok=3, routed_scaling_factor=2.5,
+               norm_topk_prob=True, rms_norm_eps=1e-5, rope_theta=25600000,
+               max_position_embeddings=128, initializer_range=0.2)
+    paddle.seed(0)
+    model = PanguMoeModel(PanguMoeConfig(
+        **cfg, n_routed_experts=16, experts_held=range(4, 8),
+        compute_dtype="float32"))
+    params = weights_pangu.make_params(
+        dict(cfg, n_routed_experts=4, router_width=16, experts_held=[4, 8]),
+        7, "float32")
+    outs = {}
+    for tr in (Tracer(), None):
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, max_slots=3, max_len=64, block_size=8,
+            num_blocks=20, token_budget=16,
+            prompt_buckets=list(range(8, 65, 8)), tracer=tr)
+        eng.add_request(list(range(1, 20)), 4)
+        outs[tr is None] = eng.run_to_completion(max_ticks=50)
+        if tr is None:
+            continue
+        ran = [e for e in tr.events("tick") if e.get("rows")]
+        assert ran and all(set(TICK_STATS) <= set(e) for e in ran)
+        for e in ran:
+            assert tuple(e["parts"]) == (PART_OPERANDS, PART_KEY, PART_CALL,
+                                         PART_STATS)
+            assert 0 <= e["parts"][PART_STATS] <= e["phases"][PHASE_SYNC]
+    assert outs[True] == outs[False]    # and the tokens are the same
+
+
 @pytest.mark.parametrize("scenario", SCENARIO_IDS)
 def test_request_events_carry_the_tick_that_emitted_them(served, scenario):
     eng, tr, prompts, got = served[scenario]
@@ -192,6 +311,30 @@ def test_base_engine_ticks_carry_the_same_phase_names(model_and_params):
     assert all(sum(e["phases"].values()) <= e["dur_s"] for e in ticks)
 
 
+def test_base_engine_dispatch_has_the_same_three_parts(model_and_params,
+                                                       monkeypatch):
+    model, params = model_and_params
+    opened, real = [], telemetry._annotation
+    monkeypatch.setattr(telemetry, "_annotation",
+                        lambda span, **stats: opened.append((span, stats))
+                        or real(span, **stats))
+    tr = Tracer()
+    eng = ContinuousBatchingEngine(model, params, max_slots=2, max_len=32,
+                                   prompt_buckets=[8], tracer=tr)
+    eng.add_request(PROMPTS[0], 4)
+    eng.run_to_completion(max_ticks=50)
+    ran = [e for e in tr.events("tick") if PHASE_DISPATCH in e["phases"]]
+    assert ran
+    for e in ran:
+        assert tuple(e["parts"]) == (PART_OPERANDS, PART_KEY, PART_CALL)
+        assert sum(e["parts"].values()) <= e["phases"][PHASE_DISPATCH]
+    # its prefill runs inside engine.admit, so a round has no kind to say
+    assert {span for span, _ in opened} == {telemetry.PHASE_TICK, *PHASES,
+                                            PART_OPERANDS, PART_KEY,
+                                            PART_CALL}
+    assert all(set(stats) == {"tick"} for _, stats in opened)
+
+
 # -------------------------------------------------- no tracer, no span --
 
 @pytest.mark.parametrize("engine", ["ragged", "base"])
@@ -213,6 +356,39 @@ def test_without_a_tracer_no_phase_and_no_annotation_is_entered(
     assert len(eng.run_to_completion(max_ticks=100)) == 1
 
 
+@pytest.mark.parametrize("engine", ["ragged", "spec", "base"])
+def test_without_a_tracer_no_part_is_entered_and_the_tokens_are_the_same(
+        model_and_params, monkeypatch, engine):
+    model, params = model_and_params
+
+    def make(tracer):
+        if engine == "base":
+            return ContinuousBatchingEngine(
+                model, params, max_slots=2, max_len=32, prompt_buckets=[8],
+                tracer=tracer)
+        return _ragged(model, params, tracer=tracer, draft=engine == "spec")
+
+    def serve(eng):
+        for p, n in zip(PROMPTS[:3], BUDGETS[:3]):
+            eng.add_request(p, n)
+        return eng.run_to_completion(max_ticks=200)
+
+    tr = Tracer()
+    with_tracer = serve(make(tr))
+    assert any(e["parts"] for e in tr.events("tick"))
+
+    def boom(*a, **kw):
+        raise AssertionError("entered with tracing off")
+
+    monkeypatch.setattr(telemetry._Phase, "__init__", boom)
+    monkeypatch.setattr(telemetry._Phase, "__enter__", boom)
+    monkeypatch.setattr(telemetry._Phase, "part", boom)
+    monkeypatch.setattr(telemetry, "_annotation", boom)
+    eng = make(None)
+    assert serve(eng) == with_tracer
+    assert eng._tick_note == {}
+
+
 def test_phase_outside_a_round_is_only_a_span():
     tr = Tracer()
     with tr.phase(telemetry.PHASE_PACK):
@@ -226,6 +402,46 @@ def test_phase_outside_a_round_is_only_a_span():
     assert ev["tick"] == 1 and list(ev["phases"]) == [telemetry.PHASE_PACK]
     assert 0.001 <= ev["phases"][telemetry.PHASE_PACK] < 0.5
     assert tr.open_tick()["tick"] == 2
+
+
+def test_a_part_runs_to_the_next_part_or_to_the_phases_end(monkeypatch):
+    opened, real = [], telemetry._annotation
+    monkeypatch.setattr(telemetry, "_annotation",
+                        lambda span, **stats: opened.append((span, stats))
+                        or real(span, **stats))
+    tr = Tracer()
+    with tr.phase(PHASE_DISPATCH) as part:  # outside a round: spans only
+        part(PART_KEY)
+    assert opened == [(PHASE_DISPATCH, {}), (PART_KEY, {})]
+    note = tr.open_tick()
+    tr.span_stats(chunk_rows=0)             # the engine's word on the round
+    with tr.phase(PHASE_DISPATCH) as part:
+        time.sleep(0.002)                   # before the first part
+        part(PART_OPERANDS)
+        time.sleep(0.001)
+        part(PART_CALL)
+        time.sleep(0.003)
+    with tr.phase(PHASE_SYNC) as part:
+        time.sleep(0.001)
+        part(PART_STATS)
+    ev = tr.tick("E", 0.5, **note)
+    assert list(ev["phases"]) == [PHASE_DISPATCH, PHASE_SYNC]
+    assert list(ev["parts"]) == [PART_OPERANDS, PART_CALL, PART_STATS]
+    whole, parts = ev["phases"][PHASE_DISPATCH], ev["parts"]
+    assert parts[PART_OPERANDS] >= 0.001 and parts[PART_CALL] >= 0.003
+    # what came before the first part is the phase's alone
+    assert parts[PART_OPERANDS] + parts[PART_CALL] <= whole - 0.002
+    assert 0 <= parts[PART_STATS] <= ev["phases"][PHASE_SYNC] - 0.001
+    kind = {"tick": 1, "chunk_rows": 0}
+    assert opened[3:] == [(name, kind) for name in (
+        PHASE_DISPATCH, PART_OPERANDS, PART_CALL, PHASE_SYNC, PART_STATS)]
+    # the word is the round's: the next round's spans start without it,
+    # and outside a round there is nothing to say it of
+    tr.span_stats(chunk_rows=7)
+    tr.open_tick()
+    with tr.phase(PHASE_DISPATCH):
+        pass
+    assert opened[-1] == (PHASE_DISPATCH, {"tick": 2})
 
 
 # ------------------------------------------------------------- due_at --
