@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import collections
 import logging
+import math
 import time
 from functools import partial
 from typing import Optional
@@ -1164,6 +1165,37 @@ class PagedContinuousBatchingEngine(ContinuousBatchingEngine):
         return m
 
 
+def _packed_shapes(T: int, C: int, S: int):
+    """The ragged tick's ONE int32 operand, field by field in the order the
+    buffer holds them: tokens, row-to-sequence and row positions (``T``
+    rows each), the block table's first ``C`` columns (``S`` x ``C``,
+    row-major), then pads, sample rows, sample-active (0/1) and emitted
+    counts (``S`` each) — ``3 T + S C + 4 S`` words."""
+    return ((T,), (T,), (T,), (S, C), (S,), (S,), (S,), (S,))
+
+
+def _packed_fields(buf, T: int, C: int, S: int):
+    """``buf`` cut into the fields of ``_packed_shapes``.  Both sides of
+    the boundary cut with this: a NumPy buffer gives writable views, which
+    the host fills; the traced operand gives static slices, which cost the
+    program nothing."""
+    fields, at = [], 0
+    for shape in _packed_shapes(T, C, S):
+        fields.append(buf[at:at + math.prod(shape)].reshape(shape))
+        at += math.prod(shape)
+    return fields
+
+
+def _pack_operands(T: int, C: int, S: int, *arrays):
+    """One round's host arrays as the one buffer ``_packed_fields`` cuts:
+    ``arrays`` in its order, each of its field's shape or a scalar to fill
+    it with (the mask lands as 0/1)."""
+    buf = np.empty(sum(map(math.prod, _packed_shapes(T, C, S))), np.int32)
+    for view, src in zip(_packed_fields(buf, T, C, S), arrays, strict=True):
+        view[...] = src
+    return buf
+
+
 class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
     """Continuous batching where the WHOLE scheduler tick is ONE compiled
     mixed-batch program (the "ragged paged attention" serving step,
@@ -1566,6 +1598,12 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         table-width) bucket.  The round is five phases, each bracketed
         by ``tracer.phase`` when a tracer is attached (telemetry.PHASES).
 
+        A plain round crosses to the device once each way: its host
+        arrays go in as one int32 buffer (``_packed_fields``), the
+        sampling key stays on the device (the tick takes ``self._key``
+        and returns the next), and the tokens come back as one vector,
+        the model's ``tick_stats`` behind them where it names any.
+
         Which program a round runs is chosen here, from the pack: one
         with no prefill chunk (and so, without a draft, at most one row a
         slot, packed first) goes to the narrow program, its operands cut
@@ -1592,39 +1630,43 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                                        spec_row0, spec_active)
         with phase(PHASE_DISPATCH) as part:     # three parts partition it
             part(PART_OPERANDS)
-            emitted0 = np.asarray(
+            # the round crosses to the chip ONCE: every host array in one
+            # buffer (_packed_fields), handed to the call as it is — the
+            # call's own argument handling makes the one transfer (a
+            # jax.device_put in front of it cost 0.3-0.6 ms a round more
+            # on the chip's host: PERF.md section 6, PR 43)
+            packed = _pack_operands(
+                T, C, self.S, toks[:T], row_seq[:T], row_pos[:T],
+                self._table[:, :C], self._pad, sample_rows, sample_active,
                 [len(self._slot_req[s].generated) if self._active[s] else 0
-                 for s in range(self.S)], np.int32)
-            operands = (
-                jnp.asarray(toks[:T]), jnp.asarray(row_seq[:T]),
-                jnp.asarray(row_pos[:T]), jnp.asarray(self._table[:, :C]),
-                jnp.asarray(self._pad), jnp.asarray(sample_rows),
-                jnp.asarray(sample_active), jnp.asarray(emitted0))
+                 for s in range(self.S)])
             planes = self._plane_operands()
             part(PART_KEY)
-            key = self._next_key()
+            # the stream's key lives on the device: the tick splits it (as
+            # _next_key did, before its own split) and hands the next back
+            key = self._key
             part(PART_CALL)
             run = self._ragged_prog(C, T)
             n = len(self.caches)
-            out = run(self.params, self.caches, *operands, key,
-                      self._presence, planes)
+            out = run(self.params, self.caches, packed, key, self._presence,
+                      planes)
             # freed here, as the call's own temporaries were: the phase
             # keeps its extent
-            del operands, key, planes
-            self.caches, ntok, self._presence = out[:n], out[n], out[n + 1]
+            del packed, key, planes
+            self.caches, vec, self._presence, self._key = (
+                out[:n], out[n], out[n + 1], out[n + 2])
             self._stats.add("ragged_steps")
             if narrow:
                 self._stats.add("narrow_steps")
         with phase(PHASE_SYNC) as part:
-            ntok = np.asarray(ntok)
+            # ... and back once: the S tokens, then the model's own
+            # counters for this tick where its spec names any
+            vec = np.asarray(vec)
+            ntok = vec[:self.S]
             names = self.cache_spec.tick_stats
             if names and self.tracer is not None:
-                # the model's own counters for this tick (one small
-                # vector), read after the tokens; never without a tracer,
-                # so what the read costs is tracing's own: PART_STATS
-                part(PART_STATS)
-                self._tick_note.update(
-                    zip(names, np.asarray(out[n + 2]).tolist()))
+                part(PART_STATS)        # the host's side of noting them
+                self._tick_note.update(zip(names, vec[self.S:].tolist()))
         with phase(PHASE_UNPACK):
             for slot in dec_slots:
                 self._t[slot] += 1
@@ -1707,13 +1749,15 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         row_sample = self._row_sample if per_request else None
         with_stats = bool(self.cache_spec.tick_stats)
 
-        # the outputs are flat — the pools' entries, the tokens, presence,
-        # then the model's tick counters where its spec names any — so a
-        # two-entry model's program is the one it always was
-        @partial(jax.jit, donate_argnums=(1, 11))
-        def run(params, pools, toks, row_seq, row_pos, table,
-                pads, sample_rows, sample_active, emitted0, key, presence,
-                planes):
+        # one int32 operand in (_packed_fields); the outputs are flat — the
+        # pools' entries, ONE int32 vector of the S tokens and then the
+        # model's tick counters where its spec names any, presence, and
+        # the sampling stream's next key
+        @partial(jax.jit, donate_argnums=(1, 4))
+        def run(params, pools, packed, key, presence, planes):
+            (toks, row_seq, row_pos, table, pads, sample_rows, sample_active,
+             emitted0) = _packed_fields(packed, T, C, S)
+            sample_active = sample_active != 0
             h = model._embed_ragged(params, toks, row_seq, row_pos, pads)
             h, pools, *stats = model.decode_ragged(
                 params, h, pools, table, row_seq, row_pos, pads)
@@ -1723,7 +1767,10 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
             with jax.named_scope("head"):       # logits and the sampler
                 h_s = h[0, sample_rows][:, None]        # (S, 1, H)
                 l2 = model.decode_logits(params, h_s)[:, -1]
+                # the engine's stream, bit for bit: the split _next_key
+                # made on the host, then the tick's own
                 key, sub = jax.random.split(key)
+                _, sub = jax.random.split(sub)
                 if per_request:
                     temp, topk, topp, greedy, rpv, mnv, eosv = planes
                     l2 = apply_repetition_penalty(l2, presence, rpv)
@@ -1741,8 +1788,9 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
                     # tokens update presence in-program
                     presence = presence.at[jnp.arange(S), ntok].max(
                         sample_active)
-            return (*pools, ntok, presence,
-                    *(stats if with_stats else ()))
+            if with_stats:
+                ntok = jnp.concatenate([ntok, *stats])
+            return (*pools, ntok, presence, key)
 
         return run
 
@@ -1944,14 +1992,12 @@ class RaggedPagedContinuousBatchingEngine(PagedContinuousBatchingEngine):
         — values are irrelevant, shapes and dtypes ARE the program
         signature (the purity test lowers through these)."""
         T, S = self.token_budget if T is None else T, self.S
-        z = jnp.zeros(S, jnp.int32)
-        return (self.params, self._alloc_caches(), jnp.zeros(T, jnp.int32),
-                jnp.zeros(T, jnp.int32),
-                jnp.minimum(jnp.arange(T, dtype=jnp.int32),
-                            C * self.bs - 1),
-                jnp.zeros((S, C), jnp.int32), z, z,
-                jnp.zeros(S, bool), z, self._warmup_key(),
-                self._scratch_presence(), self._plane_operands())
+        packed = _pack_operands(     # a host buffer, as a round hands over
+            T, C, S, 0, 0, np.minimum(np.arange(T), C * self.bs - 1),
+            0, 0, 0, 0, 0)
+        return (self.params, self._alloc_caches(), packed,
+                self._warmup_key(), self._scratch_presence(),
+                self._plane_operands())
 
     def _warmup_ragged(self, C: int, T: Optional[int] = None):
         run = self._ragged_prog(C, T)

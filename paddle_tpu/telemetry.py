@@ -158,11 +158,17 @@ PHASES = (PHASE_ADMIT, PHASE_PACK, PHASE_DISPATCH, PHASE_SYNC, PHASE_UNPACK)
 # ``engine.dispatch`` follow one another on one clock reading each and
 # partition it.  Their seconds go to the ``tick`` event's ``parts``, not
 # its ``phases``; an engine opens only those whose mechanism it has:
+# (the ragged engine fills ONE host buffer, which the call itself sends;
+# the bucketed engines send an array an operand)
 PART_OPERANDS = "engine.dispatch.operands"  # host arrays to the device
-PART_KEY = "engine.dispatch.key"            # _next_key(): its own program
+# the ragged tick splits the key itself and hands the next one back, so
+# there this is an attribute read; the bucketed engines' _next_key() is a
+# device program of its own
+PART_KEY = "engine.dispatch.key"            # taking the sampler's key
 PART_CALL = "engine.dispatch.call"          # the program's fetch, the call
-PART_STATS = "engine.sync.stats"    # the model's tick_stats read back: a
-#                                     read made only with a tracer attached
+PART_STATS = "engine.sync.stats"    # noting the model's tick_stats (they
+#                                     came back with the tokens, in the one
+#                                     read): host work, only with a tracer
 PARTS = (PART_OPERANDS, PART_KEY, PART_CALL, PART_STATS)
 
 

@@ -788,24 +788,12 @@ class TestNarrowProgram:
         """``PanguMoeModel`` keeps one width and its in-program branch
         (PERF.md section 7 item 0): no narrow program in its grid, no
         round dispatched to one."""
-        from paddle_tpu.models.pangu_moe import (PanguMoeConfig,
-                                                 PanguMoeModel)
+        from paddle_tpu.models.pangu_moe import PanguMoeModel
         from paddle_tpu.serving_paged import pow2_grid
         assert PanguMoeModel.ragged_narrow_rounds is False
         assert GPTModel.ragged_narrow_rounds is True
-        paddle.seed(0)
-        model = PanguMoeModel(PanguMoeConfig(
-            vocab_size=96, hidden_size=32, num_hidden_layers=2,
-            first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=16,
-            kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
-            v_head_dim=8, intermediate_size=48, moe_intermediate_size=12,
-            n_routed_experts=4, n_shared_experts=1, num_experts_per_tok=2,
-            max_position_embeddings=128, compute_dtype="float32"))
-        params = {n: p._data for n, p in model.named_parameters()}
-        eng = RaggedPagedContinuousBatchingEngine(
-            model, params, max_slots=3, max_len=64, block_size=8,
-            num_blocks=20, token_budget=32,
-            prompt_buckets=list(range(8, 65, 8)))
+        model, params, geometry = _pangu()
+        eng = RaggedPagedContinuousBatchingEngine(model, params, **geometry)
         assert eng.narrow_rows == 0
         assert eng.compile_grid() == [f"ragged_step:32:{C}"
                                       for C in pow2_grid(eng.MB)]
@@ -813,19 +801,23 @@ class TestNarrowProgram:
         eng.run_to_completion(max_ticks=100)
         assert eng.narrow_steps == 0 and eng.ragged_steps >= 6
 
-    # sha256 of the GPT tick's lowering at the parent of PR 39 (commit
-    # 4946d47), by (interpreted kernel, dtype): 3 slots, 64 positions in
-    # blocks of 8, a 24-row budget, 4 table columns; under the suite's
-    # settings (tests/conftest.py: matmul precision "highest")
+    # sha256 of the GPT tick's lowering, by (interpreted kernel, dtype):
+    # 3 slots, 64 positions in blocks of 8, a 24-row budget, 4 table
+    # columns; under the suite's settings (tests/conftest.py: matmul
+    # precision "highest").  Taken at the parent of PR 39 (commit 4946d47)
+    # and re-taken in PR 43, whose tick takes one packed operand and the
+    # stream's key and returns the next key: against the parent's text
+    # (41edd05) only the parameters, their slices and the key's split
+    # moved (CHANGES.md, PR 43)
     PARENT_TICK = {
         (False, "float32"):
-            "f79f44b482f924adb0bb03eef62c17f22959f40850a21bddab01f3f47672877d",
+            "b80250b058a90f420a9947e454e663593b766722d1d9df00a89b5b871ffb9476",
         (False, "bfloat16"):
-            "b67341de6ae43924fb92e1ff5f366af33268ec5480ea74a37907d656da9418f9",
+            "78d458ea1cd9fd2ea4d5350362cd6ad8d7ab2e40d68df3ced83071d18bf5b300",
         (True, "float32"):
-            "4b7442963aecc77ed20766dd66e60e50a6d4ae69c215bbc1830cb7edc836c89e",
+            "0d24d7ae8bcd917eecccc59c0255f0126ded6ef8e91f6e705649f5ed4bb13c63",
         (True, "bfloat16"):
-            "4fe15a60b509b22d23ed289e194b6b62114b0344a67cad7554c745ef590056f2",
+            "ba6dcc03d62feb0db53e53794f1e80a9ac449b657b8f70bb6a46ccb9330b56b4",
     }
 
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -851,3 +843,251 @@ class TestNarrowProgram:
             set_flags({"FLAGS_paged_attn_interpret": False})
         assert hashlib.sha256(text.encode()).hexdigest() \
             == self.PARENT_TICK[interp, dtype]
+
+
+# ------------------------------------------------------------------------
+# A round crosses to the device once each way and runs one program (ISSUE
+# 43): one packed int32 operand in, the sampling key kept on the device,
+# tokens and tick counters read back as one vector
+# ------------------------------------------------------------------------
+
+EXECUTE = "PjRtCpuExecutable::Execute"  # one event a program run (CPU client)
+
+
+def _programs_run(fn):
+    """How many compiled programs ``fn()`` dispatches, counted in a
+    profiler trace of the host: the device's own record, so a program
+    reached through the jit fast path counts like any other."""
+    import glob
+    import tempfile
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            fn()
+        path, = glob.glob(d + "/plugins/profile/*/*.xplane.pb")
+        data = jax.profiler.ProfileData.from_file(path)
+        return sum(ev.name == EXECUTE for plane in data.planes
+                   for line in plane.lines for ev in line.events)
+
+
+def _pangu():
+    """A tiny latent-attention model (its spec names ``tick_stats``), its
+    weights and an engine's geometry."""
+    from paddle_tpu.models.pangu_moe import PanguMoeConfig, PanguMoeModel
+    paddle.seed(0)
+    model = PanguMoeModel(PanguMoeConfig(
+        vocab_size=96, hidden_size=32, num_hidden_layers=2,
+        first_k_dense_replace=1, num_attention_heads=4, q_lora_rank=16,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8, intermediate_size=48, moe_intermediate_size=12,
+        n_routed_experts=4, n_shared_experts=1, num_experts_per_tok=2,
+        max_position_embeddings=128, compute_dtype="float32"))
+    params = {n: p._data for n, p in model.named_parameters()}
+    return model, params, dict(max_slots=3, max_len=64, block_size=8,
+                               num_blocks=20, token_budget=32,
+                               prompt_buckets=list(range(8, 65, 8)))
+
+
+GPT_GEOMETRY = dict(max_slots=3, max_len=64, block_size=4,
+                    prompt_buckets=[8, 16], token_budget=24)
+
+
+class TestOneCrossing:
+    def test_the_counter_counts_programs(self):
+        """The control of ``_programs_run``: k calls of a jitted function
+        read k, an eager ``jax.random.split`` (the program ``_next_key``
+        dispatched before every round) reads one more each."""
+        f = jax.jit(lambda x: x + 1)
+        x, key = jnp.zeros(4), jax.random.key(0)
+        jax.block_until_ready((f(x), jax.random.split(key)))
+        assert _programs_run(lambda: [f(x) for _ in range(3)]) == 3
+        assert _programs_run(lambda: (f(x), jax.random.split(key))) == 2
+
+    @pytest.mark.parametrize("case", ["gpt", "gpt-sampled", "gpt-tracer",
+                                      "latent", "latent-tracer"])
+    def test_a_round_is_one_transfer_one_program_one_read(
+            self, case, model_and_params, monkeypatch):
+        """Rounds of both programs (budget-wide with a chunk, narrow with
+        decode rows only; the latent model keeps one width), with and
+        without ``tick_stats`` and a tracer to note them.  Each hands the
+        program ONE host array, the packed buffer, which the call's own
+        argument handling sends (every other leaf is on the device
+        already); makes no transfer beside it — an explicit one is
+        counted, an implicit one outside the call raises under the guard
+        —; runs ONE program and reads ONE array back."""
+        from paddle_tpu.telemetry import Tracer
+        from paddle_tpu.serving_paged import _packed_shapes
+        from jax._src import array as jax_array
+        tracer = Tracer() if case.endswith("tracer") else None
+        if case.startswith("latent"):
+            model, params, geometry = _pangu()
+            assert model.cache_spec().tick_stats
+        else:
+            (model, params), geometry = model_and_params, GPT_GEOMETRY
+            assert not model.cache_spec().tick_stats
+        kw = dict(greedy=False, temperature=0.8,
+                  key=jax.random.key(5)) if case == "gpt-sampled" else {}
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, tracer=tracer, **geometry, **kw)
+        eng.warmup()
+        puts, reads, handed = [], [], []
+        real_put, real_asarray = jax.device_put, jnp.asarray
+        monkeypatch.setattr(jax, "device_put", lambda x, *a, **k:
+                            puts.append(x) or real_put(x, *a, **k))
+        monkeypatch.setattr(jnp, "asarray", lambda x, *a, **k:
+                            puts.append(x) or real_asarray(x, *a, **k))
+        # reads: ``np.asarray`` of a device array (on the CPU it goes by
+        # the buffer protocol, past every method of the array) and whatever
+        # else fetches an array's value (``int()``, ``tolist()``, ...)
+        real_np_asarray, real_value = np.asarray, jax_array.ArrayImpl._value
+        monkeypatch.setattr(np, "asarray", lambda x, *a, **k: (
+            isinstance(x, jax.Array) and reads.append(x.shape),
+            real_np_asarray(x, *a, **k))[1])
+
+        def value(self):
+            reads.append(self.shape)
+            return real_value.fget(self)
+
+        monkeypatch.setattr(jax_array.ArrayImpl, "_value", property(value))
+        real_prog = eng._ragged_prog
+
+        def prog(C, T=None):    # the call alone may send what it is handed
+            run = real_prog(C, T)
+
+            def call(*args):
+                words = sum(map(np.prod, _packed_shapes(
+                    eng.token_budget if T is None else T, C, eng.S)))
+                handed.append([(type(x), x.dtype, x.shape)
+                               for x in jax.tree.leaves(args)
+                               if not isinstance(x, jax.Array)]
+                              == [(np.ndarray, np.int32, (words,))])
+                with jax.transfer_guard_host_to_device("allow"):
+                    return run(*args)
+            return call
+
+        monkeypatch.setattr(eng, "_ragged_prog", prog)
+        eng.add_request(list(range(1, 12)), 5)
+        eng.add_request(PROMPTS[5], 7)
+        rounds = []
+
+        def serve():
+            with jax.transfer_guard_host_to_device("disallow"):
+                while eng.pending():
+                    before = (len(handed), len(puts), len(reads),
+                              eng.narrow_steps)
+                    eng.step()
+                    rounds.append((len(handed) - before[0],
+                                   len(puts) - before[1],
+                                   len(reads) - before[2],
+                                   eng.narrow_steps - before[3]))
+
+        assert _programs_run(serve) == eng.ragged_steps == len(rounds) >= 7
+        assert all(r[:3] == (1, 0, 1) for r in rounds), rounds
+        assert all(handed)
+        narrow = sum(r[3] for r in rounds)
+        assert narrow == eng.narrow_steps < len(rounds)
+        assert (narrow > 0) == bool(eng.narrow_rows)
+        S, names = eng.S, model.cache_spec().tick_stats
+        assert set(reads) == {(S + len(names),)}
+        if tracer is not None and names:
+            ran = [e for e in tracer.events("tick") if e.get("rows")]
+            assert ran and all(set(names) <= set(e) for e in ran)
+
+    def test_a_sampled_engine_draws_the_parents_stream(self):
+        """The key chain, bit for bit: ``self._key, sub = split(self._key)``
+        on the host, then the tick's own ``split(sub)[1]`` — now both
+        inside the tick, which hands the next key back.  The sampler here
+        draws from its key alone (one token for every slot), so each
+        round's tokens say which key the tick sampled with; rounds with a
+        chunk and decode-only rounds (the narrow program) share the one
+        stream."""
+        paddle.seed(11)
+        model = GPTModel(GPTConfig(
+            vocab_size=97, hidden_size=32, num_layers=2,
+            num_attention_heads=4, max_position_embeddings=96,
+            compute_dtype="float32"))    # its own program cache
+        params = {n: p._data for n, p in model.named_parameters()}
+        key = jax.random.key(1234)
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, greedy=False, key=key, **GPT_GEOMETRY)
+        draw = lambda k: jax.random.randint(k, (), 0, 97, jnp.int32)
+        eng._sample = lambda logits, k: jnp.broadcast_to(
+            draw(k), logits.shape[:1])
+        emitted = []
+        for p, n in zip(PROMPTS[:4], [6, 3, 8, 5]):
+            eng.add_request(p, n, on_token=lambda rid, tok, done:
+                            emitted[-1].append(tok))
+        kinds = set()
+        while eng.pending():
+            emitted.append([])
+            before = eng.ragged_steps, eng.narrow_steps
+            eng.step()
+            assert eng.ragged_steps == before[0] + 1
+            kinds.add(eng.narrow_steps - before[1])
+            key, sub = jax.random.split(key)            # _next_key()
+            want = int(draw(jax.random.split(sub)[1]))  # the tick's split
+            assert emitted[-1] and set(emitted[-1]) == {want}
+        assert kinds == {0, 1} and len(emitted) >= 8
+        assert (jax.random.key_data(eng._key)
+                == jax.random.key_data(key)).all()
+
+    @pytest.mark.parametrize("case", ["gpt", "latent"])
+    def test_a_warmed_sampled_engine_draws_what_an_unwarmed_one_draws(
+            self, case, model_and_params):
+        """The warm-up runs the tick with a constant key and discards the
+        key it gets back: it does not advance the stream."""
+        if case == "latent":
+            model, params, geometry = _pangu()
+        else:
+            (model, params), geometry = model_and_params, GPT_GEOMETRY
+        got = []
+        for warm in (True, False):
+            eng = RaggedPagedContinuousBatchingEngine(
+                model, params, greedy=False, temperature=0.9,
+                key=jax.random.key(77), **geometry)
+            if warm:
+                eng.warmup()
+                assert (jax.random.key_data(eng._key)
+                        == jax.random.key_data(jax.random.key(77))).all()
+            for p, n in zip(PROMPTS[:4], [6, 3, 8, 5]):
+                eng.add_request(p, n)
+            got.append(eng.run_to_completion(max_ticks=200))
+        assert got[0] == got[1]
+        greedy = RaggedPagedContinuousBatchingEngine(model, params,
+                                                     **geometry)
+        for p, n in zip(PROMPTS[:4], [6, 3, 8, 5]):
+            greedy.add_request(p, n)
+        assert greedy.run_to_completion(max_ticks=200) != got[0]
+
+    @pytest.mark.parametrize("T,C", [(24, 16), (24, 4), (8, 16), (8, 1)],
+                             ids=["wide-full", "wide-cut", "narrow-full",
+                                  "narrow-cut"])
+    def test_the_packed_layout_round_trips(self, T, C):
+        """Every field cut from the buffer INSIDE a program equals the
+        host array it was filled from — at the widest table and at a
+        column cut (``C < MB``), at the budget's rows and at
+        ``narrow_rows`` — and the buffer holds ``3 T + S C + 4 S``
+        words."""
+        from paddle_tpu.serving_paged import _pack_operands, _packed_fields
+        S, MB = 3, 16
+        rng = np.random.default_rng(T * 100 + C)
+        ints = lambda *shape: rng.integers(-5, 1 << 20, shape).astype(
+            np.int32)
+        table = ints(S, MB)
+        host = (ints(T), ints(T), ints(T), table[:, :C], ints(S), ints(S),
+                rng.integers(0, 2, S).astype(bool), ints(S))
+        buf = _pack_operands(T, C, S, *host)
+        assert buf.dtype == np.int32 and buf.shape == (3 * T + S * C
+                                                        + 4 * S,)
+
+        @jax.jit
+        def cut(packed):
+            f = _packed_fields(packed, T, C, S)
+            return (*f[:6], f[6] != 0, f[7])
+
+        for got, want in zip(cut(jax.device_put(buf)), host, strict=True):
+            assert got.dtype == want.dtype and got.shape == want.shape
+            np.testing.assert_array_equal(np.asarray(got), want)
+        # the mask's words are 0 / 1; emitted counts may come as a list
+        assert set(_packed_fields(buf, T, C, S)[6]) <= {0, 1}
+        again = _pack_operands(T, C, S, *host[:7], host[7].tolist())
+        np.testing.assert_array_equal(again, buf)

@@ -236,9 +236,10 @@ def test_spans_after_the_pack_carry_the_round_kind(served, scenario):
 
 def test_the_stats_read_is_a_part_only_where_the_model_has_tick_stats(
         served):
-    """``engine.sync.stats`` brackets the read of the model's counters,
-    which happens only with a tracer: GPT names none and has no such
-    span; the latent model's tick returns its vector and has one."""
+    """``engine.sync.stats`` brackets the noting of the model's counters
+    (they come back behind the tokens, in the one vector the round
+    reads), which happens only with a tracer: GPT names none and has no
+    such span; the latent model's tick returns them and has one."""
     from benchmarks.lib import weights_pangu
     from paddle_tpu.models.pangu_moe import (TICK_STATS, PanguMoeConfig,
                                              PanguMoeModel)
