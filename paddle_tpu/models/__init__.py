@@ -6,3 +6,4 @@ from .ernie_moe import (ErnieMoeConfig, ErnieMoeModel,  # noqa: F401
                         make_ernie_moe_train_step)
 from .pangu_moe import PanguMoeConfig, PanguMoeModel  # noqa: F401
 from .evabyte import EvaByteConfig, EvaByteModel  # noqa: F401
+from .longcat_flash import LongcatFlashConfig, LongcatFlashModel  # noqa: F401

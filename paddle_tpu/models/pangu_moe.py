@@ -48,7 +48,6 @@ kernel and only writes the indexer's keys.
 
 from __future__ import annotations
 
-import math
 from typing import Optional
 
 import jax
@@ -60,7 +59,10 @@ from ..ops.moe import gated_mlp, held_experts_ffn, route_sigmoid_topk
 from ._decode import (CacheLeaf, CacheSpec, CausalDecoderMixin, build_pools,
                       ragged_index_select, ragged_latent_attention,
                       ragged_sparse_latent_attention, ragged_write, rms_norm,
-                      rope_rotate_half, rowwise)
+                      rowwise)
+from ._mla import (MlaGeometry, mla_attend_dense, mla_in, mla_kv_b, mla_out,
+                   mla_rope, mla_softmax_scale)
+from ._mla import yarn_inv_freq, yarn_mscale  # noqa: F401  (their old home)
 
 _MLA = ("ln1_w", "q_a_w", "q_a_norm_w", "q_b_w", "kv_a_w", "kv_a_norm_w",
         "kv_b_w", "o_w", "ln2_w", "ln3_w", "ln4_w")
@@ -77,36 +79,7 @@ TICK_STATS = ("expert_rows", "expert_rows_max", "expert_pairs")
 INDEX_STATS = ("index_candidates", "index_selected")
 
 
-def yarn_inv_freq(D, theta, factor, original_max_position_embeddings,
-                  beta_fast=32, beta_slow=1, **_):
-    """The ``D / 2`` rotary frequencies under YaRN (arXiv:2309.00071, as
-    DeepSeek's ``precompute_freqs_cis`` writes it): frequency ``j`` is the
-    blend ``f_j / factor * r_j + f_j * (1 - r_j)`` of the interpolated and
-    the plain ``f_j = theta ** (-2j / D)``, ``r`` the linear ramp from 0
-    at the correction dim of ``beta_fast`` rotations over the original
-    context (rounded down) to 1 at that of ``beta_slow`` (rounded up)."""
-    def correction_dim(rotations):
-        return D * math.log(original_max_position_embeddings
-                            / (rotations * 2 * math.pi)) \
-            / (2 * math.log(theta))
-    low = max(math.floor(correction_dim(beta_fast)), 0)
-    high = min(math.ceil(correction_dim(beta_slow)), D - 1)
-    if low == high:
-        high += 0.001
-    f = [theta ** (-2.0 * j / D) for j in range(D // 2)]
-    ramp = [min(max((j - low) / (high - low), 0.0), 1.0)
-            for j in range(D // 2)]
-    return [fj / factor * r + fj * (1.0 - r) for fj, r in zip(f, ramp)]
-
-
-def yarn_mscale(factor, mscale_all_dim=1.0, **_):
-    """``m = 0.1 * mscale_all_dim * ln(factor) + 1``: the softmax scale is
-    multiplied by ``m**2``."""
-    return 0.1 * mscale_all_dim * math.log(factor) + 1.0 if factor > 1 \
-        else 1.0
-
-
-class PanguMoeConfig:
+class PanguMoeConfig(MlaGeometry):
     def __init__(self, vocab_size=153600, hidden_size=7680,
                  num_hidden_layers=61, first_k_dense_replace=3,
                  num_attention_heads=128, q_lora_rank=1536,
@@ -183,21 +156,6 @@ class PanguMoeConfig:
     @property
     def num_expert_layers(self):
         return self.num_hidden_layers - self.first_k_dense_replace
-
-    @property
-    def latent_width(self):
-        return self.kv_lora_rank + self.qk_rope_head_dim
-
-    @property
-    def latent_row(self):
-        """Columns of a cached row: ``latent_width`` and zeros up to the
-        next multiple of 128.  A tiled device layout pads a row to whole
-        128-lane tiles whatever its logical width; stating the padded
-        width keeps the pool's default layout row-major, which is the
-        layout the kernel's block DMAs need (at the logical 576 the
-        compiler stores the pool block-minor and transposes the whole of
-        it in and out of every kernel call)."""
-        return -(-self.latent_width // 128) * 128
 
     def stack_names(self, stack):
         """The parameter names of one stack ("dense", "moe") under this
@@ -306,48 +264,20 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
     def _rms(self, x, w):
         return rms_norm(x, w, self.config.rms_norm_eps)
 
-    def _rope(self, x, pos):
-        """Rotate-half rotary positions over the last axis of x (..., D)
-        at positions ``pos`` (broadcast against x's leading axes but the
-        last two: x is (..., heads, D) and pos (...,))."""
-        D = x.shape[-1]
-        c = self.config
-        if c.rope_scaling is None:
-            inv = c.rope_theta ** (
-                -jnp.arange(0, D, 2, dtype=jnp.float32) / D)
-        else:
-            inv = jnp.asarray(yarn_inv_freq(D, c.rope_theta,
-                                            **c.rope_scaling), jnp.float32)
-        return rope_rotate_half(x, pos, inv)
-
     def _stack(self, params, stack):
         return {n: params[f"{stack}_{n}"]
                 for n in self.config.stack_names(stack)}
 
     def _mla_in(self, sl, x, pos):
-        """N1 and the MLA projections of x (..., H) at logical positions
-        ``pos`` (...,): q_nope (..., nh, nope), q_r (..., nh, rope) after
-        rotation, and the row to cache (..., latent_row): c_kv, k_r, zeros.
+        """``mla_in`` (models/_mla.py) of x (..., H) at logical positions
+        ``pos`` (...,): q_nope, q_r after rotation, the row to cache.
         With an indexer, what it projects follows: (q_idx, w_idx, k_idx)
         of ``_index_in``."""
-        c = self.config
-        dt = x.dtype
-        nh, R = c.num_attention_heads, c.kv_lora_rank
-        a = self._rms(x, sl["ln1_w"])
-        c_q = self._rms(a @ sl["q_a_w"].astype(dt), sl["q_a_norm_w"])
-        q = (c_q @ sl["q_b_w"].astype(dt)).reshape(
-            x.shape[:-1] + (nh, c.qk_nope_head_dim + c.qk_rope_head_dim))
-        q_nope, q_r = q[..., :c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
-        kv = a @ sl["kv_a_w"].astype(dt)
-        c_kv = self._rms(kv[..., :R], sl["kv_a_norm_w"])
-        k_r = self._rope(kv[..., None, R:], pos)[..., 0, :]
-        pad = jnp.zeros(x.shape[:-1] + (c.latent_row - c.latent_width,), dt)
-        out = (q_nope, self._rope(q_r, pos),
-               jnp.concatenate([c_kv, k_r, pad], -1))
-        if c.index_topk is not None:
+        *out, a, c_q = mla_in(self.config, sl, x, pos)
+        if self.config.index_topk is not None:
             with jax.named_scope("indexer"):
                 out += self._index_in(sl, a, c_q, pos)
-        return out
+        return tuple(out)
 
     def _index_in(self, sl, a, c_q, pos):
         """The lightning indexer's side of a row: q_idx (..., nhi, Di)
@@ -360,7 +290,7 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         nhi, Di, Dr = c.index_n_heads, c.index_head_dim, c.qk_rope_head_dim
         q = (c_q @ sl["idx_q_b_w"].astype(dt)).reshape(
             a.shape[:-1] + (nhi, Di))
-        q = jnp.concatenate([self._rope(q[..., :Dr], pos), q[..., Dr:]], -1)
+        q = jnp.concatenate([mla_rope(c, q[..., :Dr], pos), q[..., Dr:]], -1)
         k32 = (a @ sl["idx_k_w"].astype(dt)).astype(jnp.float32)
         mu = jnp.mean(k32, -1, keepdims=True)
         k32 = (k32 - mu) * jax.lax.rsqrt(
@@ -368,33 +298,14 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         k = (k32 * sl["idx_k_norm_w"].astype(jnp.float32)
              + sl["idx_k_norm_b"].astype(jnp.float32)).astype(dt)
         k = jnp.concatenate(
-            [self._rope(k[..., None, :Dr], pos)[..., 0, :], k[..., Dr:]], -1)
+            [mla_rope(c, k[..., None, :Dr], pos)[..., 0, :], k[..., Dr:]], -1)
         w = (a @ sl["idx_w_w"].astype(dt)).astype(jnp.float32) \
             * float(nhi * Di) ** -0.5
         return q, w, k
 
-    def _kv_b(self, sl, dt):
-        """W_kvb as (R, nh, nope) for keys and (R, nh, v) for values."""
-        c = self.config
-        w = sl["kv_b_w"].astype(dt).reshape(
-            c.kv_lora_rank, c.num_attention_heads,
-            c.qk_nope_head_dim + c.v_head_dim)
-        return w[..., :c.qk_nope_head_dim], w[..., c.qk_nope_head_dim:]
-
     @property
     def _scale(self):
-        c = self.config
-        scale = float(c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
-        if c.rope_scaling is not None:
-            scale *= yarn_mscale(**c.rope_scaling) ** 2
-        return scale
-
-    def _mla_out(self, sl, x, o):
-        """Heads concatenated, W_o, N2 (under ``sandwich_norm``),
-        residual: o (..., nh, v)."""
-        o = o.reshape(o.shape[:-2] + (-1,)) @ sl["o_w"].astype(x.dtype)
-        return x + (self._rms(o, sl["ln2_w"]) if self.config.sandwich_norm
-                    else o)
+        return mla_softmax_scale(self.config)
 
     def _ffn(self, sl, x, expert: bool, valid=None):
         """N3, F, N4, residual on x (T, H); (x, rows a held expert
@@ -462,7 +373,7 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         c = self.config
         seq = jnp.clip(row_seq, 0, pad_lens.shape[0] - 1)
         pos = jnp.maximum(row_pos - pad_lens[seq], 0)
-        w_k, w_v = self._kv_b(sl, x.dtype)
+        w_k, w_v = mla_kv_b(c, sl, x.dtype)
 
         def project(x, pos):
             q_nope, q_r, latent, *index = self._mla_in(sl, x, pos)
@@ -471,8 +382,8 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
 
         def finish(x, o_lat, valid):
             with jax.named_scope("attn"):
-                x = self._mla_out(sl, x,
-                                  jnp.einsum("thr,rhd->thd", o_lat, w_v))
+                x = mla_out(c, sl, x,
+                            jnp.einsum("thr,rhd->thd", o_lat, w_v))
             x, rows = self._ffn(sl, x, expert, valid=valid)
             return (x,), rows
 
@@ -613,32 +524,10 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                                    k=self.config.index_topk)
         return selected(sc, thr, pad_lens[seq], hi).reshape(B, k, -1)
 
-    def _attend_dense(self, sl, x, cache, q_nope, q_r, t0, pad_lens,
-                      chosen=None):
-        """Absorbed attention of x's rows (B, k, ...) at cache slots
-        [t0, t0 + k) over a dense latent cache (B, Lmax, R + rope);
-        ``chosen`` (B, k, Lmax) bool narrows each row's keys."""
-        c = self.config
-        R, W = c.kv_lora_rank, c.latent_width
-        w_k, w_v = self._kv_b(sl, x.dtype)
-        q_abs = jnp.einsum("bqhd,rhd->bqhr", q_nope, w_k)
-        sc = jnp.einsum("bqhr,bkr->bhqk", q_abs, cache[..., :R],
-                        preferred_element_type=jnp.float32) \
-            + jnp.einsum("bqhd,bkd->bhqk", q_r, cache[..., R:W],
-                         preferred_element_type=jnp.float32)
-        k = jnp.arange(cache.shape[1])
-        mask = k[None, None, :] <= (t0 + jnp.arange(x.shape[1]))[None, :, None]
-        mask = mask & (k[None, None, :] >= pad_lens[:, None, None])
-        if chosen is not None:
-            mask = mask & chosen
-        sc = jnp.where(mask[:, None], sc * self._scale, -1e30)
-        p = jax.nn.softmax(sc, -1).astype(x.dtype)
-        o_lat = jnp.einsum("bhqk,bkr->bqhr", p, cache[..., :R])
-        return jnp.einsum("bqhr,rhd->bqhd", o_lat, w_v)
-
     def _run_dense(self, params, x, caches, t0, pad_lens):
         """Both stacks over x (B, k, H) written at cache slots
         [t0, t0 + k): the body of ``prefill`` and ``decode_step``."""
+        c = self.config
         B, k, H = x.shape
         if pad_lens is None:
             pad_lens = jnp.zeros((B,), jnp.int32)
@@ -651,9 +540,9 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                     with jax.named_scope("attn"):
                         q_nope, q_r, latent, *index = self._mla_in(
                             sl, carry, pos)
-                        put = lambda c, v: \
+                        put = lambda buf, v: \
                             jax.lax.dynamic_update_slice_in_dim(
-                                c, v.astype(c.dtype), t0, axis=1)
+                                buf, v.astype(buf.dtype), t0, axis=1)
                         if not index:
                             lat = ch = put(ch, latent)
                             chosen = None
@@ -663,8 +552,8 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                             ch = (lat, keys)
                             chosen = self._select_dense(
                                 q_idx, w_idx, keys, t0, pad_lens)
-                        y = self._mla_out(sl, carry, self._attend_dense(
-                            sl, carry, lat, q_nope, q_r, t0, pad_lens,
+                        y = mla_out(c, sl, carry, mla_attend_dense(
+                            c, sl, carry, lat, q_nope, q_r, t0, pad_lens,
                             chosen))
                     y, _ = self._ffn(sl, y.reshape(B * k, H), expert)
                     return y.reshape(B, k, H), ch

@@ -261,8 +261,8 @@ def moe_ffn_gather(x, gate_w, w1, b1, w2, b2, k: int = 2,
 
 
 # ---------------------------------------------------------------------------
-# sigmoid routing over more experts than are held, no capacity, no drop
-# (DeepSeek-V3 / Pangu Ultra MoE serving: one chip's share of a layer whose
+# routing over more experts than are held, no capacity, no drop (DeepSeek-V3 /
+# Pangu Ultra MoE / LongCat-Flash serving: one chip's share of a layer whose
 # routed experts are spread expert-parallel over many)
 # ---------------------------------------------------------------------------
 
@@ -304,27 +304,77 @@ def route_sigmoid_topk(x, gate_w, k: int, scaling: float = 1.0,
     return idx.astype(jnp.int32), top * scaling
 
 
+def route_softmax_topk(x, gate_w, k: int, scaling: float = 1.0, bias=None):
+    """Scores ``p = softmax(x W_r)`` in float32 over ALL the router's
+    outputs (LongCat-Flash: the real experts and then the zero-compute
+    ones), the ``k`` largest of ``p + bias`` (``bias`` (E,): the trained
+    selection bias, ``e_score_correction_bias``), their weights ``scaling
+    * p``: made from ``p``, never from ``p + bias``, and not normalised
+    over the chosen.  x (T, H); gate_w (H, E).  Returns (idx (T, k) int32,
+    w (T, k) float32).  No capacity: routing never drops a token."""
+    p = jax.nn.softmax(jnp.matmul(
+        x.astype(jnp.float32), gate_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST), axis=-1)
+    if bias is None:
+        top, idx = jax.lax.top_k(p, k)
+    else:
+        idx = jax.lax.top_k(p + bias.astype(jnp.float32), k)[1]
+        top = jnp.take_along_axis(p, idx, axis=-1)
+    return idx.astype(jnp.int32), top * scaling
+
+
+def identity_experts(x, idx, w, n_real: int, valid=None):
+    """The zero-compute (identity) experts' part of a routed layer:
+    ``(sum_{e chosen, e >= n_real} w_e) * x``.  The router's outputs
+    ``[n_real, E)`` name experts without weights that return their input;
+    every chip applies them to the rows it serves.  x (T, H); idx / w
+    (T, k) from the router; ``valid`` (T,) bool leaves rows out.  Returns
+    (out (T, H) float32, the pairs they took, int32)."""
+    zero = idx >= n_real
+    if valid is not None:
+        zero = zero & valid[:, None]
+    with jax.named_scope("zero_experts"):
+        share = jnp.sum(jnp.where(zero, w, 0.0), -1, keepdims=True)
+        return share * x.astype(jnp.float32), jnp.sum(zero, dtype=jnp.int32)
+
+
 def held_experts_ffn(x, idx, w, w_gate, w_up, w_down, first: int,
-                     valid=None):
+                     valid=None, n_real: Optional[int] = None, layer=None):
     """The held experts' part of a routed layer, dropless:
     ``sum_{e held} w_e * W_down_e(silu(W_gate_e x) * (W_up_e x))``.
 
-    x (T, H); idx / w (T, k) from the router over all experts; w_gate /
-    w_up (Eh, H, F), w_down (Eh, F, H): the experts ``[first, first + Eh)``
-    held here.  ``valid`` (T,) bool leaves rows out (the pack's padding
-    rows).  Returns (out (T, H) float32, rows (Eh,) int32: the pairs each
-    held expert computed).
+    x (T, H); idx / w (T, k) from the router over all its outputs; w_gate
+    / w_up (Eh, H, F), w_down (Eh, F, H): the experts ``[first, first +
+    Eh)`` held here.  ``valid`` (T,) bool leaves rows out (the pack's
+    padding rows).  Returns (out (T, H) float32, rows (Eh,) int32: the
+    pairs each held expert computed).
 
     Token-expert pairs whose expert is held are sorted by expert (the
     others sort behind them) and go through three grouped products
     (``jax.lax.ragged_dot``).  No pair is dropped, whatever the imbalance:
     the row buffer holds ``T * min(k, Eh)`` rows, and no routing can fill
-    more — a token's ``k`` experts are distinct (``top_k``), so at most
+    more — a token's ``k`` choices are distinct (``top_k``), so at most
     ``min(k, Eh)`` of them are held, and the held pairs, sorted first,
     all lie inside the buffer.  Rows behind them are computed by no group
-    and weigh nothing."""
+    and weigh nothing.
+
+    ``idx`` may also name outputs that are no real expert (zero-compute
+    experts, ``identity_experts``): they are never held — ``n_real``, the
+    number of real experts, where the caller passes it, must cover the
+    held range — so they sort behind the buffer with the absent experts'
+    pairs and the bound stands.
+
+    With ``layer`` (a traced index) the three weights are a whole stack's,
+    ``(L, Eh, ...)``, and that layer's experts are read in place: the
+    grouped products run over all ``L * Eh`` experts with every other
+    layer's group empty.  A grouped product is a kernel call, its operands
+    buffers: sliced out of the stack by a layer scan, the layer's experts
+    are copied in every round (1.2 GB a layer at LongCat-Flash's widths)."""
     T, k = idx.shape
-    Eh = w_gate.shape[0]
+    Eh = w_gate.shape[0 if layer is None else 1]
+    if n_real is not None and not 0 <= first <= first + Eh <= n_real:
+        raise ValueError(f"held experts [{first}, {first + Eh}) are not "
+                         f"among the {n_real} real experts")
     R = T * min(k, Eh)
     local = idx - first
     held = (local >= 0) & (local < Eh)
@@ -336,10 +386,17 @@ def held_experts_ffn(x, idx, w, w_gate, w_up, w_down, first: int,
         rows = jnp.zeros(Eh + 1, jnp.int32).at[key].add(1)[:Eh]
         xs = x[order[:R] // k]                             # (R, H)
     with jax.named_scope("experts"):
-        g = jax.lax.ragged_dot(xs, w_gate.astype(x.dtype), rows)
-        u = jax.lax.ragged_dot(xs, w_up.astype(x.dtype), rows)
+        sizes = rows
+        if layer is not None:
+            L = w_gate.shape[0]
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros(L * Eh, jnp.int32), rows, (layer * Eh,))
+            w_gate, w_up, w_down = (a.reshape((L * Eh,) + a.shape[2:])
+                                    for a in (w_gate, w_up, w_down))
+        g = jax.lax.ragged_dot(xs, w_gate.astype(x.dtype), sizes)
+        u = jax.lax.ragged_dot(xs, w_up.astype(x.dtype), sizes)
         y = jax.lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype),
-                               w_down.astype(x.dtype), rows)
+                               w_down.astype(x.dtype), sizes)
     with jax.named_scope("router"):                        # the un-sort
         # each pair reads its row back by its place in the sorted order
         # (a gather: a scatter-add of the rows costs fifteen times as much

@@ -638,6 +638,72 @@ def materialised(text, shapes):
 
 
 @pytest.mark.parametrize("rows", ["budget", "narrow"])
+def test_longcat_serving_tick_compiles_at_the_cells_shape(v5e, rows):
+    """The whole tick of ``longcat-serve-toolturns`` — the configuration
+    file, the traffic file's engine, the program's own tick builder — for
+    a described v5e, at the budget's 2,048 rows and at the 16 rows of the
+    program that rounds of decode rows run: it fits (arguments +
+    temporaries under 15.0 GB; 13.42 and 12.23), holds its ONE pool leaf
+    of eight sublayers once, and calls the latent kernel twice (one rolled
+    layer of two sublayers).
+
+    And no layer's large weights are copied out of their stacks: the
+    program has no ``conditional``, and nothing outside a fused
+    computation has the shape of a layer's expert stack (sliced by the
+    layer scan, each of the three is a 403 MB operand buffer of its
+    grouped product, written in every round: ``held_experts_ffn``'s
+    ``layer=`` reads it in place) or of a sublayer's dense-FFN matrix,
+    ``W_o`` or ``W_qa``, alone or as the pair a layer holds (sliced by
+    the scan as pairs they were copied too, 1.27 GB a layer a round and
+    11% of the device's time on the chip: the layer indexes the flat
+    stack at ``2 l + j`` itself).  What remains is the transposing copy
+    of ``W_qb`` and ``W_kvb`` (55 MB a sublayer), which the Pangu tick
+    has too."""
+    import re
+    import sys
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmarks.lib import harness, serve_scmoe, weights_longcat
+    cfg = harness.load_json("configs", "longcat-flash-chat-ep32.json")
+    eng = harness.load_json("traffic", "toolturns-backlog.json")["engine"]
+    table = weights_longcat.param_table(cfg)
+    params = {n: on_one(v5e, shape, jnp.bfloat16)
+              for n, (shape, _) in table.items()}
+    engine = serve_scmoe.build_engine(
+        cfg, dict(eng, num_blocks=1, max_slots=1), {}, None)
+    engine.NB, engine.S = eng["num_blocks"], eng["max_slots"]
+    engine.narrow_rows = -(-eng["max_slots"] // 8) * 8
+    C = eng["max_len"] // eng["block_size"]
+    assert C == engine.MB == 520 and engine.narrow_rows == 16
+    T = eng["token_budget"] if rows == "budget" else engine.narrow_rows
+    assert eng["token_budget"] > 2 * engine.narrow_rows
+    args = jax.eval_shape(lambda: engine._ragged_scratch_args(C, T))
+    args = jax.tree.map(
+        lambda a: on_one(v5e, a.shape, a.dtype) if hasattr(a, "shape")
+        else a, (params,) + tuple(args[1:]))
+    with jax.default_matmul_precision("default"):
+        compiled = engine._build_ragged_step(T, C).lower(*args).compile()
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9, ma
+    pool = 8 * (eng["num_blocks"] + 1) * 16 * 640 * 2
+    assert ma.alias_size_in_bytes >= pool           # donated, held once
+    text = compiled.as_text()
+    names = kernel_op_names(text)
+    stem = "ragged_latent_attention"
+    assert sum(f"/{stem}/" in n for n in names) == 2, names
+    assert not re.search(r"\bconditional\(", text)
+    experts = {(16, 6144, 2048), (16, 2048, 6144)}
+    assert {shape[1:] for n, (shape, _) in table.items()
+            if n.startswith("layers_e_")} == experts
+    own = {(6144, 12288), (12288, 6144), (8192, 6144), (6144, 1536)}
+    assert own <= {shape[2:] for n, (shape, _) in table.items()}
+    own |= {(2,) + s for s in own}
+    assert not materialised(text, experts | own)
+    assert ma.temp_size_in_bytes < (1.9e9 if rows == "budget" else 0.6e9)
+
+
+
+@pytest.mark.parametrize("rows", ["budget", "narrow"])
 def test_eva_serving_tick_compiles_at_the_cells_shape(v5e, rows):
     """The whole tick of ``evabyte-serve-bytedocs`` — the configuration
     file, the traffic file's engine, the program's own tick builder — for
