@@ -116,38 +116,6 @@ def rope_rotate_half(x, pos, inv_freq):
                            -1).astype(x.dtype)
 
 
-def rowwise(few, fn, *rows):
-    """``fn(*rows) -> (row-wise outputs, anything else)`` over arrays
-    whose leading axis is the pack's rows.  ``few`` is None (never),
-    or ``(n, flag)``: where the traced bool ``flag`` says that every
-    real row lies in the first ``n``, ``fn`` runs over those rows
-    alone and its row-wise outputs are padded back with zeros.  The
-    program's row count is the token budget, and at a budget of 2,048
-    a round of 16 decode rows would pay a whole chunk's products.  The
-    pools never pass through the ``cond`` (it would copy them): writes
-    and the kernel take all the rows and skip the padding themselves.
-
-    What it costs (PERF.md section 6, PR 39): every array ``fn`` closes
-    over is an operand of the ``conditional``, an operand has to be a
-    buffer, and so inside a rolled layer scan each layer's weights are
-    copied out of their stack in EVERY round, whichever branch runs.
-    The remedy is a program of the few rows' own, chosen by the host
-    (``ragged_narrow_rounds``, the ragged engine's ``narrow_rows``); the
-    latent model is the one caller left, until it moves there."""
-    if few is None:
-        return fn(*rows)
-    n, flag = few
-    T = rows[0].shape[0]
-
-    def first(*rows):
-        out, rest = fn(*(r[:n] for r in rows))
-        return jax.tree.map(
-            lambda o: jnp.pad(o, ((0, T - n),)
-                              + ((0, 0),) * (o.ndim - 1)), out), rest
-
-    return jax.lax.cond(flag, first, fn, *rows)
-
-
 def cached_attention(q, ck, cv, t, pad_lens=None):
     """Attention for new tokens written at cache slots [t, t+k) against a
     static KV cache: query row i attends to positions ≤ t + i (causal within
@@ -960,6 +928,7 @@ class CausalDecoderMixin:
     # its slots, rounded up to 8 — and dispatches a round of decode rows
     # only to that program: the host knows the pack before it dispatches.
     # A class whose tick must stay one program at one width says False
+    # (none in the tree since PR 46; the engine's tests build one)
     ragged_narrow_rounds = True
 
     def decode_ragged(self, params, h, pools, table, row_seq, row_pos,

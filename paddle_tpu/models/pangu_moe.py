@@ -58,8 +58,7 @@ from ..nn.layer.base import Layer
 from ..ops.moe import gated_mlp, held_experts_ffn, route_sigmoid_topk
 from ._decode import (CacheLeaf, CacheSpec, CausalDecoderMixin, build_pools,
                       ragged_index_select, ragged_latent_attention,
-                      ragged_sparse_latent_attention, ragged_write, rms_norm,
-                      rowwise)
+                      ragged_sparse_latent_attention, ragged_write, rms_norm)
 from ._mla import (MlaGeometry, mla_attend_dense, mla_in, mla_kv_b, mla_out,
                    mla_rope, mla_softmax_scale)
 from ._mla import yarn_inv_freq, yarn_mscale  # noqa: F401  (their old home)
@@ -71,6 +70,9 @@ _STACKS = {
     "moe": _MLA + ("router_w", "e_gate_w", "e_up_w", "e_down_w",
                    "s_gate_w", "s_up_w", "s_down_w"),
 }
+# never sliced by a layer scan: the grouped products read a layer's experts
+# in place in the whole stack (ops/moe.py held_experts_ffn)
+_EXPERTS = ("e_gate_w", "e_up_w", "e_down_w")
 _SANDWICH = ("ln2_w", "ln4_w")      # only under ``sandwich_norm``
 _INDEXER = ("idx_q_b_w", "idx_k_w", "idx_k_norm_w", "idx_k_norm_b",
             "idx_w_w")              # only with ``index_topk``
@@ -265,8 +267,16 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         return rms_norm(x, w, self.config.rms_norm_eps)
 
     def _stack(self, params, stack):
-        return {n: params[f"{stack}_{n}"]
-                for n in self.config.stack_names(stack)}
+        """(the stack's parameters that a layer scan slices a layer at a
+        time, the expert stacks whole — () for the dense stack): a layer
+        indexes its experts itself.  Sliced by the scan they are copied
+        out of their stacks a layer a round, 1.4-1.5 GB at the latent
+        cells' widths (PERF.md section 6, PR 46)."""
+        names = self.config.stack_names(stack)
+        return ({n: params[f"{stack}_{n}"] for n in names
+                 if n not in _EXPERTS},
+                tuple(params[f"{stack}_{n}"] for n in _EXPERTS
+                      if n in names))
 
     def _mla_in(self, sl, x, pos):
         """``mla_in`` (models/_mla.py) of x (..., H) at logical positions
@@ -307,15 +317,17 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
     def _scale(self):
         return mla_softmax_scale(self.config)
 
-    def _ffn(self, sl, x, expert: bool, valid=None):
-        """N3, F, N4, residual on x (T, H); (x, rows a held expert
-        computed (Eh,) or None)."""
+    def _ffn(self, sl, experts, layer, x, valid=None):
+        """N3, F, N4, residual on x (T, H) in layer ``layer`` of its
+        stack; ``experts`` the stack's three expert stacks, whole, or ()
+        where F is the dense MLP.  Returns (x, rows a held expert computed
+        (Eh,) or None)."""
         c = self.config
         n4 = (lambda f: self._rms(f, sl["ln4_w"])) if c.sandwich_norm \
             else (lambda f: f)
         with jax.named_scope("mlp"):
             m = self._rms(x, sl["ln3_w"])
-            if not expert:
+            if not experts:
                 return x + n4(gated_mlp(
                     m, sl["gate_w"], sl["up_w"], sl["down_w"])), None
             with jax.named_scope("router"):
@@ -326,8 +338,8 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                     m, sl["router_w"], c.num_experts_per_tok,
                     c.routed_scaling_factor, c.norm_topk_prob, **grouped)
             routed, rows = held_experts_ffn(
-                m, idx, w, sl["e_gate_w"], sl["e_up_w"], sl["e_down_w"],
-                c.experts_held.start, valid)
+                m, idx, w, *experts, c.experts_held.start, valid,
+                layer=layer)
             with jax.named_scope("shared_expert"):
                 shared = gated_mlp(m, sl["s_gate_w"], sl["s_up_w"],
                                    sl["s_down_w"])
@@ -349,17 +361,8 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
             return jnp.take(params["wte"], toks, axis=0)[None].astype(
                 jnp.dtype(self.config.compute_dtype))
 
-    _rowwise = staticmethod(rowwise)    # models/_decode.py
-    # One width, and the small pack a branch inside it, for now: the
-    # narrow program would move the rounding of a decode round's rows,
-    # and ``pangu-serve-longdocs``' check refuses a sound run whose
-    # rounding moved on about one seed in 15 to 45 (PERF.md section 7
-    # item 0).  A ``benchmark`` issue mends that yardstick first; then
-    # this tick moves to the narrow program and ``rowwise`` goes
-    ragged_narrow_rounds = False
-
-    def _block_ragged(self, sl, x, pool, layer, table, row_seq, row_pos,
-                      pad_lens, expert, few=None):
+    def _block_ragged(self, sl, experts, x, pool, layer, table, row_seq,
+                      row_pos, pad_lens):
         """One block for a flattened pack x (T, H) over layer ``layer`` of
         its stack's latent pools (L, NB+1, bs, W): write each row's
         latent, then attend (absorbed) — both in place in the stack, which
@@ -368,29 +371,18 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         indexer ``pool`` is the stack's pair (latent rows, indexer keys):
         both are written, and where the table holds more positions than
         ``index_topk`` a row attends the positions the indexer selects.
-        Returns (x, pool, rows a held expert computed, the selection
-        ``(scores, thr)`` or None)."""
+        ``experts`` as ``_stack`` gives them.  No branch on the pack: a
+        round of decode rows only runs the engine's narrow program
+        (``ragged_narrow_rounds``).  Returns (x, pool, rows a held expert
+        computed, the selection ``(scores, thr)`` or None)."""
         c = self.config
         seq = jnp.clip(row_seq, 0, pad_lens.shape[0] - 1)
         pos = jnp.maximum(row_pos - pad_lens[seq], 0)
         w_k, w_v = mla_kv_b(c, sl, x.dtype)
-
-        def project(x, pos):
-            q_nope, q_r, latent, *index = self._mla_in(sl, x, pos)
-            return (jnp.einsum("thd,rhd->thr", q_nope, w_k), q_r,
-                    latent, *index), ()
-
-        def finish(x, o_lat, valid):
-            with jax.named_scope("attn"):
-                x = mla_out(c, sl, x,
-                            jnp.einsum("thr,rhd->thd", o_lat, w_v))
-            x, rows = self._ffn(sl, x, expert, valid=valid)
-            return (x,), rows
-
         chosen = None
         with jax.named_scope("attn"):
-            (q_abs, q_r, latent, *index), _ = self._rowwise(
-                few, project, x, pos)
+            q_nope, q_r, latent, *index = self._mla_in(sl, x, pos)
+            q_abs = jnp.einsum("thd,rhd->thr", q_nope, w_k)
             if not index:
                 pool = ragged_write(pool, latent, table, row_seq, row_pos,
                                     layer=layer)
@@ -415,7 +407,8 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                 o_lat = ragged_sparse_latent_attention(
                     q_abs, q_r, lat_pool, *chosen, table, row_seq, row_pos,
                     pad_lens, scale=self._scale, layer=layer)
-        (x,), rows = self._rowwise(few, finish, x, o_lat, row_pos >= 0)
+            x = mla_out(c, sl, x, jnp.einsum("thr,rhd->thd", o_lat, w_v))
+        x, rows = self._ffn(sl, experts, layer, x, valid=row_pos >= 0)
         return x, pool, rows, chosen
 
     @staticmethod
@@ -450,23 +443,17 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         asks."""
         c = self.config
         x = h[0]
-        # a round of decode rows only has at most one real row a slot
-        # (``pad_lens`` has a row a slot), and the engine packs real rows
-        # first: where the program is over twice that wide, see
-        # ``_rowwise``.  What is observed is the pack, so a round with
-        # real rows further back takes the whole-width branch
-        slots = pad_lens.shape[0]
-        few = (slots, jnp.all(row_pos[slots:] < 0)) \
-            if x.shape[0] > 2 * slots else None
-        seq = jnp.clip(row_seq, 0, slots - 1)
+        seq = jnp.clip(row_seq, 0, pad_lens.shape[0] - 1)
         out_pools, rows, masks = [], None, []
         with jax.named_scope("layers"):
             for stack, pool in zip(("dense", "moe"), pools):
-                def body(carry, xs, expert=stack == "moe"):
+                stacked, experts = self._stack(params, stack)
+
+                def body(carry, xs, experts=experts):
                     sl, i = xs
                     y, p, r, chosen = self._block_ragged(
-                        sl, carry[0], carry[1], i, table, row_seq, row_pos,
-                        pad_lens, expert, few)
+                        sl, experts, carry[0], carry[1], i, table, row_seq,
+                        row_pos, pad_lens)
                     if selection_of is not None:
                         at = slice(selection_of[0], sum(selection_of))
                         r = (r, self._selected(
@@ -474,7 +461,7 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                             row_pos[at], at))
                     return (y, p), r
                 (x, pool), r = jax.lax.scan(
-                    body, (x, pool), (self._stack(params, stack),
+                    body, (x, pool), (stacked,
                                       jnp.arange(jax.tree.leaves(pool)[0]
                                                  .shape[0])))
                 if selection_of is not None:
@@ -535,8 +522,10 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
         out = []
         with jax.named_scope("layers"):
             for stack, cache in zip(("dense", "moe"), caches):
-                def body(carry, xs, expert=stack == "moe"):
-                    sl, ch = xs
+                stacked, experts = self._stack(params, stack)
+
+                def body(carry, xs, experts=experts):
+                    sl, i, ch = xs
                     with jax.named_scope("attn"):
                         q_nope, q_r, latent, *index = self._mla_in(
                             sl, carry, pos)
@@ -555,10 +544,11 @@ class PanguMoeModel(CausalDecoderMixin, Layer):
                         y = mla_out(c, sl, carry, mla_attend_dense(
                             c, sl, carry, lat, q_nope, q_r, t0, pad_lens,
                             chosen))
-                    y, _ = self._ffn(sl, y.reshape(B * k, H), expert)
+                    y, _ = self._ffn(sl, experts, i, y.reshape(B * k, H))
                     return y.reshape(B, k, H), ch
                 x, cache = jax.lax.scan(
-                    body, x, (self._stack(params, stack), cache))
+                    body, x, (stacked, jnp.arange(
+                        jax.tree.leaves(cache)[0].shape[0]), cache))
                 out.append(cache)
         return x, tuple(out)
 

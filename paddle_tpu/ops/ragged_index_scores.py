@@ -60,10 +60,14 @@ def _scores_kernel(table_ref, seq_ref, pos_ref, layer_ref, q_ref, w_ref,
         seq = seq_ref[r0 + lo]
         last = pos_ref[r0 + lo] + (n - 1)
         steps = last // keys + 1
+        # once a group of rows, not once a copy: each ``//`` of a traced
+        # integer is a dozen operations to trace and lower, and a step's
+        # copies asked 48 times (a third of a tick program's lowering)
+        deepest = last // bs
 
         def copies(g, slot):
             for k in range(kb):
-                col = jnp.minimum(g * kb + k, last // bs)
+                col = jnp.minimum(g * kb + k, deepest)
                 yield pltpu.make_async_copy(
                     pool_ref.at[layer, table_ref[seq, col]],
                     buf.at[slot, pl.ds(k * bs, bs)], sem.at[slot])

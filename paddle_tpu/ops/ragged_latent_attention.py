@@ -87,13 +87,17 @@ def _latent_kernel(table_ref, seq_ref, pos_ref, pad_ref, layer_ref, qa_ref,
         first = pos_ref[r0 + lo]
         last = first + (n - 1)
         steps = last // keys + 1
+        # once a group of rows, not once a copy: each ``//`` of a traced
+        # integer is a dozen operations to trace and lower, and a step's
+        # copies asked 48 times (a third of a tick program's lowering)
+        deepest = last // bs
         pad = pad_ref[seq]
 
         def copies(g, slot):
             for k in range(kb):
                 # clamp to the rows' deepest in-range column: a step's
                 # tail re-reads that block and masks it
-                col = jnp.minimum(g * kb + k, last // bs)
+                col = jnp.minimum(g * kb + k, deepest)
                 yield pltpu.make_async_copy(
                     pool_ref.at[layer, table_ref[seq, col]],
                     buf.at[slot, pl.ds(k * bs, bs)], sem.at[slot])

@@ -488,25 +488,58 @@ def test_sparse_attention_kernels_compile(v5e, cols):
         < T * cols * BLOCK * 4 + (192 << 20)
 
 
-def test_sparse_serving_tick_compiles_at_the_cells_shape(v5e):
-    """The whole tick of ``dsv32-serve-longctx`` — the configuration file,
-    the traffic file's engine, the program's own tick builder — for a
-    described v5e: it fits (arguments + temporaries under 15.0 GB), holds
-    each pool once, and calls the two sparse kernels and the threshold
-    search in both stacks."""
+LATENT_CELLS = {
+    # cell: (configuration, traffic, the lib of its driver, its weights,
+    #        pool bytes a block position, kernels called once a stack)
+    "longdocs": ("openpangu-ultra-moe-718b-ep16.json",
+                 "longdocs-backlog.json", "serve_latent", "weights_pangu",
+                 640 * 2, ("ragged_latent_attention/",)),
+    "longctx": ("deepseek-v3.2-exp-ep16.json", "longctx-backlog.json",
+                "serve_sparse", "weights_dsv32", (640 + 128) * 2,
+                ("ragged_index_scores", "select/",
+                 "ragged_sparse_latent_attention")),
+}
+
+
+@pytest.mark.parametrize("rows", ["budget", "narrow"])
+@pytest.mark.parametrize("cell", ["longdocs", "longctx"])
+def test_latent_serving_ticks_compile_at_the_cells_shapes(v5e, cell, rows):
+    """The whole tick of ``pangu-serve-longdocs`` and of
+    ``dsv32-serve-longctx`` — the configuration file, the traffic file's
+    engine, the program's own tick builder — for a described v5e, at the
+    budget's 2,048 rows and at the rows of the program that rounds of
+    decode rows run (16 and 8): it fits (arguments + temporaries under
+    15.0 GB), holds each pool once, and calls its kernels in both stacks.
+
+    And the expert layer's weights are not copied out of their stacks
+    (PR 46): the program has no ``conditional`` — ``_decode.rowwise``'s,
+    which made every array its branches closed over an operand buffer, is
+    gone with the function — and nothing outside a fused computation has
+    the shape of a layer's slice of an expert stack: sliced by the layer
+    scan each of the three was the operand buffer of its grouped product,
+    1.4-1.5 GB a layer a round together; ``held_experts_ffn``'s
+    ``layer=`` reads a layer's experts in place."""
+    import importlib
+    import re
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    from benchmarks.lib import harness, serve_sparse, weights_dsv32
-    cfg = harness.load_json("configs", "deepseek-v3.2-exp-ep16.json")
-    eng = harness.load_json("traffic", "longctx-backlog.json")["engine"]
+    from benchmarks.lib import harness
+    config, traffic, lib, weights, row_bytes, kernels = LATENT_CELLS[cell]
+    lib, weights = (importlib.import_module(f"benchmarks.lib.{m}")
+                    for m in (lib, weights))
+    cfg = harness.load_json("configs", config)
+    eng = harness.load_json("traffic", traffic)["engine"]
+    table = weights.param_table(cfg)
     params = {n: on_one(v5e, shape, jnp.bfloat16)
-              for n, (shape, _) in weights_dsv32.param_table(cfg).items()}
-    engine = serve_sparse.build_engine(cfg, dict(eng, num_blocks=1), {},
-                                       None)
+              for n, (shape, _) in table.items()}
+    engine = lib.build_engine(cfg, dict(eng, num_blocks=1), {}, None)
     engine.NB = eng["num_blocks"]
     C = eng["max_len"] // eng["block_size"]
-    args = jax.eval_shape(lambda: engine._ragged_scratch_args(C))
+    assert C == engine.MB
+    assert engine.narrow_rows == -(-eng["max_slots"] // 8) * 8
+    T = eng["token_budget"] if rows == "budget" else engine.narrow_rows
+    args = jax.eval_shape(lambda: engine._ragged_scratch_args(C, T))
     args = jax.tree.map(
         lambda a: on_one(v5e, a.shape, a.dtype) if hasattr(a, "shape")
         else a, (params,) + tuple(args[1:]))
@@ -515,16 +548,22 @@ def test_sparse_serving_tick_compiles_at_the_cells_shape(v5e):
     # for the experts' grouped product refuses a float32 product of
     # bfloat16 operands
     with jax.default_matmul_precision("default"):
-        compiled = engine._build_ragged_step(
-            eng["token_budget"], C).lower(*args).compile()
+        compiled = engine._build_ragged_step(T, C).lower(*args).compile()
     ma = compiled.memory_analysis()
     assert ma.argument_size_in_bytes + ma.temp_size_in_bytes < 15.0e9, ma
-    pools = 5 * (eng["num_blocks"] + 1) * 16 * (640 + 128) * 2
+    pools = cfg["num_hidden_layers"] * (eng["num_blocks"] + 1) * 16 \
+        * row_bytes
     assert ma.alias_size_in_bytes >= pools          # donated, held once
-    names = kernel_op_names(compiled.as_text())
-    for stem in ("ragged_index_scores", "select/",
-                 "ragged_sparse_latent_attention"):
+    text = compiled.as_text()
+    names = kernel_op_names(text)
+    for stem in kernels:
         assert sum(stem in n for n in names) == 2, (stem, names)
+    assert not re.search(r"\bconditional\(", text)
+    experts = {shape[1:] for n, (shape, _) in table.items()
+               if n.startswith("moe_e_")}
+    H, F = cfg["hidden_size"], cfg["moe_intermediate_size"]
+    assert experts == {(16, H, F), (16, F, H)}
+    assert not materialised(text, experts)
 
 
 def kernel_op_names(text):

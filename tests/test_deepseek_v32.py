@@ -82,10 +82,11 @@ def interpret(request):
     paddle.set_flags({"FLAGS_paged_attn_interpret": False})
 
 
-def engine(model, params, **kw):
+def engine(model, params, token_budget=16, **kw):
     return RaggedPagedContinuousBatchingEngine(
         model, params, max_slots=3, max_len=64, block_size=8, num_blocks=20,
-        token_budget=16, prompt_buckets=list(range(8, 65, 8)), **kw)
+        token_budget=token_budget, prompt_buckets=list(range(8, 65, 8)),
+        **kw)
 
 
 # ----------------------------------------------------------- the model --
@@ -178,6 +179,39 @@ def test_engine_prefill_then_decode_matches_the_reference(dtype, interpret):
     assert cache["layout"] == "latent" and cache["leaf_bytes"] == [
         n * 21 * 8 * w * item for n in (1, 2) for w in (128, 8)]
     assert cache["pool_bytes"] == sum(cache["leaf_bytes"])
+
+
+@pytest.mark.parametrize("interpret", [False, True], indirect=True,
+                         ids=["xla", "kernel"])
+def test_narrow_rounds_give_the_wide_programs_tokens(interpret):
+    """With an indexer too: rounds of decode rows only run the tick at 8
+    rows, over the widest table (64 positions against ``index_topk`` 8: the
+    selection bites in the narrow program as in the wide ones), and serve
+    the budget-wide program's tokens; the indexer's counters come back
+    from either."""
+    model, params = build("float32")
+    ids = np.random.default_rng(2).integers(1, 96, 40)
+    prompts = [ids[:21].tolist(), ids[5:18].tolist(), ids[3:32].tolist(),
+               ids[:9].tolist()]
+
+    def serve(eng):
+        rids = [eng.add_request(p, 6) for p in prompts]
+        done = eng.run_to_completion()
+        return [done[r] for r in rids]
+
+    tr = Tracer()
+    eng = engine(model, params, token_budget=24, tracer=tr)
+    wide = engine(model, params, token_budget=24)
+    assert eng.narrow_rows == 8
+    wide.narrow_rows = 0
+    assert serve(eng) == serve(wide)
+    assert 0 < eng.narrow_steps < eng.ragged_steps and eng.mixed_steps
+    assert wide.narrow_steps == 0 and wide.ragged_steps == eng.ragged_steps
+    narrow = [k for k in tr.events("tick")
+              if k.get("budget_used") and not k["prefill_tokens"]]
+    assert narrow and all(k["rows_run"] == 8 for k in narrow)
+    assert all(0 < k["index_selected"] <= k["index_candidates"]
+               for k in narrow)
 
 
 def test_without_a_tracer_the_tick_returns_what_it_returned():

@@ -64,10 +64,11 @@ def interpret(request):
     paddle.set_flags({"FLAGS_paged_attn_interpret": False})
 
 
-def engine(model, params, **kw):
+def engine(model, params, token_budget=16, **kw):
     return RaggedPagedContinuousBatchingEngine(
         model, params, max_slots=3, max_len=64, block_size=8, num_blocks=20,
-        token_budget=16, prompt_buckets=list(range(8, 65, 8)), **kw)
+        token_budget=token_budget, prompt_buckets=list(range(8, 65, 8)),
+        **kw)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -134,44 +135,36 @@ def test_generate_agrees_with_the_engine():
     assert got == list(want)
 
 
-@pytest.mark.parametrize("real", [5, 8, 11], ids=["few", "edge", "over"])
-def test_a_pack_of_few_rows_runs_the_same_blocks_over_those_rows(real):
-    """A program over twice as wide as the engine has slots runs its
-    row-wise work over the first ``slots`` rows alone when no real row
-    lies behind them: the real rows' hidden states, the pools and the
-    counters are those of the same pack with as many idle slots as make
-    the program too narrow to have the branch."""
+@pytest.mark.parametrize("interpret", [False, True], indirect=True,
+                         ids=["xla", "kernel"])
+def test_narrow_rounds_give_the_wide_programs_tokens(interpret):
+    """Rounds of decode rows only run the tick at 8 rows (3 slots, a
+    budget of 24), rounds with a chunk — some of them beside decode rows —
+    the budget-wide one: greedy tokens are those of the budget-wide
+    program alone, which is what every round ran while this model's tick
+    held a branch on the pack instead (``_decode.rowwise``, until
+    PR 46)."""
     model, params = build("float32")
-    C, bs, T = 16, 8, 24
-    from paddle_tpu.models._decode import build_pools
-    blocks = np.random.default_rng(8).permutation(3 * C).reshape(3, C) + 1
+    ids = np.random.default_rng(2).integers(1, 96, 40)
+    prompts = [ids[:21].tolist(), ids[5:18].tolist(), ids[3:32].tolist(),
+               ids[:9].tolist()]
 
-    def run(slots):
-        rng = np.random.default_rng(8)
-        table = np.zeros((slots, C), np.int32)
-        table[:3] = blocks
-        pads = np.zeros(slots, np.int32)
-        pads[1] = 2
-        seq = np.full(T, -1, np.int32)
-        pos = np.full(T, -1, np.int32)
-        seq[:real] = np.arange(real) % 3
-        # each sequence's rows at consecutive positions, as a chunk has them
-        for q in range(3):
-            mine = np.flatnonzero(seq[:real] == q)
-            pos[mine] = 3 + np.arange(len(mine))
-        toks = jnp.asarray(rng.integers(1, 96, T) * (pos >= 0), jnp.int32)
-        h = model._embed_ragged(params, toks, None, None, None)
-        return model.decode_ragged(
-            params, h, build_pools(model.cache_spec(), (3 * C + 1, bs)),
-            jnp.asarray(table), jnp.asarray(seq), jnp.asarray(pos),
-            jnp.asarray(pads))
+    def serve(eng):
+        rids = [eng.add_request(p, 6) for p in prompts]
+        done = eng.run_to_completion()
+        return [done[r] for r in rids]
 
-    h_big, pools_big, stats_big = run(8)            # 24 > 2 x 8: the branch
-    h_ref, pools_ref, stats_ref = run(12)           # 24 = 2 x 12: none
-    assert float(jnp.abs(h_big[0, :real] - h_ref[0, :real]).max()) < 1e-5
-    for a, b in zip(pools_big, pools_ref):
-        assert float(jnp.abs(a[:, 1:] - b[:, 1:]).max()) < 1e-5
-    assert stats_big.tolist() == stats_ref.tolist()
+    tr = Tracer()
+    eng = engine(model, params, token_budget=24, tracer=tr)
+    wide = engine(model, params, token_budget=24)
+    assert eng.narrow_rows == 8 and PanguMoeModel.ragged_narrow_rounds
+    wide.narrow_rows = 0
+    assert serve(eng) == serve(wide)
+    assert 0 < eng.narrow_steps < eng.ragged_steps and eng.mixed_steps
+    assert wide.narrow_steps == 0 and wide.ragged_steps == eng.ragged_steps
+    for k in tr.events("tick"):
+        if k.get("budget_used"):
+            assert k["rows_run"] == (24 if k["prefill_tokens"] else 8)
 
 
 def pack(rng, dtype, nh=4, R=32, Dr=8, NB=20, bs=4, S=3, C=8, W=None):
@@ -343,28 +336,35 @@ def test_prefix_lookup_works_on_a_latent_cache():
 
 # sha256 of the Pangu tick's lowering under this suite's conftest, by
 # (dtype, kernels interpreted, table width).  Taken on the tree before
-# PR 36 (commit 3a775a4) and re-taken in PR 43, whose tick takes one packed
-# operand and the stream's key and returns tokens and counters as one
-# vector and the next key: against the parent's text (41edd05) only the
-# parameters, their slices, the key's split and the outputs' concatenation
-# moved (CHANGES.md, PR 43)
+# PR 36 (commit 3a775a4), re-taken in PR 43 (the tick's one packed operand,
+# the stream's key, tokens and counters as one vector) and in PR 46, which
+# moved what the text was pinned to keep: the two ``stablehlo.case`` of
+# ``_decode.rowwise`` a stack are gone with the function (a round of
+# decode rows only runs the narrow program instead), and the expert
+# stacks are no ``xs`` of the layer scan — the three grouped products take
+# the whole stack with every other layer's group empty
+# (``held_experts_ffn(layer=)``); and in the four texts that hold the
+# interpreted kernel, its walk asks for a group's deepest block once
+# (``ops/ragged_latent_attention.py``: the same column, a third of a tick
+# program's lowering time less).  What the DeepSeek-V3.2-Exp keys add is
+# still reached only through keys this configuration lacks
 PARENT_TICK = {
     ("float32", False, 4):
-        "6034690ec140e86513ea36a919a33ab9d7631de88be2a99c127d667ce84b35a1",
+        "8ff10516c3a83b89afb9bcfc333a89f1c64c085593530eb9c625cbe9098a2364",
     ("float32", False, 8):
-        "cf742217a4d69408190d6c37cd167c5d5528b45cbf80eb26961942b9e99868f2",
+        "0573dd1e468da5157aab2f4c0f0486d7a723a9a69ae4e54481cc6bdc3a3cfd44",
     ("float32", True, 4):
-        "043b6d87487b3db87e7e708b858ed69cf959016d014304b2fd2cdcb36d6a4cc2",
+        "23df76810a83defc6f429a2c84d417444cd2996304b5fd67e4c51aa5f9e96755",
     ("float32", True, 8):
-        "0c3004fbeb65affb0aa62d38af57df51f235c3a6d5f7cc28d9c8829d1d5e168a",
+        "d72cd5547700c16ba55b848ae9c18b4683c5e9ff3752b8753409e0c4c55d60ca",
     ("bfloat16", False, 4):
-        "24feb7d0248c9ca91c2bc537371293b08b5584bcfc3e7f82f4fa3cef73b42439",
+        "aef22a075755192a68fa60037065cf82aa968f397d98a00f6d3ceb809512fd63",
     ("bfloat16", False, 8):
-        "84cd079bd43f428deee70f1caa0cdbac511d6d8a5fc264071c13aa8d9b01c1c0",
+        "587e448f66037d6e4c58f666dfd87ff525b18f86758ffaa9a70e25f289996181",
     ("bfloat16", True, 4):
-        "579b06cf315bd3fe181a5beeff2f611b65575a98244c607f74a2b62193b194a5",
+        "c0f6fe81873dda812a7c7bac9a7bee91995eb470dcbae91fd4d27ae7bde22eb1",
     ("bfloat16", True, 8):
-        "296ffca457a4a93215415f093109d2055f68ad241285c58b6fe2cf5396ddee36",
+        "5b9f67e16986f0084e27f3a1f121de002672b3a240cbf298f960d98f6986aea6",
 }
 
 
@@ -375,12 +375,15 @@ PARENT_TICK = {
 def test_the_pangu_tick_lowers_as_at_the_parent(dtype, interpret, cols):
     """What DeepSeek-V3.2-Exp added to the model class, the router and the
     latent kernel is reached only through keys the Pangu configuration
-    does not have: its tick lowers to the parent's program to the byte."""
+    does not have: its tick lowers to the pinned program to the byte (PR
+    46's: no branch on the pack, no expert stack sliced by the scan)."""
     import hashlib
     model, params = build(dtype)
     eng = engine(model, params)
     text = eng._build_ragged_step(16, cols).lower(
         *eng._ragged_scratch_args(cols)).as_text()
+    if not interpret:       # an interpreted kernel is loops and branches
+        assert "stablehlo.case" not in text and "stablehlo.if" not in text
     assert hashlib.sha256(text.encode()).hexdigest() \
         == PARENT_TICK[dtype, interpret, cols]
 

@@ -615,8 +615,8 @@ class TestPoolsCarriedWhole:
 
 
 class OneWidthGPT(GPTModel):
-    """A model that declines the narrow program, as the latent model
-    does: every round through the budget-wide tick."""
+    """A model that declines the narrow program (none in the tree does
+    since PR 46): every round through the budget-wide tick."""
     ragged_narrow_rounds = False
 
 
@@ -784,22 +784,25 @@ class TestNarrowProgram:
                 *eng._ragged_scratch_args(4, rows)).as_text()
         assert text(*model_and_params) == text(*one_width)
 
-    def test_the_latent_model_declines(self):
-        """``PanguMoeModel`` keeps one width and its in-program branch
-        (PERF.md section 7 item 0): no narrow program in its grid, no
-        round dispatched to one."""
+    def test_the_latent_model_takes_it(self):
+        """``PanguMoeModel`` declined the narrow program and kept a
+        branch on the pack inside its one width until PR 46; no model in
+        the tree declines now (``OneWidthGPT`` above stands in for one):
+        the narrow program is in its grid and its decode-only rounds run
+        it."""
         from paddle_tpu.models.pangu_moe import PanguMoeModel
         from paddle_tpu.serving_paged import pow2_grid
-        assert PanguMoeModel.ragged_narrow_rounds is False
+        assert PanguMoeModel.ragged_narrow_rounds is True
         assert GPTModel.ragged_narrow_rounds is True
         model, params, geometry = _pangu()
         eng = RaggedPagedContinuousBatchingEngine(model, params, **geometry)
-        assert eng.narrow_rows == 0
-        assert eng.compile_grid() == [f"ragged_step:32:{C}"
-                                      for C in pow2_grid(eng.MB)]
+        assert eng.narrow_rows == 8
+        assert eng.compile_grid() == [
+            f"ragged_step:32:{C}" for C in pow2_grid(eng.MB)] \
+            + [f"ragged_step:8:{eng.MB}"]
         eng.add_request(list(range(1, 12)), 6)
         eng.run_to_completion(max_ticks=100)
-        assert eng.narrow_steps == 0 and eng.ragged_steps >= 6
+        assert 0 < eng.narrow_steps < eng.ragged_steps
 
     # sha256 of the GPT tick's lowering, by (interpreted kernel, dtype):
     # 3 slots, 64 positions in blocks of 8, a 24-row budget, 4 table
@@ -867,6 +870,73 @@ def _programs_run(fn):
         data = jax.profiler.ProfileData.from_file(path)
         return sum(ev.name == EXECUTE for plane in data.planes
                    for line in plane.lines for ev in line.events)
+
+
+class TestProgramsOfOneModel:
+    """Engines of one model share its program cache, the narrow program
+    with the rest: every entry is a ``jax.jit`` function, built in the
+    foreground by the round that first needs it and counted as the one
+    miss it is, so an engine that brings other operands finds the key and
+    ``jax.jit`` compiles the form it lacks."""
+
+    def _serve(self, eng):
+        rids = [eng.add_request(p, n)
+                for p, n in zip(PROMPTS[:4], [9, 4, 7, 6])]
+        got = eng.run_to_completion(max_ticks=200)
+        return [got[r] for r in rids]
+
+    def test_one_miss_a_program_and_one_form_of_each(self, model_and_params):
+        from paddle_tpu.telemetry import Tracer
+        model, params = model_and_params
+        model.__dict__.pop("_serving_programs", None)
+        tr = Tracer()
+        eng = RaggedPagedContinuousBatchingEngine(
+            model, params, tracer=tr, **GPT_GEOMETRY)
+        first = self._serve(eng)
+        keys = sorted(k[1:3] for k in model._serving_programs)
+        assert (8, eng.MB) in keys and eng.narrow_steps > 0
+        m = eng.metrics()
+        assert m["compile_misses"] == len(keys)
+        assert sorted(e["key"] for e in tr.events("compile")
+                      if not e["hit"]) == sorted(
+            f"ragged_step:{T}:{C}" for T, C in keys)
+        # wide rounds that follow narrow ones take what those returned
+        # (pools, presence, key) as they took their own: no program gains
+        # a second compiled form on the serving path, where no counter
+        # would see it
+        assert self._serve(eng) == first
+        assert eng.metrics()["compile_misses"] == m["compile_misses"]
+        assert {k[1:3]: run._cache_size()
+                for k, run in model._serving_programs.items()} \
+            == {k: 1 for k in keys}
+        # a second engine of the model finds every program built
+        again = RaggedPagedContinuousBatchingEngine(model, params,
+                                                    **GPT_GEOMETRY)
+        assert self._serve(again) == first
+        assert again.metrics()["compile_misses"] == 0
+
+    @pytest.mark.parametrize("dtype", ["bfloat16", "float16"])
+    def test_an_engine_with_params_of_another_dtype_is_served(
+            self, dtype, model_and_params):
+        """The key holds the engine's geometry and nothing of its
+        operands' dtypes: the second engine hits every key and each
+        program it runs compiles a second form for its params."""
+        model, params = model_and_params
+        cast = {n: p.astype(dtype) for n, p in params.items()}
+        model.__dict__.pop("_serving_programs", None)
+        alone = self._serve(RaggedPagedContinuousBatchingEngine(
+            model, cast, **GPT_GEOMETRY))
+        model.__dict__.pop("_serving_programs", None)
+        eng = RaggedPagedContinuousBatchingEngine(model, params,
+                                                  **GPT_GEOMETRY)
+        self._serve(eng)
+        other = RaggedPagedContinuousBatchingEngine(model, cast,
+                                                    **GPT_GEOMETRY)
+        assert self._serve(other) == alone
+        assert other.narrow_steps > 0
+        assert other.metrics()["compile_misses"] == 0
+        assert {run._cache_size()
+                for run in model._serving_programs.values()} == {2}
 
 
 def _pangu():
